@@ -113,9 +113,7 @@ def audit_callable(fn, *args, where: str, enable_x64: bool = True,
 
     try:
         if enable_x64:
-            from jax.experimental import enable_x64 as _x64ctx
-
-            with _x64ctx():
+            with jax.enable_x64(True):
                 jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
         else:
             jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)

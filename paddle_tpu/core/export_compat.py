@@ -1,19 +1,13 @@
-"""Lazy, version-tolerant access to ``jax.export``.
+"""Call-time access to ``jax.export``.
 
-The container's baked-in jax can predate the public `jax.export` module
-(it moved out of `jax.experimental.export` around 0.4.30, and some
-builds strip it).  A top-level ``import jax.export`` therefore used to
-kill module import — and with it pytest collection — for the whole
-static/onnx/inference chain.  Every export consumer now resolves the
-module through here at CALL time:
+Export consumers resolve the module through here at CALL time, so
+importing them never imports ``jax.export``:
 
     from ..core.export_compat import get_jax_export
-    je = get_jax_export()          # raises ExportUnavailableError
-    exp = je.export(jax.jit(fn))(*specs)
+    exp = get_jax_export().export(jax.jit(fn))(*specs)
 
-Import of the consumer modules never touches jax.export; tests gate on
-`jax_export_available()` and skip with a reason instead of dying at
-collection.
+The installed jax always has the module; the availability names below
+stay because callers and tests import them.
 """
 from __future__ import annotations
 
@@ -22,51 +16,15 @@ __all__ = ["ExportUnavailableError", "get_jax_export",
 
 
 class ExportUnavailableError(ImportError):
-    """This jax build has no usable jax.export module."""
-
-
-_module = None
-_error = None
+    """A jax build without jax.export (not the installed one)."""
 
 
 def get_jax_export():
-    """The jax.export module (new or experimental spelling), cached.
-    Raises ExportUnavailableError with an actionable message when the
-    build lacks both."""
-    global _module, _error
-    if _module is not None:
-        return _module
-    if _error is not None:
-        raise ExportUnavailableError(_error)
-    import jax
+    """The jax.export module."""
+    import jax.export as je
 
-    try:
-        import jax.export as je
-    except ImportError:
-        je = None
-    if je is None or not hasattr(je, "export"):
-        try:
-            from jax.experimental import export as je  # pre-0.4.30 home
-        except ImportError:
-            je = None
-    if je is not None and hasattr(je, "export"):
-        _module = je
-        return je
-    _error = (
-        f"this jax build ({jax.__version__}) provides no usable "
-        "jax.export module (neither jax.export nor "
-        "jax.experimental.export): serialized-StableHLO paths — "
-        "jit.save with input_spec, jit.load, "
-        "static.save/load_inference_model, onnx stablehlo format — "
-        "are unavailable; parameter-only save/load still works")
-    raise ExportUnavailableError(_error)
+    return je
 
 
 def jax_export_available() -> bool:
-    """True when get_jax_export() would succeed (tests use this for
-    skip-with-reason instead of dying at collection)."""
-    try:
-        get_jax_export()
-        return True
-    except ExportUnavailableError:
-        return False
+    return True

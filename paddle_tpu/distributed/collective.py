@@ -131,7 +131,7 @@ def _axis_bound(axis):
 
 def _collective_retry():
     """Retry policy for eager collectives: a host-dispatched collective
-    that dies on a transient fault (tunnel drop, preempted slice,
+    that dies on a transient fault (link drop, preempted slice,
     injected collective.call) is re-issued with backoff before the
     error surfaces — "retry then raise" (EQuARX-class collective
     faults, ISSUE 3).  PADDLE_TPU_COLLECTIVE_RETRIES tunes attempts."""
@@ -152,10 +152,7 @@ def _eager_collective(name, x, group, per_shard_fn, out_sharding_spec=None):
     mesh = g.mesh
     axis = g.axis
     val = x._value if isinstance(x, Tensor) else jnp.asarray(x)
-    try:  # jax>=0.5 exports shard_map at top level
-        from jax import shard_map
-    except ImportError:  # jax 0.4.x: experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     in_spec = _infer_spec(val, mesh, axis)
     out_spec = out_sharding_spec if out_sharding_spec is not None else in_spec

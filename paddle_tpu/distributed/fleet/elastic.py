@@ -68,7 +68,7 @@ class ElasticManager:
         self.enabled = os.environ.get("PADDLE_ELASTIC_ENABLE",
                                       "1") not in ("0", "false")
         # heartbeat store traffic rides the resilience retry policy: a
-        # transient TCPStore error (master restarting, tunnel blip) is
+        # transient TCPStore error (master restarting, transient fault) is
         # retried with backoff instead of silently dropping beats — and
         # a persistent one is COUNTED (resilience.giveups) while the
         # watch thread stays alive to beat again next interval

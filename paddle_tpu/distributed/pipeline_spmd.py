@@ -45,10 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:  # jax>=0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["stack_stages", "spmd_pipeline", "spmd_pipeline_reference"]

@@ -118,6 +118,14 @@ def current_spmd_mesh():
     return get_topology().spmd_mesh
 
 
+def traced_spmd_mesh():
+    """The mesh of the SPMD program being traced right now (set by
+    `use_spmd_mesh`: the train step, a pipeline stage), else None.
+    Kernel dispatch asks this to learn it must run per shard — a default
+    topology is no evidence that the current trace spans its devices."""
+    return _mesh_override
+
+
 import contextlib
 
 

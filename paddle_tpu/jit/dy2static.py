@@ -21,7 +21,7 @@ How it works:
   * Variables assigned under a traced branch/loop must hold Tensors (or
     stay untouched): rebinding a Python scalar divergently is a
     graph-break and raises `Dy2StaticError` with guidance — the loud-error
-    contract (VERDICT.md round-1 item 5) instead of silent specialization.
+    contract instead of silent specialization.
 
 Scope: `if`/`while`/boolean ops at any nesting depth inside the converted
 function; user-defined callees are converted transitively via `_jst_call`

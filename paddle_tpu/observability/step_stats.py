@@ -6,9 +6,8 @@ and turns wall-clock step measurements into:
   * per-step records — wall time, tokens/s, estimated MFU (from the
     caller's FLOPs accounting, the same 6*N*tokens model bench.py uses),
     host->device transfer bytes, device allocator peak — emitted as a
-    JSONL stream whose lines follow the `tools/chip_session_log.jsonl`
-    convention (every line a self-describing object with "phase" and
-    "t"), so `tools/analyze_chip_log.py` consumes live runs and
+    JSONL stream (every line a self-describing object with "phase"
+    and "t"), so `tools/analyze_chip_log.py` consumes live runs and
     historical logs uniformly;
   * a compile-time ledger: records marked ``compile=True`` (first-step
     trace+compile walls) are summarized separately from steady-state
@@ -243,7 +242,7 @@ class StepTimer:
 
 def validate_stream(entries) -> list:
     """Schema errors for the step_stats entries in `entries` (non-step
-    entries are ignored — chip_session logs interleave phases).  Empty
+    entries are ignored — a stream may interleave phases).  Empty
     list = valid."""
     errors = []
     for i, e in enumerate(entries):

@@ -211,10 +211,7 @@ def ring_attention(q, k, v, mesh=None, seq_axis="sep", causal=True,
     axis. use_flash: None = platform policy (Pallas ring on real TPU, jnp
     body elsewhere), True/False = force (tests exercise the Pallas ring
     through the interpreter on CPU meshes with True)."""
-    try:  # jax>=0.5 exports shard_map at top level
-        from jax import shard_map
-    except ImportError:  # jax 0.4.x: experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ...distributed import topology as topo_mod
 
@@ -251,12 +248,7 @@ def ring_attention(q, k, v, mesh=None, seq_axis="sep", causal=True,
         body = functools.partial(_local_ring_attention_jnp,
                                  axis_name=seq_axis, causal=causal)
 
-    try:
-        fn = shard_map(
-            body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-    except TypeError:  # jax 0.4.x spells the replication check check_rep
-        fn = shard_map(
-            body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+    fn = shard_map(
+        body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)

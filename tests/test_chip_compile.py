@@ -1,0 +1,204 @@
+"""Compile rehearsal for a DESCRIBED v5e: the kernels of the chip_smoke
+path (GPT-3 125M trainer + serving engine), at real widths, through the
+chip's own compiler — no chip attached, nothing runs.
+
+`tests/test_tpu_lowering.py` only exports for TPU (BlockSpec checks at
+lowering); the VMEM refusals of the chip's compiler show only here.  The
+topology is described inside a module-scoped fixture (never at import:
+one process may load the TPU library, and under xdist every worker
+imports this file), and every compile happens in the test's own process.
+Keep these tests in this ONE file.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import autotune
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas.decode_attention import decode_attention
+from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+# GPT-3 125M attention widths (chip_smoke.py / bench.py)
+S, H, D = 1024, 12, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these compiles out of it
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(one_chip, f, *shapes):
+    """Compile f for the described chip; the Pallas kernel must be in
+    the compiled program (no silent interpreter / reference fallback)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with fa.force_tpu_lowering():
+        text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.fixture
+def flash_counters():
+    """Counter deltas of the flash dispatch, read after the test body
+    traced it: {"flash.dispatch{tier=...}": n, "flash.blocks{...}": n}."""
+    from paddle_tpu.observability import metrics
+
+    was = metrics.enabled()
+    metrics.enable()
+    before = dict(metrics.snapshot()["counters"])
+
+    def delta():
+        now = metrics.snapshot()["counters"]
+        return {k: v - before.get(k, 0) for k, v in now.items()
+                if k.startswith("flash.") and v - before.get(k, 0)}
+
+    yield delta
+    if not was:
+        metrics.disable()
+
+
+@pytest.mark.parametrize("layout,tier,blocks", [
+    ("auto", "flat", (256, 512)),          # what GPT-125M trains with
+    ("transpose", "transpose", (512, 1024)),
+])
+@pytest.mark.parametrize("b", [8, 32])
+def test_flash_cold_default_compiles_fwd_bwd(one_chip, monkeypatch,
+                                             flash_counters, b, layout,
+                                             tier, blocks):
+    """What `scaled_dot_product_attention` runs in the train step: the
+    real dispatch (`flash_attention_fwd`), cold autotune cache (the
+    static default blocks), forward and backward.  `auto` resolves to
+    flat or transpose — never the kv core, whose backward the v5e
+    compiler refuses at these shapes."""
+    monkeypatch.setenv("FLAGS_flash_layout", layout)
+    monkeypatch.setattr(autotune, "_enabled", lambda: False)
+
+    def fwd(q, k, v):
+        return fa.flash_attention_fwd(q, k, v, is_causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    qkv = [((b, S, H, D), jnp.bfloat16)] * 3
+    _compile(one_chip, fwd, *qkv)
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    bq, bk = blocks
+    assert flash_counters() == {
+        f"flash.dispatch{{tier={tier}}}": 2,
+        f"flash.blocks{{block_k={bk},block_q={bq},tier={tier}}}": 2}
+
+
+def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
+                                                  monkeypatch):
+    """A Mosaic kernel inside a multi-device program is refused at
+    lowering ("cannot be automatically partitioned"); under the mesh the
+    train step announces (`use_spmd_mesh`) the dispatch runs per shard.
+    dp=4 over the host's four chips, global batch 8, fwd + bwd."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.distributed import topology
+
+    monkeypatch.setattr(autotune, "_enabled", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1, 1),
+                ("dp", "sep", "mp"))
+    q = jax.ShapeDtypeStruct((8, S, H, D), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+
+    def loss(q, k, v):
+        with topology.use_spmd_mesh(mesh):
+            return fa.flash_attention_fwd(
+                q, k, v, is_causal=True).astype(jnp.float32).sum()
+
+    with fa.force_tpu_lowering():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_flash_candidates_fit_the_gate(monkeypatch):
+    """No kv/flat candidate list holds a pair the dispatch gate's own
+    arithmetic rejects (the compiler refuses (512,1024) and (1024,1024)
+    backward at this shape)."""
+    seen = {}
+
+    def spy(op, sig, cands, run, default):
+        seen[sig] = (list(cands), default)
+        return default
+
+    monkeypatch.setattr(autotune, "pick", spy)
+    q = jax.ShapeDtypeStruct((32, S, H, D), jnp.bfloat16)
+    for lt in ("flat", "kv"):
+        fa._tuned_blocks(32, S, S, H, D, q.dtype, True, layout=lt)
+    assert len(seen) == 2
+    for cands, default in seen.values():
+        assert default in cands
+        assert (512, 1024) not in cands and (1024, 1024) not in cands
+        assert all(fa._kv_native_ok(q, q, *c) for c in cands)
+
+
+def _engine_shapes():
+    """The decode shapes of chip_smoke's serve phase: default
+    EngineConfig over GPT-3 125M (max_seq_len 1024)."""
+    from paddle_tpu.inference.engine import EngineConfig
+
+    cfg = EngineConfig()
+    pages_per_seq = -(-1024 // cfg.page_size)
+    return (cfg.max_slots, pages_per_seq,
+            (cfg.max_slots * pages_per_seq + 1, H, cfg.page_size, D))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_compiles_at_engine_shapes(one_chip, dtype):
+    slots, pages_per_seq, pool = _engine_shapes()
+    _compile(one_chip, paged_attention,
+             ((slots, H, D), dtype), (pool, dtype), (pool, dtype),
+             ((slots, pages_per_seq), jnp.int32), ((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("b,cap,dtype", [
+    (8, 1024, jnp.bfloat16),    # a full-context batch
+    (1, 128, jnp.float32),      # generate() as chip_smoke calls it
+])
+def test_decode_attention_compiles(one_chip, b, cap, dtype):
+    _compile(one_chip, decode_attention,
+             ((b, H, D), dtype), ((b, H, cap, D), dtype),
+             ((b, H, cap, D), dtype), ((b,), jnp.int32))
+
+
+def test_masked_prefill_compiles(one_chip, monkeypatch):
+    """The engine's left-padded prefill bucket that passes the biased
+    gate (128 keys): additive mask streamed through the biased core."""
+    monkeypatch.setattr(autotune, "_enabled", lambda: False)
+
+    def prefill(q, k, v, mask):
+        return fa.flash_attention_fwd(q, k, v, mask=mask,
+                                      bias_grad_safe=True)
+
+    qkv = [((1, 128, H, D), jnp.float32)] * 3
+    _compile(one_chip, prefill, *qkv, ((1, 1, 128, 128), jnp.float32))

@@ -865,6 +865,45 @@ def test_autotune_pick_contract(monkeypatch, tmp_path):
                          "bad") == "ok"
 
 
+def test_autotune_pick_searches_inside_a_trace(monkeypatch, tmp_path):
+    """Dispatch sites call pick() while the train step is being traced
+    (jit of value_and_grad).  The candidates must still run eagerly: on
+    the chip every candidate once failed with a tracer-conversion error
+    and the search silently fell back to the default (PR 23)."""
+    from paddle_tpu.ops.pallas import autotune
+
+    monkeypatch.setattr(autotune, "_CACHE_PATH",
+                        str(tmp_path / "autotune.json"))
+    monkeypatch.setattr(autotune, "_cache", None)
+    monkeypatch.setattr(autotune, "_devkind", lambda: "test-kind")
+    timed = []
+
+    q = jax.ShapeDtypeStruct((2, 256, 2, 64), jnp.bfloat16)
+
+    def flash_loss(y):
+        return fa._flash_core_flat(y, y, y, True, 128, 128).astype(
+            jnp.float32).sum()
+
+    def run(cfg):
+        # a candidate's Pallas kernel must TRACE out here too (the chip
+        # run's second failure: 'program_id' evaluated eagerly)
+        with fa.force_tpu_lowering():
+            text = jax.jit(jax.grad(flash_loss)).trace(q).lower(
+                lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+        w = jnp.eye(256, dtype=jnp.float32) * cfg
+        return (lambda y: y @ w), jnp.ones((256, 256), jnp.float32)
+
+    def loss(z):
+        timed.append(autotune.pick("testop", "sig", [1.0, 1.0001], run,
+                                   None))
+        return (z * 2).sum()
+
+    jax.jit(jax.value_and_grad(loss))(jnp.ones(3))
+    assert timed and timed[0] in (1.0, 1.0001)   # a winner, not the default
+    assert autotune.cached_config("testop", "sig") == timed[0]
+
+
 @pytest.mark.slow
 def test_train_step_layout_parity(monkeypatch):
     """FULL GPT train step, loss parity across flash layouts: on the

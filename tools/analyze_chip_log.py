@@ -1,7 +1,4 @@
-"""Render tools/chip_session_log.jsonl into a markdown digest.
-
-The watcher auto-commits raw capture data; this turns it into the
-PERF.md-style tables: one section per phase, latest entry per unique
+"""Render a JSONL stream of run records into a markdown digest: one section per phase, latest entry per unique
 key, errors listed last.  `step_stats` entries (the observability
 StepTimer stream, docs/OBSERVABILITY.md) get schema validation plus a
 per-run summary (compile ledger vs steady walls, tokens/s, MFU) instead
@@ -11,7 +8,7 @@ of the latest-entry-wins table; `trace_event` entries (span-tracer
 observability/export.py) get schema validation plus a per-process dump
 digest.  Exit is non-zero on any schema error in any stream (the CI
 hook).
-Run: python tools/analyze_chip_log.py [log.jsonl]
+Run: python tools/analyze_chip_log.py log.jsonl
 """
 from __future__ import annotations
 
@@ -20,10 +17,6 @@ import json
 import os
 import sys
 from collections import OrderedDict
-
-LOG = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                   "chip_session_log.jsonl")
-
 
 def _load_obs_module(name):
     """File-load an observability module (stdlib-only by contract) so
@@ -42,7 +35,7 @@ _trace = _load_obs_module("trace")
 _export = _load_obs_module("export")
 
 
-def load(path=LOG):
+def load(path):
     entries = []
     try:
         with open(path) as f:
@@ -137,8 +130,11 @@ def digest(entries, schema_errors=None, trace_errors=None,
 
 
 def main(argv):
-    path = argv[1] if len(argv) > 1 else LOG
-    entries = load(path)
+    if len(argv) < 2:
+        print("usage: python tools/analyze_chip_log.py LOG.jsonl",
+              file=sys.stderr)
+        return 2
+    entries = load(argv[1])
     # validate once; digest renders the same result and the exit code
     # makes a corrupt step-stats or trace stream fail loudly in CI
     errors = _step_stats.validate_stream(entries)
