@@ -52,6 +52,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import flags, rng
 from ..core.tensor import Tensor
+from ..observability import trace as _trace
 from ..observability import xla_cost as _xla_cost
 from . import topology as topo_mod
 
@@ -355,26 +356,36 @@ class DistributedTrainStep:
             _metrics.inc("collective.quantized_tier", precision=precision)
 
         def step(params, opt_state, buffers, key, lr, *batch_leaves):
+            # each stage under a `train_step.*` scope: the names reach the
+            # optimized program's op_name metadata, where
+            # `xla_cost.program_ledger` joins a device trace to them
+            # (trace time only; nothing runs per step)
             if gather_full:
                 # all-gather: full params for the next forward (ZeRO-1's
                 # per-step gather — the bits equal the sharded storage's)
-                run_params = {
-                    n: jax.lax.with_sharding_constraint(
-                        v, self._sharding(self._p_full_spec[n]))
-                    for n, v in params.items()}
+                with jax.named_scope("train_step.gather"):
+                    run_params = {
+                        n: jax.lax.with_sharding_constraint(
+                            v, self._sharding(self._p_full_spec[n]))
+                        for n, v in params.items()}
             else:
                 run_params = params
-            (loss, (new_buffers, new_key)), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(run_params, buffers, key,
-                                       list(batch_leaves))
+            # JAX marks the ops inside with jvp(...) / transpose(...):
+            # that is the forward/backward split
+            with jax.named_scope("train_step.loss"):
+                (loss, (new_buffers, new_key)), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(run_params, buffers, key,
+                                           list(batch_leaves))
             if clip_norm is not None:
-                gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                          for g in jax.tree_util.tree_leaves(grads))
-                scale = jnp.minimum(
-                    1.0, clip_norm / jnp.maximum(jnp.sqrt(gsq), 1e-6))
-                grads = jax.tree_util.tree_map(
-                    lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype),
-                    grads)
+                with jax.named_scope("train_step.clip"):
+                    gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                              for g in jax.tree_util.tree_leaves(grads))
+                    scale = jnp.minimum(
+                        1.0, clip_norm / jnp.maximum(jnp.sqrt(gsq), 1e-6))
+                    grads = jax.tree_util.tree_map(
+                        lambda g: (g.astype(jnp.float32)
+                                   * scale).astype(g.dtype),
+                        grads)
             if zero_sharded:
                 # per-parameter sharding constraint at the point the
                 # backward produces each grad: the partitioner reduces
@@ -389,23 +400,27 @@ class DistributedTrainStep:
                     return jax.lax.with_sharding_constraint(
                         g, self._sharding(self._p_spec[n]))
 
-                grads = {n: _sync(n, g) for n, g in grads.items()}
-            # the update consumes the SHARDED params/grads/slots: every
-            # optimizer is elementwise over same-shaped leaves, so the
-            # whole weight update runs on 1/dp of each parameter
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state, lr)
-            # pin result shardings so the update stays ZeRO-partitioned
-            new_params = {
-                n: jax.lax.with_sharding_constraint(
-                    v, self._sharding(self._p_spec[n]))
-                for n, v in new_params.items()}
-            new_opt_slots = {
-                n: {k: jax.lax.with_sharding_constraint(
-                    v, self._sharding(self._s_spec[n][k]))
-                    for k, v in sd.items()}
-                for n, sd in new_opt["slots"].items()}
-            new_opt = {"slots": new_opt_slots, "step": new_opt["step"]}
+                with jax.named_scope("train_step.grad_sync"):
+                    grads = {n: _sync(n, g) for n, g in grads.items()}
+            with jax.named_scope("train_step.update"):
+                # the update consumes the SHARDED params/grads/slots:
+                # every optimizer is elementwise over same-shaped leaves,
+                # so the whole weight update runs on 1/dp of each
+                # parameter
+                new_params, new_opt = optimizer.apply_gradients(
+                    params, grads, opt_state, lr)
+                # pin result shardings so the update stays
+                # ZeRO-partitioned
+                new_params = {
+                    n: jax.lax.with_sharding_constraint(
+                        v, self._sharding(self._p_spec[n]))
+                    for n, v in new_params.items()}
+                new_opt_slots = {
+                    n: {k: jax.lax.with_sharding_constraint(
+                        v, self._sharding(self._s_spec[n][k]))
+                        for k, v in sd.items()}
+                    for n, sd in new_opt["slots"].items()}
+                new_opt = {"slots": new_opt_slots, "step": new_opt["step"]}
             if guarded:
                 # in-step NaN/Inf guard: one fused finiteness reduction
                 # over loss + grads; a bad step keeps params/opt/buffers
@@ -415,10 +430,12 @@ class DistributedTrainStep:
                 # same dropout mask into the retry.
                 from ..resilience import guards as _guards
 
-                ok = _guards.tree_finite(loss, grads)
-                new_params = _guards.tree_select(ok, new_params, params)
-                new_opt = _guards.tree_select(ok, new_opt, opt_state)
-                new_buffers = _guards.tree_select(ok, new_buffers, buffers)
+                with jax.named_scope("train_step.guard"):
+                    ok = _guards.tree_finite(loss, grads)
+                    new_params = _guards.tree_select(ok, new_params, params)
+                    new_opt = _guards.tree_select(ok, new_opt, opt_state)
+                    new_buffers = _guards.tree_select(ok, new_buffers,
+                                                      buffers)
             else:
                 ok = jnp.bool_(True)
             return loss, ok, new_params, new_opt, new_buffers, new_key
@@ -629,18 +646,26 @@ class DistributedTrainStep:
         """batch: (inputs, labels) Tensors (loss_fn mode) or raw model args.
         Returns the loss as a Tensor; model/optimizer state advances."""
         self._check_preemption()  # safe point: pre-dispatch
+        # host spans (one branch each while the tracer is off): the
+        # device idles under one of these when the host is in its way
+        sp = _trace.begin("train_step.place_batch")
         placed, treedef = self._place_batch(batch, batch_axis=0)
+        _trace.end(sp)
         compiled = self._ensure_compiled(treedef)
         placed = self._maybe_poison(placed)
         s = self._state
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+        sp = _trace.begin("train_step.dispatch")
         loss, ok, params, opt, buffers, key = compiled(
             s["params"], s["opt"], s["buffers"], s["key"], lr, *placed)
+        _trace.end(sp)
         self._swap_state(params, opt, buffers, key)
         if self.guard is not None:
             # ONE host-visible scalar per dispatch (the guarded mode's
             # only extra transfer) drives the warn→skip→rollback ladder
+            sp = _trace.begin("train_step.guard_sync")
             self.guard.observe(bool(ok))
+            _trace.end(sp)
         self._check_preemption()  # safe point: post-step, state swapped
         return Tensor(loss)
 

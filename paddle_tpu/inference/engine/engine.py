@@ -1558,12 +1558,24 @@ class InferenceEngine:
     def _loop(self):
         # _running is a stop flag: a stale read costs one extra step;
         # taking the lock here would serialize the loop against submit()
+        waiting = None  # the open `engine.wait_request` span: ONE per
+        # idle stretch (an empty batch, blocked on the next request),
+        # closed before the step that serves the request begins
         while self._running:  # pt-lint: ok[PT102]
             if not self.step():
                 with self._work:
                     # pt-lint: ok[PT504] (wakeup re-check: _running/scheduler are OWNED by _lock; reading them under the _work cv is the standard missed-notify guard — a stale read costs one 50ms wait)
                     if self._running and not self.scheduler.has_work():
+                        if waiting is None:
+                            waiting = _trace.begin("engine.wait_request",
+                                                   cat="engine")
                         self._work.wait(timeout=0.05)
+                    # pt-lint: ok[PT504] (same re-check as above)
+                    if waiting is not None and (
+                            self.scheduler.has_work() or not self._running):
+                        _trace.end(waiting)
+                        waiting = None
+        _trace.end(waiting)
 
     def stop(self, timeout: float = 10.0):
         with self._lock:

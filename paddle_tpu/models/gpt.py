@@ -13,6 +13,7 @@ one XLA program), optional per-block recompute (jax rematerialization).
 from __future__ import annotations
 
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -260,10 +261,13 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             # hidden states as logits.
             x.name = "fused_head_hidden"
             return x
-        if self.cfg.tie_embeddings:
-            logits = x.matmul(self.gpt.wte.weight, transpose_y=True)
-        else:
-            logits = self.lm_head(x)
+        # the head is no sublayer of its own when the embedding is tied:
+        # an explicit scope names its work in the program
+        with jax.named_scope("head"):
+            if self.cfg.tie_embeddings:
+                logits = x.matmul(self.gpt.wte.weight, transpose_y=True)
+            else:
+                logits = self.lm_head(x)
         if kv_caches is not None:
             return logits, new_caches
         return logits
@@ -509,7 +513,9 @@ class GPTPretrainingCriterion(nn.Layer):
                     self.ignore_index)
                 return total / jnp.maximum(count, 1.0)
 
-            return apply("fused_linear_ce", f, logits, labels, w)
+            # head projection + CE in one chunk scan: one scope, `head_ce`
+            with jax.named_scope("head_ce"):
+                return apply("fused_linear_ce", f, logits, labels, w)
         if self.fused and lv.shape[-1] >= 8192:
             from ..core.dispatch import apply
 
@@ -522,10 +528,11 @@ class GPTPretrainingCriterion(nn.Layer):
                     self.ignore_index)
                 return total / jnp.maximum(count, 1.0)
 
-            return apply("fused_softmax_ce", f, logits, labels)
-        loss = F.cross_entropy(logits, labels, reduction="mean",
-                               ignore_index=self.ignore_index)
-        return loss
+            with jax.named_scope("ce"):
+                return apply("fused_softmax_ce", f, logits, labels)
+        with jax.named_scope("ce"):
+            return F.cross_entropy(logits, labels, reduction="mean",
+                                   ignore_index=self.ignore_index)
 
 
 class GPTEmbeddingStage(nn.Layer):

@@ -11,13 +11,18 @@ what jit.to_static and every parallelism recipe build on.
 from __future__ import annotations
 
 import contextlib
+import threading
 from collections import OrderedDict
 
 import jax
 import numpy as np
 
 from ..core import dtypes as _dtypes
+from ..core import flags
 from ..core.tensor import Parameter, Tensor
+
+# the layers being called on this thread, innermost last (trace time only)
+_scope_stack = threading.local()
 
 
 class HookRemoveHelper:
@@ -298,12 +303,38 @@ class Layer:
             res = hook(self, inputs)
             if res is not None:
                 inputs = res if isinstance(res, tuple) else (res,)
-        out = self.forward(*inputs, **kwargs)
+        if flags.in_trace():
+            out = self._forward_in_scope(inputs, kwargs)
+        else:
+            out = self.forward(*inputs, **kwargs)
         for hook in list(self._forward_post_hooks.values()):
             res = hook(self, inputs, out)
             if res is not None:
                 out = res
         return out
+
+    def _forward_in_scope(self, inputs, kwargs):
+        """Inside a traced program, `forward` runs under a
+        `jax.named_scope` of this layer's module path component, so every
+        op it issues carries `.../gpt/h.3/attn/...` in its `op_name`
+        (what `observability.xla_cost.program_ledger` joins a device
+        trace to).  The component is the name under which the calling
+        layer holds this one, as `named_sublayers` spells it — a layer
+        held in a `LayerList`, which is iterated and never called, takes
+        `<list>.<index>` — and the class name for a layer called from
+        outside any layer.  Trace time only: eager calls never get here.
+        """
+        stack = _scope_stack.__dict__.setdefault("layers", [])
+        name = None
+        if stack:
+            name = next((n for n, l in stack[-1].named_sublayers()
+                         if l is self), None)
+        stack.append(self)
+        try:
+            with jax.named_scope(name or type(self).__name__):
+                return self.forward(*inputs, **kwargs)
+        finally:
+            stack.pop()
 
     # --- functional bridge (TPU-native jit/shard path) -----------------------
     def functional_state(self):

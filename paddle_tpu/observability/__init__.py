@@ -30,6 +30,12 @@ from . import (  # noqa: F401
 )
 from .step_stats import StepTimer  # noqa: F401
 
+if metrics.enabled() or trace.enabled():
+    # telemetry switched on by the environment (PADDLE_TPU_METRICS /
+    # PADDLE_TPU_TRACE): the compile totals start with it, as they do
+    # behind metrics.enable() / trace.enable()
+    xla_cost.watch_process_compiles()
+
 __all__ = ["metrics", "flight", "step_stats", "trace", "xla_cost",
            "request_trace", "slo", "export", "goodput", "tenant_ledger",
            "timeseries", "lifecycle", "StepTimer", "attach", "detach"]

@@ -418,8 +418,19 @@ def dump_jsonl(path, extra=None):
     return _default.dump_jsonl(path, extra)
 
 
+def _watch_compiles() -> None:
+    """Start xla_cost's process-wide compile totals no later than
+    telemetry (a no-op where this module was file-loaded standalone)."""
+    try:
+        from . import xla_cost
+    except ImportError:
+        return
+    xla_cost.watch_process_compiles()
+
+
 def enable():
     _default.enable()
+    _watch_compiles()
 
 
 def disable():

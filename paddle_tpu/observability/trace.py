@@ -24,6 +24,13 @@ scopes render as stacked slices per thread instead of collapsing onto
 one row, and synthetic tracks for frames/counters sorted below the real
 threads.
 
+One clock with the device: while the tracer is enabled every span also
+opens a `jax.profiler.TraceAnnotation` of the same name, so in any
+profiler session (an operator's xprof, the benchmark's traced window)
+program spans and device ops lie on one timeline; `epoch_perf_ns` is the
+`perf_counter_ns` origin of every `ts` here, for a reader that maps this
+buffer onto that timeline itself.
+
 Cost model: DISABLED by default — one attribute read + branch per call
 (`observability.attach()`, `trace.enable()`, or env
 ``PADDLE_TPU_TRACE=1`` turn it on).  When enabled, a span is two clock
@@ -42,6 +49,7 @@ import contextlib
 import functools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -76,7 +84,7 @@ class Span:
     metadata computed inside the span (e.g. xla_cost attaches the
     compiler's FLOPs estimate to the compile span that produced it)."""
 
-    __slots__ = ("name", "cat", "args", "t0_us", "tid", "depth")
+    __slots__ = ("name", "cat", "args", "t0_us", "tid", "depth", "ann")
 
     def __init__(self, name, cat, args, t0_us, tid, depth):
         self.name = name
@@ -85,6 +93,31 @@ class Span:
         self.t0_us = t0_us
         self.tid = tid
         self.depth = depth
+        self.ann = _open_annotation(name)
+
+
+def _open_annotation(name):
+    """The span on the profiler's clock: an entered
+    `jax.profiler.TraceAnnotation`, or None where jax is not loaded (no
+    profiler session can exist then; this module never imports it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+        return ann
+    except Exception:  # pt-lint: ok[PT005]
+        return None    # (a span must never fail for its mirror)
+
+
+def _close_annotation(sp) -> None:
+    if sp.ann is not None:
+        ann, sp.ann = sp.ann, None
+        try:
+            ann.__exit__(None, None, None)
+        except Exception:  # pt-lint: ok[PT005]
+            pass           # (as above)
 
 
 class SpanTracer:
@@ -98,9 +131,10 @@ class SpanTracer:
                 "1", "true", "True")
         self._enabled = bool(enabled)
         # one epoch per tracer: every ts is microseconds since this
-        # monotonic origin, so spans/instants/frames from all threads
-        # share a comparable clock
-        self._epoch_ns = time.perf_counter_ns()
+        # `perf_counter_ns` origin, so spans/instants/frames from all
+        # threads share a comparable clock — published, because it is the
+        # clock a device-trace reader maps with its own perf offset
+        self.epoch_perf_ns = time.perf_counter_ns()
         self.wall_epoch = time.time()
         self.pid = os.getpid()
         self._tids: dict = {}        # threading ident -> small stable tid
@@ -136,7 +170,7 @@ class SpanTracer:
 
     # ------------------------------ clock/ids ------------------------------
     def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._epoch_ns) / 1e3
+        return (time.perf_counter_ns() - self.epoch_perf_ns) / 1e3
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -194,9 +228,13 @@ class SpanTracer:
         if stack and sp in stack:
             # tolerate unbalanced exits: drop this span and anything
             # opened (and never closed) inside it
-            del stack[stack.index(sp):]
+            i = stack.index(sp)
+            for inner in reversed(stack[i + 1:]):
+                _close_annotation(inner)
+            del stack[i:]
             if stack:
                 sp.args.setdefault("parent", stack[-1].name)
+        _close_annotation(sp)
         if not self._enabled:
             # disabled mid-span: the stack is already popped (a leaked
             # entry would mislabel every later span's parent), only the
@@ -303,6 +341,7 @@ class SpanTracer:
             "displayTimeUnit": "ms",
             "otherData": {"schema": SCHEMA_VERSION, "pid": self.pid,
                           "wall_epoch": self.wall_epoch,
+                          "epoch_perf_ns": self.epoch_perf_ns,
                           "dropped_events": self.dropped()},
         }
 
@@ -368,6 +407,9 @@ def counter(name, track="counters", **series):
 
 def enable():
     _default.enable()
+    metrics = _metrics_module()
+    if metrics is not None:
+        metrics._watch_compiles()   # compile totals start with telemetry
 
 
 def disable():

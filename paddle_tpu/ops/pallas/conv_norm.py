@@ -279,6 +279,7 @@ def _conv_pallas(x, w, scale, shift, s, p, act, depthwise, rows):
                                lambda bi, ri: (bi, 0, ri, 0)),
         out_shape=jax.ShapeDtypeStruct((b, cout, h_out, w_out), x.dtype),
         interpret=_interpret(),
+        name="conv_norm_fwd",
     )(xp, w, scale.reshape(cout, 1), shift.reshape(cout, 1))
 
 

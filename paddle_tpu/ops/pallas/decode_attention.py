@@ -119,5 +119,6 @@ def decode_attention(q, kcache, vcache, pos, block_k=256, interpret=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="decode_attention_fwd",
     )(pos2, q4, kcache, vcache)
     return out.reshape(b, hq, d)

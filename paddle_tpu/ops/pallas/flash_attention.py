@@ -83,6 +83,37 @@ _KV_VMEM_LIMIT = 34 * 1024 * 1024
 _DEFAULT_LAYOUT = "auto"
 
 
+# Names in the program (docs/OBSERVABILITY.md "Scopes"): every
+# pallas_call is `flash_<tier>_<fwd|dq|dkdv>`, and the transposes,
+# reshapes and pads a tier's wrapper puts around its kernels sit under
+# LAYOUT_SCOPE — XLA ops, device time outside the kernel family.
+LAYOUT_SCOPE = "flash.layout"
+
+
+def _layout_swap(*xs):
+    """[B,S,H,D] <-> [B,H,S,D] on each of `xs`: XLA transposes, named."""
+    with jax.named_scope(LAYOUT_SCOPE):
+        return tuple(jnp.swapaxes(x, 1, 2) for x in xs)
+
+
+def _fwd_name(kernel, diff):
+    """A forward kernel's name: plain where the call is the whole story
+    (inference), `jvp(<kernel>)` where it is the forward half of a
+    differentiated call (the custom_vjp's fwd rule, which also saves the
+    residuals).  XLA names a Mosaic custom call after the innermost
+    scope of its op_name, and pallas_call makes the name that scope — so
+    JAX's own transform marks, which module scopes push outward, have to
+    be written here for a device trace's event to keep saying which
+    direction it belongs to (`%jvp_flash_flat_fwd_.3`)."""
+    return f"jvp({kernel})" if diff else kernel
+
+
+def _bwd_name(kernel):
+    """A backward kernel's name, `transpose(jvp(<kernel>))`: these run
+    only as the transpose of a differentiated forward (see _fwd_name)."""
+    return f"transpose(jvp({kernel}))"
+
+
 _FORCE_COMPILED = False  # see force_tpu_lowering()
 
 
@@ -283,7 +314,7 @@ def _pick_block(seq, pref):
 
 
 def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
-           seq_k_real=None):
+           seq_k_real=None, diff=False):
     """Forward on head-major [B,H,S,D] operands (the kernels' native
     layout). Returns (out_t [B,H,Sq,D], lse [B,H,Sq,1]).
 
@@ -333,6 +364,7 @@ def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
         ],
         interpret=_interpret(),
         compiler_params=_compiler_params(),
+        name=_fwd_name("flash_transpose_fwd", diff),
     )(qt, kt, vt)
     return out, lse
 
@@ -375,7 +407,7 @@ def _fwd_kernel_mh(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
         lse_ref[hh, :, :] = lse.astype(jnp.float32)
 
 
-def _fwd_mh(q, k, v, causal, block_q, block_k):
+def _fwd_mh(q, k, v, causal, block_q, block_k, diff=False):
     """Forward on [B,S,H,D] with zero layout changes (see _fwd_kernel_mh).
     Returns (out [B,S,H,D], lse [B,H,Sq,1])."""
     b, sq, h, d = q.shape
@@ -406,6 +438,7 @@ def _fwd_mh(q, k, v, causal, block_q, block_k):
         ],
         interpret=_interpret(),
         compiler_params=dimsem,
+        name=_fwd_name("flash_mh_fwd", diff),
     )(q, k, v)
     return out, lse
 
@@ -692,6 +725,7 @@ def _bwd_mh(q, k, v, out, lse, do, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
         interpret=_interpret(),
         compiler_params=dimsem,
+        name=_bwd_name("flash_mh_dq"),
     )(q, k, v, out, lse, do)
 
     kv_spec = pl.BlockSpec((None, block_k, h, d),
@@ -706,6 +740,7 @@ def _bwd_mh(q, k, v, out, lse, do, causal, block_q, block_k):
                    jax.ShapeDtypeStruct((b, sk, h, d), v.dtype)],
         interpret=_interpret(),
         compiler_params=dimsem,
+        name=_bwd_name("flash_mh_dkdv"),
     )(q, k, v, out, lse, do)
 
     return dq, dk, dv
@@ -749,6 +784,7 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
         interpret=_interpret(),
         compiler_params=_compiler_params(),
+        name=_bwd_name("flash_transpose_dq"),
     )(qt, kt, vt, ot, lse, dot)
 
     # dK/dV: grid over KV heads; each instance reads its whole group of
@@ -769,6 +805,7 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
                    jax.ShapeDtypeStruct((b, h_kv, sk, d), vt.dtype)],
         interpret=_interpret(),
         compiler_params=_compiler_params(),
+        name=_bwd_name("flash_transpose_dkdv"),
     )(qt, kt, vt, ot, lse, dot)
 
     return dq, dk, dv
@@ -788,12 +825,10 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_core(q, k, v, causal, block_q, block_k, seq_q_real=None,
                 seq_k_real=None):
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    qt, kt, vt = _layout_swap(q, k, v)
     out, _ = _fwd_t(qt, kt, vt, causal, block_q, block_k,
                     seq_q_real, seq_k_real)
-    return jnp.swapaxes(out, 1, 2)
+    return _layout_swap(out)[0]
 
 
 def _flash_core_fwd(q, k, v, causal, block_q, block_k, seq_q_real=None,
@@ -802,21 +837,18 @@ def _flash_core_fwd(q, k, v, causal, block_q, block_k, seq_q_real=None,
     # transposes, so backward reuses them instead of re-transposing all
     # five operands from [B,S,H,D] — only the cotangent (in) and the three
     # grads (out) cross layouts in the backward pass
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    qt, kt, vt = _layout_swap(q, k, v)
     out_t, lse = _fwd_t(qt, kt, vt, causal, block_q, block_k,
-                        seq_q_real, seq_k_real)
-    return jnp.swapaxes(out_t, 1, 2), (qt, kt, vt, out_t, lse)
+                        seq_q_real, seq_k_real, diff=True)
+    return _layout_swap(out_t)[0], (qt, kt, vt, out_t, lse)
 
 
 def _flash_core_bwd(causal, block_q, block_k, seq_q_real, seq_k_real,
                     res, g):
     qt, kt, vt, ot, lse = res
-    dq, dk, dv = _bwd_t(qt, kt, vt, ot, lse, jnp.swapaxes(g, 1, 2),
+    dq, dk, dv = _bwd_t(qt, kt, vt, ot, lse, _layout_swap(g)[0],
                         causal, block_q, block_k, seq_q_real, seq_k_real)
-    return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2))
+    return _layout_swap(dq, dk, dv)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -833,7 +865,7 @@ def _flash_core_mh(q, k, v, causal, block_q, block_k):
 
 
 def _flash_core_mh_fwd(q, k, v, causal, block_q, block_k):
-    out, lse = _fwd_mh(q, k, v, causal, block_q, block_k)
+    out, lse = _fwd_mh(q, k, v, causal, block_q, block_k, diff=True)
     return out, (q, k, v, out, lse)
 
 
@@ -904,7 +936,7 @@ def _kv_dimsem():
         vmem_limit_bytes=_KV_VMEM_LIMIT)
 
 
-def _fwd_kv(qt, k, v, causal, block_q, block_k):
+def _fwd_kv(qt, k, v, causal, block_q, block_k, diff=False):
     """Forward with head-major Q/O ([B,H,Sq,D]) and native-layout K/V
     ([B,Sk,Hkv,D]); GQA reads the shrunken KV directly (hh // rep).
     Returns (out_t [B,H,Sq,D], lse [B,H,Sq,1])."""
@@ -940,6 +972,7 @@ def _fwd_kv(qt, k, v, causal, block_q, block_k):
         ],
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
+        name=_fwd_name("flash_kv_fwd", diff),
     )(qt, k, v)
     return out, lse
 
@@ -1037,6 +1070,7 @@ def _bwd_kv(qt, k, v, ot, lse, dot, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
+        name=_bwd_name("flash_kv_dq"),
     )(qt, k, v, ot, lse, dot)
 
     hm_full = pl.BlockSpec((None, h, sq, d), lambda bi, kj: (bi, 0, 0, 0))
@@ -1056,6 +1090,7 @@ def _bwd_kv(qt, k, v, ot, lse, dot, causal, block_q, block_k):
                    jax.ShapeDtypeStruct((b, sk, h_kv, d), v.dtype)],
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
+        name=_bwd_name("flash_kv_dkdv"),
     )(qt, k, v, ot, lse, dot)
     return dq, dk, dv
 
@@ -1071,7 +1106,7 @@ def _flash_core_kv(q, k, v, causal, block_q, block_k):
 
 def _flash_core_kv_fwd(q, k, v, causal, block_q, block_k):
     qt = _to_hm(q)
-    out_t, lse = _fwd_kv(qt, k, v, causal, block_q, block_k)
+    out_t, lse = _fwd_kv(qt, k, v, causal, block_q, block_k, diff=True)
     return _from_hm(out_t), (qt, k, v, out_t, lse)
 
 
@@ -1148,6 +1183,7 @@ def _to_hm(x):
                                lambda bi, si: (bi, 0, si, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), x.dtype),
         compiler_params=_kv_dimsem(),
+        name="flash_relayout_to_hm",
     )(x)
 
 
@@ -1167,6 +1203,7 @@ def _from_hm(xt):
                                lambda bi, si: (bi, si, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, d), xt.dtype),
         compiler_params=_kv_dimsem(),
+        name="flash_relayout_from_hm",
     )(xt)
 
 
@@ -1230,7 +1267,7 @@ def _fwd_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
         lse_ref[hh] = lse.astype(jnp.float32)
 
 
-def _fwd_flat(q, k, v, h, causal, block_q, block_k):
+def _fwd_flat(q, k, v, h, causal, block_q, block_k, diff=False):
     """Forward on flat [B,Sq,H*D] q and [B,Sk,Hkv*D] k/v.
     Returns (out [B,Sq,H*D], lse [B,H,Sq,1])."""
     b, sq, hd = q.shape
@@ -1264,6 +1301,7 @@ def _fwd_flat(q, k, v, h, causal, block_q, block_k):
         ],
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
+        name=_fwd_name("flash_flat_fwd", diff),
     )(q, k, v)
     return out, lse
 
@@ -1344,6 +1382,7 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
+        name=_bwd_name("flash_flat_dq"),
     )(q, k, v, out, lse, do)
 
     q_full = pl.BlockSpec((None, sq, hd), lambda bi, kj: (bi, 0, 0))
@@ -1361,6 +1400,7 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
                    jax.ShapeDtypeStruct((b, sk, hkvd), v.dtype)],
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
+        name=_bwd_name("flash_flat_dkdv"),
     )(q, k, v, out, lse, do)
     return dq, dk, dv
 
@@ -1372,31 +1412,38 @@ def _flash_core_flat(q, k, v, causal, block_q, block_k):
     transposes, zero relayouts, zero padded arrays. Numerics are the
     shared flash loops — identical to _flash_core."""
     b, sq, h, d = q.shape
-    out, _ = _fwd_flat(q.reshape(b, sq, h * d),
-                       k.reshape(b, k.shape[1], -1),
-                       v.reshape(b, v.shape[1], -1),
-                       h, causal, block_q, block_k)
-    return out.reshape(b, sq, h, d)
+    with jax.named_scope(LAYOUT_SCOPE):
+        qf = q.reshape(b, sq, h * d)
+        kf = k.reshape(b, k.shape[1], -1)
+        vf = v.reshape(b, v.shape[1], -1)
+    out, _ = _fwd_flat(qf, kf, vf, h, causal, block_q, block_k)
+    with jax.named_scope(LAYOUT_SCOPE):
+        return out.reshape(b, sq, h, d)
 
 
 def _flash_core_flat_fwd(q, k, v, causal, block_q, block_k):
     b, sq, h, d = q.shape
-    qf = q.reshape(b, sq, h * d)
-    kf = k.reshape(b, k.shape[1], -1)
-    vf = v.reshape(b, v.shape[1], -1)
-    out, lse = _fwd_flat(qf, kf, vf, h, causal, block_q, block_k)
-    return out.reshape(b, sq, h, d), (qf, kf, vf, out, lse, h, d)
+    with jax.named_scope(LAYOUT_SCOPE):
+        qf = q.reshape(b, sq, h * d)
+        kf = k.reshape(b, k.shape[1], -1)
+        vf = v.reshape(b, v.shape[1], -1)
+    out, lse = _fwd_flat(qf, kf, vf, h, causal, block_q, block_k,
+                         diff=True)
+    with jax.named_scope(LAYOUT_SCOPE):
+        return out.reshape(b, sq, h, d), (qf, kf, vf, out, lse, h, d)
 
 
 def _flash_core_flat_bwd(causal, block_q, block_k, res, g):
     qf, kf, vf, out, lse, h, d = res
     b, sq, hd = qf.shape
-    dq, dk, dv = _bwd_flat(qf, kf, vf, out, lse,
-                           g.reshape(b, sq, hd), h, causal,
+    with jax.named_scope(LAYOUT_SCOPE):
+        gf = g.reshape(b, sq, hd)
+    dq, dk, dv = _bwd_flat(qf, kf, vf, out, lse, gf, h, causal,
                            block_q, block_k)
-    return (dq.reshape(b, sq, h, d),
-            dk.reshape(b, kf.shape[1], -1, d),
-            dv.reshape(b, vf.shape[1], -1, d))
+    with jax.named_scope(LAYOUT_SCOPE):
+        return (dq.reshape(b, sq, h, d),
+                dk.reshape(b, kf.shape[1], -1, d),
+                dv.reshape(b, vf.shape[1], -1, d))
 
 
 _flash_core_flat.defvjp(_flash_core_flat_fwd, _flash_core_flat_bwd)
@@ -1513,7 +1560,8 @@ def _bias_idx(bias_shape, b_dims):
     return lambda bi, hi, j: (bi * has_b, hi * has_h, 0, j)  # dkv band
 
 
-def _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k):
+def _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k,
+            diff=False):
     """Biased forward, head-major operands; bias [Bb, Hb, Sq, Sk] f32
     (Bb/Hb broadcastable). Returns (out_t, lse)."""
     b, h, sq, d = qt.shape
@@ -1547,6 +1595,7 @@ def _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k):
         ],
         interpret=_interpret(),
         compiler_params=_compiler_params(),
+        name=_fwd_name("flash_biased_fwd", diff),
     )(qt, kt, vt, bias)
     return out, lse
 
@@ -1584,6 +1633,7 @@ def _bwd_tb(qt, kt, vt, bias, ot, lse, dot, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
         interpret=_interpret(),
         compiler_params=_compiler_params(),
+        name=_bwd_name("flash_biased_dq"),
     )(qt, kt, vt, bias, ot, lse, dot)
 
     kv_spec = pl.BlockSpec((None, None, block_k, d),
@@ -1600,6 +1650,7 @@ def _bwd_tb(qt, kt, vt, bias, ot, lse, dot, causal, block_q, block_k):
                    jax.ShapeDtypeStruct((b, h, sk, d), vt.dtype)],
         interpret=_interpret(),
         compiler_params=_compiler_params(),
+        name=_bwd_name("flash_biased_dkdv"),
     )(qt, kt, vt, bias, ot, lse, dot)
     return dq, dk, dv
 
@@ -1611,27 +1662,23 @@ def _flash_core_b(q, k, v, bias, causal, block_q, block_k):
     [Sq, Sk] score matrix never materializes. The bias itself receives NO
     gradient (zero cotangent): the entry only routes stop-gradient masks
     here; trainable biases take the reference path."""
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    qt, kt, vt = _layout_swap(q, k, v)
     out, _ = _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k)
-    return jnp.swapaxes(out, 1, 2)
+    return _layout_swap(out)[0]
 
 
 def _flash_core_b_fwd(q, k, v, bias, causal, block_q, block_k):
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out_t, lse = _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k)
-    return jnp.swapaxes(out_t, 1, 2), (qt, kt, vt, bias, out_t, lse)
+    qt, kt, vt = _layout_swap(q, k, v)
+    out_t, lse = _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k,
+                         diff=True)
+    return _layout_swap(out_t)[0], (qt, kt, vt, bias, out_t, lse)
 
 
 def _flash_core_b_bwd(causal, block_q, block_k, res, g):
     qt, kt, vt, bias, ot, lse = res
-    dq, dk, dv = _bwd_tb(qt, kt, vt, bias, ot, lse,
-                         jnp.swapaxes(g, 1, 2), causal, block_q, block_k)
-    return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2), jnp.zeros_like(bias))
+    dq, dk, dv = _bwd_tb(qt, kt, vt, bias, ot, lse, _layout_swap(g)[0],
+                         causal, block_q, block_k)
+    return _layout_swap(dq, dk, dv) + (jnp.zeros_like(bias),)
 
 
 _flash_core_b.defvjp(_flash_core_b_fwd, _flash_core_b_bwd)
@@ -1926,9 +1973,10 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
     pad_k = (-sk) % 8
     if pad_q or pad_k:
         widths = lambda p: ((0, 0), (0, p), (0, 0), (0, 0))
-        q = jnp.pad(q, widths(pad_q))
-        k = jnp.pad(k, widths(pad_k))
-        v = jnp.pad(v, widths(pad_k))
+        with jax.named_scope(LAYOUT_SCOPE):
+            q = jnp.pad(q, widths(pad_q))
+            k = jnp.pad(k, widths(pad_k))
+            v = jnp.pad(v, widths(pad_k))
     # tier intent from the layout flag (before block tuning: kv/flat/mh
     # blocks tune under their own layout-tagged autotune signature)
     layout = _layout_flag()
@@ -1963,7 +2011,8 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
         _count_dispatch("transpose", block_q, block_k)
         out = _flash_core(q, k, v, bool(is_causal), block_q, block_k,
                           sq, sk)
-        return out[:, :sq]
+        with jax.named_scope(LAYOUT_SCOPE):
+            return out[:, :sq]
     if intended == "mh":
         _count_dispatch("mh", block_q, block_k)
         return _flash_core_mh(q, k, v, bool(is_causal), block_q, block_k)

@@ -128,6 +128,7 @@ def _pallas_norm_fwd(x, w, b, bias, res, eps, kind, want_z,
         grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interpret() if interpret is None else interpret,
+        name="fused_norm_fwd",
     )(*operands)
     if want_z:
         return outs[0].reshape(shape), outs[1].reshape(shape)

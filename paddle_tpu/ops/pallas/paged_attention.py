@@ -210,6 +210,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, block_k=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), out_dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="paged_attention_fwd",
     )(*inputs)
     return out.reshape(b, hq, d)
 

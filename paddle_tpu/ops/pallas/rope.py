@@ -73,6 +73,7 @@ def _rope_call(x, cos, sin, adjoint, interpret=None):
         out_specs=pl.BlockSpec((br, h, d), lambda r: (r, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h, d), x.dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="rope_rotate",
     )(x2, cos2, sin2)
     return out.reshape(b, s, h, d)
 

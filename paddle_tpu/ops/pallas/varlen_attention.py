@@ -132,6 +132,7 @@ def _vl_fwd(qh, kh, vh, seg_q, seg_k, causal, block_q, block_k,
         ],
         interpret=_interpret(),
         compiler_params=_dimsem(),
+        name="varlen_attention_fwd",
     )(qh, kh, vh, seg_q, seg_k)
     return out, lse
 
@@ -166,6 +167,7 @@ def _vl_bwd(qh, kh, vh, ot, lse, dot, seg_q, seg_k, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((h, tq, d), qh.dtype),
         interpret=_interpret(),
         compiler_params=_dimsem(),
+        name="varlen_attention_dq",
     )(qh, kh, vh, ot, lse, dot, seg_q, seg_k)
 
     dk, dv = pl.pallas_call(
@@ -180,6 +182,7 @@ def _vl_bwd(qh, kh, vh, ot, lse, dot, seg_q, seg_k, causal, block_q,
                    jax.ShapeDtypeStruct((h, tk, d), vh.dtype)],
         interpret=_interpret(),
         compiler_params=_dimsem(),
+        name="varlen_attention_dkdv",
     )(qh, kh, vh, ot, lse, dot, seg_q, seg_k)
     return dq, dk, dv
 

@@ -284,6 +284,7 @@ def _fwd_pallas(qkv, bias, mask, ws, shift, num_heads, band):
                                lambda bi, ri: (bi, ri, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, c), qkv.dtype),
         interpret=_interpret(),
+        name="window_attention_fwd",
     )(*operands)
 
 
@@ -322,6 +323,7 @@ def _bwd_pallas(qkv, bias, mask, g, ws, shift, num_heads):
             jax.ShapeDtypeStruct((B, num_heads, p, p), jnp.float32),
         ],
         interpret=_interpret(),
+        name="window_attention_bwd",
     )(*operands)
     return dqkv, dbias.sum(axis=0)
 

@@ -301,13 +301,19 @@ def test_xla_cost_tracer_guard_and_disabled_passthrough():
     # telemetry off: plain jit passthrough, nothing captured
     assert float(inst(x)) == 8.0
     assert xla_cost.last_costs("sq") is None
+    # ... and the program ledger stays empty: the wrapper forwarded
+    # straight to the jitted callable, it compiled nothing itself
+    assert xla_cost.program_ledger("sq") is None
+    assert inst._compiled == {}
     # telemetry on under an outer trace: Compiled refuses tracers, the
     # guard must route through the composable jit path
     trace.enable()
     g = jax.grad(lambda x: inst(x))(x)
     np.testing.assert_allclose(np.asarray(g), 2 * np.ones((8,)), rtol=1e-6)
+    assert xla_cost.program_ledger("sq") is None   # the tracer guard too
     assert float(inst(x)) == 8.0  # concrete call still captures
     assert xla_cost.last_costs("sq")["flops"] >= 0
+    assert xla_cost.program_ledger("sq")["n_compiles"] == 1
 
 
 def test_jit_to_static_compile_span():
