@@ -16,15 +16,26 @@ Design (TPU-first, not a CUDA translation):
     preferred_element_type — casting operands to f32 first (round-2 design)
     forces the MXU off its bf16 path and measured 4x slower. The softmax
     scale is applied to the f32 logits, not the bf16 operands.
-  * backward: recomputation-style — one kernel produces dQ (grid over
-    q_blocks), one produces dK/dV (grid over kv_blocks), both replaying
-    blocked logits from saved (out, logsumexp) rather than storing P; same
-    bf16-dot + diagonal-only-masking treatment as forward.
+  * backward: recomputation-style — blocked logits are replayed from
+    saved (out, logsumexp) rather than storing P; same bf16-dot +
+    diagonal-only-masking treatment as forward. The transpose and flat
+    cores replay them ONCE: one fused kernel (grid over kv_blocks, inner
+    loop over q_blocks) makes dK/dV and accumulates dQ for the whole
+    sequence in f32 VMEM, working on the transposed tile sT = k·qT so
+    that lse and delta are lane-dense rows (_fused_bwd_loop: 5 block
+    matmuls a tile, FlashAttention's count). Where the sequence-long
+    q/o/do/dQ do not fit the kernel's VMEM (_kv_vmem_bytes,
+    _t_vmem_bytes) they run the split pair the kv, mh, biased and varlen
+    tiers keep: one kernel for dQ (grid over q_blocks, _dq_loop), one
+    for dK/dV (grid over kv_blocks, _dkv_loop) — 7 matmuls and the
+    per-logit chain twice. Same dots, same order: bit-identical.
   * block sizes are autotuned per signature on a fwd+bwd run (cached on
     disk; paddle/phi/kernels/autotune role). At B32 H12 S1024 D64 bf16 the
     tuned kernel measures ~4x over the 128x128 static default.
   * dtype: IO in input dtype, accumulation in f32; softmax stats rank-2
-    `(block_q, 1)` f32 (rank-1 stats blocks do not lower to Mosaic).
+    `(block_q, 1)` f32 (rank-1 stats blocks do not lower to Mosaic);
+    the VJP's forward writes lse as lane-dense rows, which the fused
+    backward reads (the column pads 128-fold, in VMEM and in HBM).
   * non-TPU backends run the same kernels through the Pallas interpreter so
     CPU tests validate the exact kernel code (fake-backend strategy,
     SURVEY §4.5).
@@ -84,7 +95,8 @@ _DEFAULT_LAYOUT = "auto"
 
 
 # Names in the program (docs/OBSERVABILITY.md "Scopes"): every
-# pallas_call is `flash_<tier>_<fwd|dq|dkdv>`, and the transposes,
+# pallas_call is `flash_<tier>_<fwd|dq|dkdv>` (`flash_<tier>_bwd` for
+# the fused backward), and the transposes,
 # reshapes and pads a tier's wrapper puts around its kernels sit under
 # LAYOUT_SCOPE — XLA ops, device time outside the kernel family.
 LAYOUT_SCOPE = "flash.layout"
@@ -263,10 +275,30 @@ def _online_softmax(q, load_kv, *, iq, block_q, block_k, scale, causal,
     return acc / l_safe, m + jnp.log(l_safe)
 
 
+def _col_to_row(col):
+    """[n, 1] f32 column -> the same values as a lane-dense [1, n] row,
+    exactly (a plain (n, 1) -> (1, n) reshape does not lower to Mosaic):
+    128 at a time, select the diagonal of the lane-broadcast piece and
+    sum over sublanes (one value and zeros).  VPU work on purpose: a
+    transpose of the broadcast column costs the fused backward, whose
+    dQ contraction already loads the transpose unit, 0.13 ms a layer
+    more at the benchmark's shapes (PERF.md, PR 27)."""
+    n = col.shape[0]
+    rows = []
+    for lo in range(0, n, 128):
+        c = min(128, n - lo)
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0) ==
+               jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
+        rows.append(jnp.sum(jnp.where(eye, col[lo:lo + c], 0.0), axis=0,
+                            keepdims=True))
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
-                causal, seq_q, seq_k):
+                causal, seq_q, seq_k, lse_rows=False):
     # q_ref: [block_q, d]; k_ref/v_ref: [seq_k, d]; o_ref: [block_q, d];
-    # lse_ref: [block_q, 1].
+    # lse_ref: [block_q, 1], or the lane-dense row [1, block_q] the
+    # fused backward reads (lse_rows).
     block_q = q_ref.shape[0]
     out, lse = _online_softmax(
         q_ref[:],
@@ -275,7 +307,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
         iq=pl.program_id(2), block_q=block_q, block_k=block_k,
         scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k)
     o_ref[:] = out.astype(o_ref.dtype)
-    lse_ref[:] = lse.astype(jnp.float32)
+    lse = lse.astype(jnp.float32)
+    lse_ref[:] = _col_to_row(lse) if lse_rows else lse
 
 
 def _fwd_kernel_bias(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, *, scale,
@@ -316,7 +349,10 @@ def _pick_block(seq, pref):
 def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
            seq_k_real=None, diff=False):
     """Forward on head-major [B,H,S,D] operands (the kernels' native
-    layout). Returns (out_t [B,H,Sq,D], lse [B,H,Sq,1]).
+    layout). Returns (out_t [B,H,Sq,D], lse [B,H,Sq,1]); under
+    differentiation (diff) lse comes lane-dense, one row a q block:
+    [B,H,Sq/block_q,1,block_q], as _bwd_t reads it (the column pads
+    every value to 128 lanes, in VMEM and in HBM).
 
     GQA: kt/vt may carry fewer heads ([B,Hkv,S,D], Hq % Hkv == 0) — the
     K/V index maps group query heads onto their KV head (hi // rep), so
@@ -340,9 +376,18 @@ def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
     grid = (b, h, pl.cdiv(sq, block_q))
+    if diff:
+        lse_spec = pl.BlockSpec((None, None, None, 1, block_q),
+                                lambda bi, hi, qi: (bi, hi, qi, 0, 0))
+        lse_shape = (b, h, sq // block_q, 1, block_q)
+    else:
+        lse_spec = pl.BlockSpec((None, None, block_q, 1),
+                                lambda bi, hi, qi: (bi, hi, qi, 0))
+        lse_shape = (b, h, sq, 1)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq_r, seq_k=sk_r),
+                          causal=causal, seq_q=sq_r, seq_k=sk_r,
+                          lse_rows=diff),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, block_q, d),
@@ -355,12 +400,11 @@ def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
         out_specs=[
             pl.BlockSpec((None, None, block_q, d),
                          lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_q, 1),
-                         lambda bi, hi, qi: (bi, hi, qi, 0)),
+            lse_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         interpret=_interpret(),
         compiler_params=_compiler_params(),
@@ -560,6 +604,17 @@ def _bwd_dq_kernel_mh(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
         dq_ref[:, hh, :] = dq.astype(dq_ref.dtype)
 
 
+def _causal_q_blocks(jk, block_q, block_k, off, num_iters):
+    """The q blocks that see KV block `jk` under the bottom-right
+    aligned causal mask, as (start_block, first_full): KV block jk is
+    seen by q rows >= jk*block_k - off; q blocks from first_full on
+    (min q_id + off >= max k_id) are fully unmasked, those between run
+    the masked body."""
+    start_block = jnp.clip((jk * block_k - off) // block_q, 0, num_iters)
+    first_full = -(-((jk + 1) * block_k - 1 - off) // block_q)  # ceil
+    return start_block, jnp.clip(first_full, start_block, num_iters)
+
+
 def _dkv_loop(k, v, load_q, *, jk, block_q, block_k, scale, causal,
               seq_q, seq_k, seg_k=None, load_seg_q=None, load_bias=None):
     """Shared dK/dV recurrence. One body for the per-head and
@@ -614,19 +669,150 @@ def _dkv_loop(k, v, load_q, *, jk, block_q, block_k, scale, causal,
              jnp.zeros((block_k, d), jnp.float32))
     tail_masked = segmented or seq_q % block_q != 0
     if causal:
-        # bottom-right alignment: kv block jk is seen by q rows
-        # >= jk*block_k - off. q blocks with min q_id + off >= max k_id
-        # are fully unmasked; between the diagonal and there runs masked.
         # (Segmented mode: boundaries cut anywhere, all blocks masked.)
-        start_block = jnp.clip((jk * block_k - off) // block_q,
-                               0, num_iters)
-        first_full = -(-((jk + 1) * block_k - 1 - off) // block_q)  # ceil
-        first_full = jnp.clip(first_full, start_block, num_iters)
+        start_block, first_full = _causal_q_blocks(jk, block_q, block_k,
+                                                   off, num_iters)
         carry = jax.lax.fori_loop(start_block, first_full, make_body(True),
                                   carry)
         return jax.lax.fori_loop(first_full, num_iters,
                                  make_body(tail_masked), carry)
     return jax.lax.fori_loop(0, num_iters, make_body(tail_masked), carry)
+
+
+def _fused_bwd_loop(k, v, load_q, add_dq, *, jk, block_q, block_k, scale,
+                    causal, seq_q, seq_k):
+    """The fused backward recurrence: ONE replay of the logits per
+    (q block, KV block) makes all three gradients, where _dq_loop and
+    _dkv_loop each replay them.  Walks the q blocks that see KV block
+    `jk` (the same start_block / first_full split as _dkv_loop) on the
+    TRANSPOSED tile sT = k·qT [block_k, block_q]: the q index lies
+    along lanes, so lse and delta are lane-dense rows, dV += pT·do and
+    dK += dsT·q are plain contractions and only dQ contracts over the
+    transposed side (one tile transpose where _dkv_loop has two).
+    load_q(i) -> (q, do, lse_row, delta_row), rows [1, block_q] f32;
+    add_dq(i, x) adds x [block_q, d] f32 into q block i of the caller's
+    dQ accumulator.  The caller walks KV blocks in ascending order, so
+    dQ sums in _dq_loop's order with _dq_loop's dots: bit-identical to
+    the split pair on the rows that are real.  Returns (dk, dv), each
+    [block_k, d] f32."""
+    d = k.shape[-1]
+    off = seq_k - seq_q
+    kv_tail = seq_k % block_k != 0
+
+    def make_body(masked):
+        def body(i, carry):
+            dk, dv = carry
+            q, do, lse, delta = load_q(i)
+            st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            st = st * scale
+            if masked:
+                k_ids = jk * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                q_ids = i * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                valid = q_ids < seq_q
+                if kv_tail:  # dQ must not see padded key rows
+                    valid = jnp.logical_and(valid, k_ids < seq_k)
+                if causal:
+                    valid = jnp.logical_and(valid, q_ids + off >= k_ids)
+                st = jnp.where(valid, st, NEG_INF)
+            pt = jnp.exp(st - lse)
+            dv_new = dv + jax.lax.dot_general(
+                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            dst = (pt * (dpt - delta) * scale).astype(q.dtype)
+            dk_new = dk + jax.lax.dot_general(
+                dst, q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            add_dq(i, jax.lax.dot_general(
+                dst, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            return dk_new, dv_new
+        return body
+
+    num_iters = pl.cdiv(seq_q, block_q)
+    carry = (jnp.zeros((block_k, d), jnp.float32),
+             jnp.zeros((block_k, d), jnp.float32))
+    tail_masked = kv_tail or seq_q % block_q != 0
+    if causal:
+        start_block, first_full = _causal_q_blocks(jk, block_q, block_k,
+                                                   off, num_iters)
+        carry = jax.lax.fori_loop(start_block, first_full, make_body(True),
+                                  carry)
+        return jax.lax.fori_loop(first_full, num_iters,
+                                 make_body(tail_masked), carry)
+    return jax.lax.fori_loop(0, num_iters, make_body(tail_masked), carry)
+
+
+def _fused_prologue(first_kv, dq_acc, fill_delta, n_q_blocks):
+    """At a batch row's first KV block: zero the sequence-long dQ
+    accumulator and fill the lane-dense delta scratch, one q block at a
+    time (delta = rowsum(do·o) as the split kernels compute it, then
+    relaid; it is read back for every later KV block)."""
+    @pl.when(first_kv)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, dq_acc.dtype)
+
+        def body(i, _):
+            fill_delta(i)
+            return 0
+        jax.lax.fori_loop(0, n_q_blocks, body, 0)
+
+
+def _delta_row(do, o):
+    """delta = rowsum(do·o) of one q block, as a lane-dense row."""
+    return _col_to_row(jnp.sum(do.astype(jnp.float32) *
+                               o.astype(jnp.float32), axis=1,
+                               keepdims=True))
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
+                      dk_ref, dv_ref, dq_acc, delta_ref, *, scale,
+                      block_q, causal, seq_q, seq_k, rep):
+    """Grid (b, h_kv, kv_blocks), KV axis sequential.  q/o/do/dq refs
+    carry the KV head's GROUP of `rep` query heads ([rep, seq_q, d]);
+    lse_ref [rep, n_q_blocks, 1, block_q] f32, lane-dense; k/v/dk/dv
+    refs [block_k, d].  dq's block index does not depend on the KV axis, so
+    it stays resident: dq_acc [rep, seq_q, d] f32 is zeroed at the first
+    KV block and written (cast once) at the last; delta_ref is the
+    lane-dense delta scratch, shaped like lse_ref."""
+    block_k = k_ref.shape[0]
+    jk = pl.program_id(2)
+
+    def rows(i):
+        return pl.ds(i * block_q, block_q)
+
+    def fill_delta(i):
+        for r in range(rep):
+            delta_ref[r, i] = _delta_row(do_ref[r, rows(i), :],
+                                         o_ref[r, rows(i), :])
+
+    _fused_prologue(jk == 0, dq_acc, fill_delta, lse_ref.shape[1])
+    k = k_ref[:]
+    v = v_ref[:]
+    dk_acc = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
+    dv_acc = jnp.zeros((block_k, v.shape[-1]), jnp.float32)
+    for r in range(rep):
+        def add_dq(i, x, r=r):
+            dq_acc[r, rows(i), :] += x
+
+        dk, dv = _fused_bwd_loop(
+            k, v,
+            lambda i, r=r: (q_ref[r, rows(i), :], do_ref[r, rows(i), :],
+                            lse_ref[r, i], delta_ref[r, i]),
+            add_dq, jk=jk, block_q=block_q, block_k=block_k, scale=scale,
+            causal=causal, seq_q=seq_q, seq_k=seq_k)
+        dk_acc = dk_acc + dk
+        dv_acc = dv_acc + dv
+    dk_ref[:] = dk_acc.astype(dk_ref.dtype)
+    dv_ref[:] = dv_acc.astype(dv_ref.dtype)
+
+    @pl.when(jk == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel_bias(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
@@ -746,6 +932,14 @@ def _bwd_mh(q, k, v, out, lse, do, causal, block_q, block_k):
     return dq, dk, dv
 
 
+def _count_backward(tier, fused):
+    """`flash.backward{tier,kind}`: which backward a core's VJP traced,
+    the fused kernel or the split dq + dkdv pair (a cold block search's
+    candidate traces count too)."""
+    _metrics.inc("flash.backward", tier=tier,
+                 kind="fused" if fused else "split")
+
+
 def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
            seq_q_real=None, seq_k_real=None):
     """Backward on head-major [B,H,S,D] operands; returns dq/dk/dv in the
@@ -753,10 +947,15 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
     (the forward already computed them), so backward only transposes the
     incoming cotangent and the outgoing grads — half the transpose HBM
     traffic of re-deriving all five operands from [B,S,H,D]
-    (PERF.md: ~25 ms/step of transposes at the bench shape).
+    (PERF.md: ~25 ms/step of transposes at the bench shape).  lse is
+    lane-dense, as _fwd_t(diff=True) returns it.
     seq_*_real: logical lengths for padded arrays (see _fwd_t) — kernels
     bound loops/masks on the real lengths, so padded key rows contribute
-    nothing and the caller slices padded grad rows off."""
+    nothing and the caller slices padded grad rows off.
+
+    One fused kernel (_bwd_fused_kernel) wherever the KV head's group of
+    sequence-long q/o/do/dq fits the kernel's VMEM by _t_vmem_bytes;
+    the split dq + dkdv pair past that."""
     b, h, sq, d = qt.shape
     h_kv = kt.shape[1]
     assert h % h_kv == 0, (h, h_kv)
@@ -768,6 +967,38 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
 
+    fused = _t_vmem_bytes(sq, sk, rep, d, qt.dtype.itemsize, block_q,
+                          block_k, fused=True) <= _T_VMEM_LIMIT
+    _count_backward("transpose", fused)
+    if fused:
+        group_q = pl.BlockSpec((None, rep, sq, d),
+                               lambda bi, hi, j: (bi, hi, 0, 0))
+        n_q = sq // block_q
+        group_lse = pl.BlockSpec((None, rep, n_q, 1, block_q),
+                                 lambda bi, hi, j: (bi, hi, 0, 0, 0))
+        kv_spec = pl.BlockSpec((None, None, block_k, d),
+                               lambda bi, hi, j: (bi, hi, j, 0))
+        return pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, scale=scale,
+                              block_q=block_q, causal=causal, seq_q=sq_r,
+                              seq_k=sk_r, rep=rep),
+            grid=(b, h_kv, pl.cdiv(sk, block_k)),
+            in_specs=[group_q, kv_spec, kv_spec, group_q, group_lse,
+                      group_q],
+            out_specs=[group_q, kv_spec, kv_spec],
+            out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
+                       jax.ShapeDtypeStruct((b, h_kv, sk, d), kt.dtype),
+                       jax.ShapeDtypeStruct((b, h_kv, sk, d), vt.dtype)],
+            scratch_shapes=[pltpu.VMEM((rep, sq, d), jnp.float32),
+                            pltpu.VMEM((rep, n_q, 1, block_q),
+                                       jnp.float32)],
+            interpret=_interpret(),
+            compiler_params=_compiler_params(),
+            name=_bwd_name("flash_transpose_bwd"),
+        )(qt, kt, vt, ot, lse, dot)
+
+    with jax.named_scope(LAYOUT_SCOPE):
+        lse = lse.reshape(b, h, sq, 1)
     q_spec = pl.BlockSpec((None, None, block_q, d),
                           lambda bi, hi, i: (bi, hi, i, 0))
     k_spec_full = pl.BlockSpec((None, None, sk, d),
@@ -812,10 +1043,12 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
 
 
 def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
+    b, sq, h, _ = q.shape
+    bq = _pick_block(sq, block_q)
     dq, dk, dv = _bwd_t(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                         jnp.swapaxes(v, 1, 2), jnp.swapaxes(out, 1, 2),
-                        lse, jnp.swapaxes(do, 1, 2), causal,
-                        block_q, block_k)
+                        lse.reshape(b, h, sq // bq, 1, bq),
+                        jnp.swapaxes(do, 1, 2), causal, block_q, block_k)
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
             jnp.swapaxes(dv, 1, 2))
 
@@ -1249,9 +1482,10 @@ _from_hm.defvjp(_from_hm_fwd, _from_hm_bwd)
 
 
 def _fwd_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                     block_k, causal, seq_q, seq_k, n_heads, rep, d):
+                     block_k, causal, seq_q, seq_k, n_heads, rep, d,
+                     lse_rows=False):
     # q_ref/o_ref: [block_q, H*D]; k_ref/v_ref: [seq_k, Hkv*D];
-    # lse_ref: [H, block_q, 1]
+    # lse_ref: [H, block_q, 1], or lane-dense [H, block_q] (lse_rows)
     block_q = q_ref.shape[0]
     iq = pl.program_id(1)
     for hh in range(n_heads):
@@ -1264,12 +1498,18 @@ def _fwd_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
             iq=iq, block_q=block_q, block_k=block_k, scale=scale,
             causal=causal, seq_q=seq_q, seq_k=seq_k)
         o_ref[:, hh * d:(hh + 1) * d] = out.astype(o_ref.dtype)
-        lse_ref[hh] = lse.astype(jnp.float32)
+        lse = lse.astype(jnp.float32)
+        if lse_rows:
+            lse_ref[hh:hh + 1, :] = _col_to_row(lse)
+        else:
+            lse_ref[hh] = lse
 
 
 def _fwd_flat(q, k, v, h, causal, block_q, block_k, diff=False):
     """Forward on flat [B,Sq,H*D] q and [B,Sk,Hkv*D] k/v.
-    Returns (out [B,Sq,H*D], lse [B,H,Sq,1])."""
+    Returns (out [B,Sq,H*D], lse [B,H,Sq,1]); under differentiation
+    (diff) lse comes lane-dense, one row a head and q block:
+    [B,Sq/block_q,H,block_q], as _bwd_flat reads it."""
     b, sq, hd = q.shape
     d = hd // h
     sk, hkvd = k.shape[1], k.shape[2]
@@ -1278,10 +1518,18 @@ def _fwd_flat(q, k, v, h, causal, block_q, block_k, diff=False):
     scale = 1.0 / math.sqrt(d)
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
+    if diff:
+        lse_spec = pl.BlockSpec((None, None, h, block_q),
+                                lambda bi, qi: (bi, qi, 0, 0))
+        lse_shape = (b, sq // block_q, h, block_q)
+    else:
+        lse_spec = pl.BlockSpec((None, h, block_q, 1),
+                                lambda bi, qi: (bi, 0, qi, 0))
+        lse_shape = (b, h, sq, 1)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel_flat, scale=scale, block_k=block_k,
                           causal=causal, seq_q=sq, seq_k=sk, n_heads=h,
-                          rep=rep, d=d),
+                          rep=rep, d=d, lse_rows=diff),
         grid=(b, pl.cdiv(sq, block_q)),
         in_specs=[
             pl.BlockSpec((None, block_q, hd),
@@ -1292,12 +1540,11 @@ def _fwd_flat(q, k, v, h, causal, block_q, block_k, diff=False):
         out_specs=[
             pl.BlockSpec((None, block_q, hd),
                          lambda bi, qi: (bi, qi, 0)),
-            pl.BlockSpec((None, h, block_q, 1),
-                         lambda bi, qi: (bi, 0, qi, 0)),
+            lse_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         interpret=_interpret(),
         compiler_params=_kv_dimsem(),
@@ -1358,8 +1605,64 @@ def _bwd_dkv_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
         dv_ref[:, ksl] = dv_acc.astype(dv_ref.dtype)
 
 
+def _bwd_fused_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                           dq_ref, dk_ref, dv_ref, dq_acc, delta_ref, *,
+                           scale, block_q, causal, seq_q, seq_k, n_heads,
+                           rep, d):
+    """Grid (b, kv_blocks), KV axis sequential; the fused recurrence on
+    flat operands (see _bwd_fused_kernel).  q/o/do/dq refs: [seq_q,
+    H*D], dq resident over the KV axis; lse_ref [n_q_blocks, H, block_q]
+    f32; k/v/dk/dv refs [block_k, Hkv*D]; dq_acc [seq_q, H*D] f32 and
+    delta_ref (shaped like lse_ref) scratch.  Heads walk a static loop
+    over 64-lane slices, as in the split flat kernels."""
+    block_k = k_ref.shape[0]
+    jk = pl.program_id(1)
+
+    def rows(i):
+        return pl.ds(i * block_q, block_q)
+
+    def fill_delta(i):
+        for hh in range(n_heads):
+            sl = slice(hh * d, (hh + 1) * d)
+            delta_ref[i, hh:hh + 1, :] = _delta_row(do_ref[rows(i), sl],
+                                                    o_ref[rows(i), sl])
+
+    _fused_prologue(jk == 0, dq_acc, fill_delta, lse_ref.shape[0])
+    for hkv in range(n_heads // rep):
+        ksl = slice(hkv * d, (hkv + 1) * d)
+        k = k_ref[:, ksl]
+        v = v_ref[:, ksl]
+        dk_acc = jnp.zeros((block_k, d), jnp.float32)
+        dv_acc = jnp.zeros((block_k, d), jnp.float32)
+        for r in range(rep):
+            hh = hkv * rep + r
+            qsl = slice(hh * d, (hh + 1) * d)
+
+            def add_dq(i, x, qsl=qsl):
+                dq_acc[rows(i), qsl] += x
+
+            dk, dv = _fused_bwd_loop(
+                k, v,
+                lambda i, qsl=qsl, hh=hh: (
+                    q_ref[rows(i), qsl], do_ref[rows(i), qsl],
+                    lse_ref[i, hh:hh + 1, :], delta_ref[i, hh:hh + 1, :]),
+                add_dq, jk=jk, block_q=block_q, block_k=block_k,
+                scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k)
+            dk_acc = dk_acc + dk
+            dv_acc = dv_acc + dv
+        dk_ref[:, ksl] = dk_acc.astype(dk_ref.dtype)
+        dv_ref[:, ksl] = dv_acc.astype(dv_ref.dtype)
+
+    @pl.when(jk == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
 def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
-    """Backward companion of _fwd_flat: everything stays [B,S,H*D]."""
+    """Backward companion of _fwd_flat: everything stays [B,S,H*D]; lse
+    is lane-dense, as _fwd_flat(diff=True) returns it.  One fused kernel
+    where _kv_vmem_bytes says the sequence-long dQ fits beside q/o/do,
+    the split pair where only theirs does."""
     b, sq, hd = q.shape
     d = hd // h
     sk, hkvd = k.shape[1], k.shape[2]
@@ -1368,6 +1671,36 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
     block_k = _pick_block(sk, block_k)
     rep = hd // hkvd
 
+    fused = _kv_vmem_bytes(sq, sk, h, h // rep, d, q.dtype.itemsize,
+                           block_q, block_k,
+                           fused=True) <= _KV_VMEM_LIMIT
+    _count_backward("flat", fused)
+    if fused:
+        q_full = pl.BlockSpec((None, sq, hd), lambda bi, kj: (bi, 0, 0))
+        n_q = sq // block_q
+        lse_full = pl.BlockSpec((None, n_q, h, block_q),
+                                lambda bi, kj: (bi, 0, 0, 0))
+        kv_spec = pl.BlockSpec((None, block_k, hkvd),
+                               lambda bi, kj: (bi, kj, 0))
+        return pl.pallas_call(
+            functools.partial(_bwd_fused_kernel_flat, scale=scale,
+                              block_q=block_q, causal=causal, seq_q=sq,
+                              seq_k=sk, n_heads=h, rep=rep, d=d),
+            grid=(b, pl.cdiv(sk, block_k)),
+            in_specs=[q_full, kv_spec, kv_spec, q_full, lse_full, q_full],
+            out_specs=[q_full, kv_spec, kv_spec],
+            out_shape=[jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
+                       jax.ShapeDtypeStruct((b, sk, hkvd), k.dtype),
+                       jax.ShapeDtypeStruct((b, sk, hkvd), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((sq, hd), jnp.float32),
+                            pltpu.VMEM((n_q, h, block_q), jnp.float32)],
+            interpret=_interpret(),
+            compiler_params=_kv_dimsem(),
+            name=_bwd_name("flash_flat_bwd"),
+        )(q, k, v, out, lse, do)
+
+    with jax.named_scope(LAYOUT_SCOPE):
+        lse = jnp.swapaxes(lse, 1, 2).reshape(b, h, sq, 1)
     q_spec = pl.BlockSpec((None, block_q, hd), lambda bi, qi: (bi, qi, 0))
     lse_spec = pl.BlockSpec((None, h, block_q, 1),
                             lambda bi, qi: (bi, 0, qi, 0))
@@ -1468,23 +1801,96 @@ def _gate_reject(gate: str, reason: str, q, k, blocks) -> None:
                    blocks=list(blocks))
 
 
-def _kv_vmem_bytes(sq, sk, h, h_kv, d, esz, bq, bk) -> int:
+def _up(n, m):
+    return -(-n // m) * m
+
+
+def _stat_rows_bytes(lead, sub, bq) -> int:
+    """VMEM of one lane-dense stats block [lead, sub, bq] f32 (lse or
+    delta of the fused backward): sublanes pad to 8, lanes to 128."""
+    return lead * _up(sub, 8) * _up(bq, 128) * 4
+
+
+def _kv_vmem_bytes(sq, sk, h, h_kv, d, esz, bq, bk, fused=False) -> int:
     """Scoped-VMEM estimate of the kv-native AND flat kernels (same
     block geometry) at blocks (bq, bk): the larger of the forward (full
-    K+V per batch row) and the dKV kernel, which keeps full-sequence
-    q/o/do and the lane-padded lse resident for the head walk.  Pipelined
-    operands count twice (double buffering); the f32 logits-sized
-    temporaries (s, p, dp, ds) count once.  Checked against what the v5e
-    compiler accepts and refuses at [32,1024,12,64] bf16
+    K+V per batch row) and the backward's KV-grid kernel, which keeps
+    full-sequence q/o/do resident for the head walk.  Pipelined operands
+    count twice (double buffering); the f32 logits-sized temporaries
+    (s, p, dp, ds) count once.  The split pair's dKV kernel holds the
+    column lse, lane-padded.  Checked against what the v5e compiler
+    accepts and refuses at [32,1024,12,64] bf16
     (tests/test_chip_compile.py): (512,512) and (256,512) compile, the
-    backward at (512,1024) and (1024,1024) is RESOURCE_EXHAUSTED."""
+    backward at (512,1024) and (1024,1024) is RESOURCE_EXHAUSTED.
+
+    This, the split pair's estimate, is what the kv AND flat gates and
+    their candidate lists hold to _KV_VMEM_LIMIT: the tiers' reach is
+    the split pair's.  fused=True describes the flat tier's fused
+    backward, which holds lane-dense lse and delta instead, plus the
+    sequence-long dQ (its output block and the f32 accumulator);
+    _bwd_flat runs it inside that reach where it fits (19.4–20.2 MiB by
+    the compiler at [32,1024,12,64] (512,512), 23.7 here).  It is no
+    gate: at [16,2048,12,64] it reads 33.97 MiB for (256,256), under
+    the limit, and the chip's compiler refuses that kernel."""
     fwd = (2 * (2 * sk * h_kv * d + 2 * bq * h * d) * esz
            + 2 * bq * bk * 4)
-    dkv = (2 * 3 * sq * h * d * esz       # q, o, do
-           + 2 * h * sq * 128 * 4         # lse [h, sq, 1] f32, lanes pad to 128
-           + 2 * 4 * bk * h_kv * d * esz  # k, v, dk, dv blocks
-           + 4 * bq * bk * 4)             # s, p, dp, ds
-    return max(fwd, dkv)
+    bwd = (2 * 3 * sq * h * d * esz       # q, o, do
+           + 2 * 4 * bk * h_kv * d * esz)  # k, v, dk, dv blocks
+    if fused:
+        bwd += (sq * h * d * (2 * esz + 4)          # dq block + f32 acc
+                + 3 * _stat_rows_bytes(sq // bq, h, bq)  # lse (x2), delta
+                + _fused_tile_bytes(bq, bk, esz))
+    else:
+        bwd += (2 * h * sq * 128 * 4      # lse [h, sq, 1] f32, lanes pad to 128
+                + 4 * bq * bk * 4)        # s, p, dp, ds
+    return max(fwd, bwd)
+
+
+def _fused_tile_bytes(bq, bk, esz) -> int:
+    """The fused backward's logits-sized temporaries: sT and dpT in f32
+    and one operand-dtype tile, not four f32 tiles — the v5e compiler's
+    footprint grows by 8.1–10 bytes a logit from (512, 512) to
+    (1024, 1024) blocks at [16,2048,12,64] bf16 (read by lowering
+    vmem_limit_bytes until the compile is refused, PERF.md PR 27)."""
+    return (8 + esz) * bq * bk
+
+
+# scoped VMEM of a kernel that sets no limit of its own (the transpose
+# core's): the compiler's default on v5e
+_T_VMEM_LIMIT = 16 * 1024 * 1024
+
+
+def _t_vmem_bytes(sq, sk, rep, d, esz, bq, bk, biased=False,
+                  fused=False) -> int:
+    """Scoped-VMEM estimate of the transpose core at blocks (bq, bk).
+
+    fused=False, the forward and the split pair, which the block search
+    holds to 12 MB (_tuned_blocks): f32 logits block (s and p live
+    together) + full K/V + q/o/acc. GQA: the grouped dK/dV kernel
+    additionally keeps rep x seq_q x d of q/o/do resident (block-size
+    independent, but it eats the same budget the logits compete for).
+    Biased kernels hold an f32 bias band: [bq, sk] (fwd/dQ) or [sq, bk]
+    (dKV) — the larger.
+
+    fused=True, the fused backward, which _bwd_t holds to _T_VMEM_LIMIT:
+    one KV head's group of `rep` query heads keeps sequence-long q/o/do,
+    the dQ block and its f32 accumulator resident (head size pads to
+    128 lanes), beside the k/v/dk/dv blocks, the lane-dense lse and
+    delta and the logits-sized tiles (_fused_tile_bytes).  Against the
+    compiler at [16,2048,12,64] bf16: 8.7 / 12.2 / 17.2 MiB here for
+    (512,512) / (512,1024) / (1024,1024), 8.0–8.3 / 12.0–12.3 /
+    15.0–15.3 MiB there."""
+    if not fused:
+        group = 3 * rep * sq * d * esz if rep > 1 else 0
+        bias_band = max(bq * sk, sq * bk) * 4 if biased else 0
+        return (2 * bq * bk * 4 + 2 * sk * d * esz + 2 * bq * d * esz
+                + bq * d * 4 + group + bias_band)
+    group = rep * sq * _up(d, 128)
+    return (2 * 3 * group * esz            # q, o, do
+            + group * (2 * esz + 4)        # dq block + f32 acc
+            + 2 * 4 * bk * _up(d, 128) * esz
+            + 3 * _stat_rows_bytes(rep * (sq // bq), 1, bq)
+            + _fused_tile_bytes(bq, bk, esz))
 
 
 def _kv_native_ok(q, k, block_q=512, block_k=512, _gate="kv") -> bool:
@@ -1738,6 +2144,12 @@ def _ref_attention(q, k, v, mask, is_causal):
     return out.astype(q.dtype)
 
 
+# the block search's cache key. Re-keyed from "flash_fwdbwd" when the
+# transpose and flat cores' backward became one fused kernel: a pair
+# tuned on the split backward is not reused.
+_AUTOTUNE_OP = "flash_fwd_fusedbwd"
+
+
 def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
                   biased=False, layout=None):
     """Autotuned (block_q, block_k) for this attention signature
@@ -1767,21 +2179,6 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
     pairs = ((512, 1024), (1024, 1024), (512, 512), (256, 512),
              (256, 256), (128, 128))
 
-    def vmem_est(bq, bk):
-        # f32 logits block (s and p live together) + full K/V + q/o/acc;
-        # must leave headroom in the ~16 MB/core VMEM budget. GQA: the
-        # grouped dK/dV kernel additionally keeps rep x seq_q x d of
-        # q/o/do resident (block-size independent, but it eats the same
-        # budget the logits compete for).
-        group = (3 * (h // h_kv) * sq * d * itemsize
-                 if h_kv and h_kv != h else 0)
-        # biased kernels hold an f32 bias band: [bq, sk] (fwd/dQ) or
-        # [sq, bk] (dKV) — budget the larger
-        bias_band = max(bq * sk, sq * bk) * 4 if biased else 0
-        return (2 * bq * bk * 4 + 2 * sk * d * itemsize
-                + 2 * bq * d * itemsize + bq * d * 4 + group
-                + bias_band)
-
     lt = layout if layout in ("kv", "flat", "mh") else None
     itemsize = jnp.dtype(dtype).itemsize
 
@@ -1792,7 +2189,12 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
             return _kv_vmem_bytes(
                 sq, sk, h, h_kv or h, d, itemsize, bq, bk) <= (
                     0.9 if tight else 1.0) * _KV_VMEM_LIMIT
-        return vmem_est(bq, bk) <= (8 if tight else 12) * 1024 * 1024
+        # must leave headroom in the ~16 MB/core VMEM budget; a pair
+        # whose fused backward does not fit runs the split pair
+        # (_bwd_t), so the fused kernel never narrows the candidates
+        return _t_vmem_bytes(
+            sq, sk, h // (h_kv or h), d, itemsize, bq, bk,
+            biased) <= (8 if tight else 12) * 1024 * 1024
 
     cands = [(bq, bk)
              for bq, bk in pairs
@@ -1816,8 +2218,11 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
 
     def run(cfg):
         # concrete dummy data, same signature; the returned (f, x) pair
-        # chains fwd+bwd inside autotune's one-dispatch timing loop
-        # (grad(loss)(q) is q-shaped, so y = f(y) composes)
+        # chains fwd+bwd inside autotune's one-dispatch timing loop: the
+        # gradients of (q, k, v) are (q, k, v)-shaped, so y = f(y)
+        # composes.  All three: a gradient of q alone lets XLA drop a
+        # split backward's dkdv call as dead code, and that pair is then
+        # timed as fwd + dq against fused candidates' fwd + whole bwd
         rs = np.random.RandomState(0)
         hk = h_kv or h
         qv = jnp.asarray(rs.randn(b, sq, h, d), dtype)
@@ -1827,7 +2232,7 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
         if biased:  # benchmark the kernel that will actually run
             bias_v = jnp.zeros((1, 1, sq, sk), jnp.float32)
 
-            def loss(qv):
+            def loss(qv, kv, vv):
                 return _flash_core_b(qv, kv, vv, bias_v, causal, cfg[0],
                                      cfg[1]).astype(jnp.float32).sum()
         else:
@@ -1837,11 +2242,12 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
             core = {"kv": _flash_core_kv, "flat": _flash_core_flat,
                     "mh": _flash_core_mh}.get(lt, _flash_core)
 
-            def loss(qv):
+            def loss(qv, kv, vv):
                 return core(qv, kv, vv, causal, cfg[0],
                             cfg[1]).astype(jnp.float32).sum()
 
-        return jax.grad(loss), qv
+        grads = jax.grad(loss, argnums=(0, 1, 2))
+        return (lambda qkv: grads(*qkv)), (qv, kv, vv)
 
     sig = (f"{b}x{sq}x{sk}x{h}x{d}|{jnp.dtype(dtype).name}|c{int(causal)}"
            + (f"|kv{h_kv}" if h_kv and h_kv != h else "")
@@ -1851,13 +2257,13 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
         # shape is NOT reused (it was measured on different kernels) —
         # count the refusal so cold layout caches are visible
         lsig = sig + f"|L{lt}"
-        if autotune.cached_config("flash_fwdbwd", lsig) is None and \
-                autotune.cached_config("flash_fwdbwd", sig) is not None:
+        if autotune.cached_config(_AUTOTUNE_OP, lsig) is None and \
+                autotune.cached_config(_AUTOTUNE_OP, sig) is not None:
             _metrics.inc("autotune.cross_layout_reject", layout=lt)
             _flight.record("autotune.cross_layout_reject", layout=lt,
                            signature=sig)
         sig = lsig
-    return autotune.pick("flash_fwdbwd", sig, cands, run, default)
+    return autotune.pick(_AUTOTUNE_OP, sig, cands, run, default)
 
 
 def _per_shard(mesh, q, k, v, mask, **kw):

@@ -110,7 +110,75 @@ def test_flash_cold_default_compiles_fwd_bwd(one_chip, monkeypatch,
     bq, bk = blocks
     assert flash_counters() == {
         f"flash.dispatch{{tier={tier}}}": 2,
-        f"flash.blocks{{block_k={bk},block_q={bq},tier={tier}}}": 2}
+        f"flash.blocks{{block_k={bk},block_q={bq},tier={tier}}}": 2,
+        f"flash.backward{{kind=fused,tier={tier}}}": 1}
+
+
+@pytest.mark.parametrize("tier,shape,blocks", [
+    ("flat", (32, 1024, 12, 64), (512, 512)),    # gpt3-125m.train.seq1024
+    ("transpose", (16, 2048, 12, 64), (512, 512)),   # ...train.seq2048
+    ("transpose", (16, 2048, 12, 64), (512, 1024)),  # its largest fused
+])
+def test_fused_backward_compiles_at_the_cells_shapes(one_chip,
+                                                     flash_counters, tier,
+                                                     shape, blocks):
+    """The two benchmark cells' attention at their tuned blocks
+    (512, 512) and at the largest pair whose fused backward the gates
+    admit, forward and backward: the fused backward fits the chip's
+    VMEM, and the compiled program holds what the benchmark's kernel
+    family (`benchmark/kernels/flash_train.json`) looks for: two flash
+    calls a layer pass, ONE of them a backward call that returns a tuple
+    (it counts the passes), named after the tier."""
+    import json
+    import re
+    from pathlib import Path
+
+    core = {"flat": fa._flash_core_flat, "transpose": fa._flash_core}[tier]
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):   # as a module scope names it
+            return core(q, k, v, True, *blocks).astype(jnp.float32).sum()
+
+    text = _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    *[(shape, jnp.bfloat16)] * 3)
+    assert flash_counters() == {
+        f"flash.backward{{kind=fused,tier={tier}}}": 1}
+    family = json.loads((Path(__file__).parents[1] / "benchmark" /
+                         "kernels" / "flash_train.json").read_text())
+    lines = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
+    events = [ln for ln in lines if re.search(family["events"], ln)]
+    passes = [ln for ln in events if re.search(family["passes"], ln)]
+    assert len(events) == 2 and len(passes) == 1, events
+    assert passes[0].startswith(f"%transpose_jvp_flash_{tier}_bwd__")
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,causal,block", [
+    ((8, 197, 12, 64), 12, False, None),  # ViT: padded, one block of 200
+    ((4, 1024, 32, 128), 8, True, 512),   # LLaMA-class GQA, groups of four
+])
+def test_fused_backward_compiles_padded_and_grouped(one_chip, monkeypatch,
+                                                    flash_counters,
+                                                    q_shape, kv_heads,
+                                                    causal, block):
+    """The transpose core's other callers through the real dispatch: an
+    odd length (stats rows of 200 lanes, delta relaid by the diagonal
+    sum) and a KV head's group of four query heads resident at once
+    (at 512 x 512; the cold default (512, 1024) needs 17.8 MB there and
+    takes the split pair)."""
+    monkeypatch.setattr(autotune, "_enabled", lambda: False)
+    monkeypatch.setenv("FLAGS_flash_layout", "transpose")
+    b, s, _, d = q_shape
+
+    def loss(q, k, v):
+        return fa.flash_attention_fwd(
+            q, k, v, is_causal=causal, block_q=block,
+            block_k=block).astype(jnp.float32).sum()
+
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+             (q_shape, jnp.bfloat16), *[((b, s, kv_heads, d),
+                                         jnp.bfloat16)] * 2)
+    assert flash_counters()[
+        "flash.backward{kind=fused,tier=transpose}"] == 1
 
 
 def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
@@ -143,8 +211,10 @@ def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
 
 def test_flash_candidates_fit_the_gate(monkeypatch):
     """No kv/flat candidate list holds a pair the dispatch gate's own
-    arithmetic rejects (the compiler refuses (512,1024) and (1024,1024)
-    backward at this shape)."""
+    arithmetic rejects (the compiler refuses the split pair's
+    (512,1024) and (1024,1024) backward at this shape).  Both tiers'
+    reach is the split pair's estimate; inside it the flat tier's fused
+    backward fits with room to spare."""
     seen = {}
 
     def spy(op, sig, cands, run, default):
@@ -160,6 +230,17 @@ def test_flash_candidates_fit_the_gate(monkeypatch):
         assert default in cands
         assert (512, 1024) not in cands and (1024, 1024) not in cands
         assert all(fa._kv_native_ok(q, q, *c) for c in cands)
+        assert all(fa._kv_vmem_bytes(S, S, H, H, D, 2, *c, fused=True)
+                   < fa._kv_vmem_bytes(S, S, H, H, D, 2, *c) for c in cands)
+    # the flat gate still refuses the seq2048 cell's shape at EVERY
+    # candidate (the fused estimate alone would let (256, 256) through,
+    # and the chip's compiler refuses that kernel): that cell exists to
+    # run the transpose core
+    q2 = jax.ShapeDtypeStruct((16, 2048, H, D), jnp.bfloat16)
+    pairs = ((512, 1024), (1024, 1024), (512, 512), (256, 512),
+             (256, 256), (128, 128))
+    assert not any(fa._kv_native_ok(q2, q2, *c, _gate="flat")
+                   for c in pairs)
 
 
 def _engine_shapes():

@@ -288,9 +288,9 @@ def test_autotune_cross_layout_reject(monkeypatch):
     base_sig = f"{b}x{sq}x{sk}x{h}x{d}|bfloat16|c1"
     devkind = jax.devices()[0].platform  # "cpu" in tests
     monkeypatch.setattr(autotune, "_cache", {
-        f"{devkind}|flash_fwdbwd|{base_sig}": {"config": [512, 1024]}})
+        f"{devkind}|{fa._AUTOTUNE_OP}|{base_sig}": {"config": [512, 1024]}})
     monkeypatch.setattr(autotune, "_devkind", lambda: devkind)
-    assert autotune.cached_config("flash_fwdbwd", base_sig) == (512, 1024)
+    assert autotune.cached_config(fa._AUTOTUNE_OP, base_sig) == (512, 1024)
     fa._tuned_blocks(b, sq, sk, h, d, jnp.bfloat16, True, layout="flat")
     snap = metrics.snapshot()
     assert snap["counters"][

@@ -256,6 +256,28 @@ def test_flash_flat_fwd_bwd_lowers(shape):
     _assert_mosaic(mlir)
 
 
+@pytest.mark.parametrize("tier,shape", [
+    ("flat", (32, 1024, 12, 64)),        # gpt3-125m.train.seq1024
+    ("transpose", (16, 2048, 12, 64)),   # gpt3-125m.train.seq2048
+])
+def test_flash_fused_backward_lowers(tier, shape):
+    """The fused backward (dQ, dK, dV from one kernel: sequence-long dQ
+    resident over the KV axis, lane-dense lse rows, VMEM scratch) at the
+    two benchmark cells' shapes and blocks: forward + ONE backward
+    call."""
+    core = {"flat": fa._flash_core_flat, "transpose": fa._flash_core}[tier]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(core(q, k, v, True, 512, 512).astype(jnp.float32))
+
+    mlir = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    _assert_mosaic(mlir)
+    assert mlir.count("stablehlo.custom_call @tpu_custom_call") == 2, (
+        "expected the forward and one fused backward kernel")
+    assert f"flash_{tier}_bwd" in mlir
+
+
 def test_flash_kv_native_fwd_bwd_lowers():
     """The kv-native core (K/V/dK/dV native layout, Pallas relayouts for
     Q/O) must lower for both directions."""
