@@ -245,6 +245,15 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             self.lm_head = mpu.ColumnParallelLinear(
                 cfg.hidden_size, cfg.vocab_size, has_bias=False)
 
+    def fused_head_weight(self):
+        """The [vocab, hidden] weight `GPTPretrainingCriterion(model=)`
+        projects with: the tied embedding (live: the train step binds
+        it).  The untied head is held [hidden, vocab] and has no fused
+        path."""
+        assert self.cfg.tie_embeddings, \
+            "fused head+CE currently requires tied embeddings"
+        return self.gpt.wte.weight
+
     def forward(self, input_ids, kv_caches=None, cache_pos=None,
                 attn_start=None):
         if kv_caches is not None:
@@ -473,9 +482,10 @@ class GPTPretrainingCriterion(nn.Layer):
     model= (with cfg.fused_head_ce=True on the model): the criterion
     receives HIDDEN states and fuses the LM-head projection into the
     chunked CE (`_fused_linear_ce`) — the [B,S,V] logits and their
-    cotangent never exist. Reads the tied embedding weight through the
-    live parameter, so the train step's bind_state makes it
-    differentiable like any other param."""
+    cotangent never exist. Reads the model's [vocab, hidden] head weight
+    through `model.fused_head_weight()` (GPT: the tied embedding;
+    models/afmoe.py: an untied head) — the live parameter, so the train
+    step's bind_state makes it differentiable like any other param."""
 
     def __init__(self, ignore_index=-100, fused=True, model=None):
         super().__init__()
@@ -483,8 +493,7 @@ class GPTPretrainingCriterion(nn.Layer):
         self.fused = fused
         self._model = model
         if model is not None:
-            assert model.cfg.tie_embeddings, \
-                "fused head+CE currently requires tied embeddings"
+            model.fused_head_weight()   # refuses a model without one
 
     def forward(self, logits, labels):
         lv = logits._value if hasattr(logits, "_value") else logits
@@ -502,7 +511,7 @@ class GPTPretrainingCriterion(nn.Layer):
         if self._model is not None and self.fused and is_hidden:
             from ..core.dispatch import apply
 
-            w = self._model.gpt.wte.weight  # live (bindable) param
+            w = self._model.fused_head_weight()  # live (bindable) param
 
             def f(hh, lb, wv):
                 n = 1

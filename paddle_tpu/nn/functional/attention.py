@@ -48,7 +48,8 @@ def sdp_kernel(enable_math=False, enable_flash=True,
         _sdp_policy = old
 
 
-def _sdpa_ref(q, k, v, attn_mask, dropout_p, is_causal, scale):
+def _sdpa_ref(q, k, v, attn_mask, dropout_p, is_causal, scale,
+              window=None):
     # q,k,v: [B, S, H, D] (paddle flash-attention layout); GQA inputs
     # (fewer KV heads) expand here — the Pallas path reads them grouped.
     # Flat-layout spelling: the einsums contract on the native [B,S,H,D]
@@ -67,6 +68,8 @@ def _sdpa_ref(q, k, v, attn_mask, dropout_p, is_causal, scale):
     if is_causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:   # a sliding window of `window` keys
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         logits = jnp.where(mask, logits, -1e30)
     if attn_mask is not None:
         if attn_mask.dtype == jnp.bool_:
@@ -79,8 +82,10 @@ def _sdpa_ref(q, k, v, attn_mask, dropout_p, is_causal, scale):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
-    """Inputs [batch, seq, heads, head_dim] (reference layout)."""
+                                 training=True, name=None, window=None):
+    """Inputs [batch, seq, heads, head_dim] (reference layout).
+    window (with is_causal, no mask): each query sees the `window` keys
+    that end at it (see ops.pallas.flash_attention_fwd)."""
     from ...ops import pallas as _pl
 
     # masks that need no gradient may stream through the biased fused
@@ -95,7 +100,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     def f(q, k, v, m):
         if _sdp_policy["flash"] and _pl.flash_attention_available(q):
             return _pl.flash_attention_fwd(q, k, v, m, is_causal,
-                                           bias_grad_safe=mask_sg)
+                                           bias_grad_safe=mask_sg,
+                                           window=window)
         if _sdp_policy["flash"]:
             # flash requested but unavailable for this input/backend —
             # the dispatch-tier fallback that used to be silent
@@ -114,7 +120,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                 + ("unavailable for this input (CPU/interpret mode or "
                    "unsupported shape/dtype)"
                    if _sdp_policy["flash"] else "also disabled"))
-        return _sdpa_ref(q, k, v, m, dropout_p, is_causal, None)
+        if window is not None and (m is not None or not is_causal):
+            raise ValueError("scaled_dot_product_attention: window= needs "
+                             "is_causal=True and no mask")
+        return _sdpa_ref(q, k, v, m, dropout_p, is_causal, None, window)
 
     return apply("scaled_dot_product_attention", f, query, key, value,
                  attn_mask)

@@ -108,7 +108,17 @@ def _layout_swap(*xs):
         return tuple(jnp.swapaxes(x, 1, 2) for x in xs)
 
 
-def _fwd_name(kernel, diff):
+def _windowed(kernel, window):
+    """`flash_transpose_fwd` -> `flash_transpose_window_fwd` where the
+    call has a window: windowed calls are named apart, so a device trace
+    tells a banded layer from a full one."""
+    if window is None:
+        return kernel
+    tier, _, part = kernel.rpartition("_")
+    return f"{tier}_window_{part}"
+
+
+def _fwd_name(kernel, diff, window=None):
     """A forward kernel's name: plain where the call is the whole story
     (inference), `jvp(<kernel>)` where it is the forward half of a
     differentiated call (the custom_vjp's fwd rule, which also saves the
@@ -117,13 +127,14 @@ def _fwd_name(kernel, diff):
     JAX's own transform marks, which module scopes push outward, have to
     be written here for a device trace's event to keep saying which
     direction it belongs to (`%jvp_flash_flat_fwd_.3`)."""
+    kernel = _windowed(kernel, window)
     return f"jvp({kernel})" if diff else kernel
 
 
-def _bwd_name(kernel):
+def _bwd_name(kernel, window=None):
     """A backward kernel's name, `transpose(jvp(<kernel>))`: these run
     only as the transpose of a differentiated forward (see _fwd_name)."""
-    return f"transpose(jvp({kernel}))"
+    return f"transpose(jvp({_windowed(kernel, window)}))"
 
 
 _FORCE_COMPILED = False  # see force_tpu_lowering()
@@ -135,13 +146,18 @@ def _interpret():
     return jax.devices()[0].platform != "tpu"
 
 
-def _compiler_params():
+def _compiler_params(vmem_limit_bytes=None):
     # dimension_semantics lets Mosaic reorder/parallelize the (b, h) grid
     # axes; the trailing q/kv-block axis stays sequential (online softmax /
     # accumulation carries). Interpreter mode rejects TPU compiler params.
+    # vmem_limit_bytes: only a kernel whose estimate passes the
+    # compiler's default (_T_VMEM_LIMIT) sets one.
     if _interpret():
         return None
-    return _TPUCompilerParams(dimension_semantics=_DIMSEM)
+    if vmem_limit_bytes is None:
+        return _TPUCompilerParams(dimension_semantics=_DIMSEM)
+    return _TPUCompilerParams(dimension_semantics=_DIMSEM,
+                              vmem_limit_bytes=int(vmem_limit_bytes))
 
 
 @contextlib.contextmanager
@@ -183,9 +199,41 @@ def flash_attention_available(q) -> bool:
 
 # =========================== forward kernel ===========================
 
+def _band_k_blocks(iq, block_q, block_k, off, window, num_full,
+                   num_iters):
+    """The KV blocks q block `iq` visits under a causal window (row i
+    sees key j iff 0 <= i + off - j < window), as (lo, full_lo,
+    full_hi): blocks [lo, full_lo) are cut by the band's LEFT edge,
+    [full_lo, full_hi) lie wholly inside it for every row, and
+    [full_hi, num_iters) are cut by the diagonal — the two masked runs
+    meet where the window is narrower than a block.  Blocks below `lo`
+    are wholly outside the band and never visited."""
+    lo = jnp.clip((iq * block_q + off - window + 1) // block_k, 0,
+                  num_iters)
+    full_lo = jnp.clip(pl.cdiv((iq + 1) * block_q + off - window, block_k),
+                       lo, num_iters)
+    return lo, full_lo, jnp.clip(num_full, full_lo, num_iters)
+
+
+def _band_q_blocks(jk, block_q, block_k, off, window, start_block,
+                   first_full, num_iters):
+    """The q blocks that see KV block `jk` under a causal window, as
+    (first_full, full_hi, end): after the diagonal run [start_block,
+    first_full) of _causal_q_blocks, blocks [first_full, full_hi) hold
+    the KV block wholly inside every row's band, [full_hi, end) are cut
+    by the band's right edge (rows >= k + window - off see nothing of
+    it), and blocks from `end` on are never visited."""
+    end = jnp.clip(((jk + 1) * block_k + window - 2 - off) // block_q + 1,
+                   start_block, num_iters)
+    first_full = jnp.clip(first_full, start_block, end)
+    full_hi = jnp.clip((window + jk * block_k - off) // block_q,
+                       first_full, end)
+    return first_full, full_hi, end
+
+
 def _online_softmax(q, load_kv, *, iq, block_q, block_k, scale, causal,
                     seq_q, seq_k, seg_q=None, load_seg_k=None,
-                    load_bias=None):
+                    load_bias=None, window=None):
     """The shared flash recurrence: walk KV blocks with f32 running
     max/sum/acc; logits never materialize in HBM. One body for BOTH
     forward kernels (per-head transpose layout and all-heads block) —
@@ -210,6 +258,13 @@ def _online_softmax(q, load_kv, *, iq, block_q, block_k, scale, causal,
     ALiBi / additive masks), added to the scaled logits before the
     running softmax — the bias streams blockwise, never a full [Sq, Sk]
     logits materialization.
+
+    window (causal only): row i attends keys j with
+    0 <= i + off - j < window.  KV blocks wholly left of the band are
+    never visited and the blocks its left edge cuts run the masked body
+    (_band_k_blocks).  A row whose first visited block is wholly masked
+    carries p = 1 garbage until its first real key arrives with
+    alpha = exp(-1e30 - m) = 0, which erases it exactly.
     """
     d = q.shape[-1]
     off = seq_k - seq_q  # causal diagonal offset (0 for self-attention)
@@ -233,6 +288,9 @@ def _online_softmax(q, load_kv, *, iq, block_q, block_k, scale, causal,
                 valid = k_ids < seq_k
                 if causal:
                     valid = jnp.logical_and(valid, q_ids + off >= k_ids)
+                if window is not None:
+                    valid = jnp.logical_and(valid,
+                                            q_ids + off - k_ids < window)
                 if segmented:
                     seg_k = load_seg_k(j)  # [block_k, 1]
                     valid = jnp.logical_and(
@@ -262,6 +320,14 @@ def _online_softmax(q, load_kv, *, iq, block_q, block_k, scale, causal,
         if segmented:
             m, l, acc = jax.lax.fori_loop(0, num_iters, make_body(True),
                                           carry0)
+        elif window is not None:
+            lo, full_lo, full_hi = _band_k_blocks(
+                iq, block_q, block_k, off, window, num_full, num_iters)
+            carry = jax.lax.fori_loop(lo, full_lo, make_body(True), carry0)
+            carry = jax.lax.fori_loop(full_lo, full_hi, make_body(False),
+                                      carry)
+            m, l, acc = jax.lax.fori_loop(full_hi, num_iters,
+                                          make_body(True), carry)
         else:
             carry = jax.lax.fori_loop(0, num_full, make_body(False),
                                       carry0)
@@ -295,7 +361,7 @@ def _col_to_row(col):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
-                causal, seq_q, seq_k, lse_rows=False):
+                causal, seq_q, seq_k, lse_rows=False, window=None):
     # q_ref: [block_q, d]; k_ref/v_ref: [seq_k, d]; o_ref: [block_q, d];
     # lse_ref: [block_q, 1], or the lane-dense row [1, block_q] the
     # fused backward reads (lse_rows).
@@ -305,7 +371,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
         lambda j: (k_ref[pl.ds(j * block_k, block_k), :],
                    v_ref[pl.ds(j * block_k, block_k), :]),
         iq=pl.program_id(2), block_q=block_q, block_k=block_k,
-        scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k)
+        scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k,
+        window=window)
     o_ref[:] = out.astype(o_ref.dtype)
     lse = lse.astype(jnp.float32)
     lse_ref[:] = _col_to_row(lse) if lse_rows else lse
@@ -346,8 +413,14 @@ def _pick_block(seq, pref):
     return max(b, 8)
 
 
+def _win(kernel_kw, window):
+    """Kernel keywords with `window` added only where there is one: an
+    un-windowed call binds exactly the keywords it always did."""
+    return kernel_kw if window is None else dict(kernel_kw, window=window)
+
+
 def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
-           seq_k_real=None, diff=False):
+           seq_k_real=None, diff=False, window=None):
     """Forward on head-major [B,H,S,D] operands (the kernels' native
     layout). Returns (out_t [B,H,Sq,D], lse [B,H,Sq,1]); under
     differentiation (diff) lse comes lane-dense, one row a q block:
@@ -364,7 +437,10 @@ def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
     a block-friendly multiple (odd ViT-style lengths, e.g. 197): the
     kernels mask on the REAL bounds (k_ids < seq_k), padded key rows
     never contribute, and the caller slices padded q rows off the
-    output."""
+    output.
+
+    window: see _online_softmax; K/V stay whole in VMEM (block-sliced),
+    the loop visits the band's blocks only."""
     b, h, sq, d = qt.shape
     h_kv = kt.shape[1]
     assert h % h_kv == 0, (h, h_kv)
@@ -385,9 +461,9 @@ def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
                                 lambda bi, hi, qi: (bi, hi, qi, 0))
         lse_shape = (b, h, sq, 1)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq_r, seq_k=sk_r,
-                          lse_rows=diff),
+        functools.partial(_fwd_kernel, **_win(dict(
+            scale=scale, block_k=block_k, causal=causal, seq_q=sq_r,
+            seq_k=sk_r, lse_rows=diff), window)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, block_q, d),
@@ -408,7 +484,7 @@ def _fwd_t(qt, kt, vt, causal, block_q, block_k, seq_q_real=None,
         ],
         interpret=_interpret(),
         compiler_params=_compiler_params(),
-        name=_fwd_name("flash_transpose_fwd", diff),
+        name=_fwd_name("flash_transpose_fwd", diff, window),
     )(qt, kt, vt)
     return out, lse
 
@@ -491,7 +567,7 @@ def _fwd_mh(q, k, v, causal, block_q, block_k, diff=False):
 
 def _dq_loop(q, do, lse, delta, load_kv, *, iq, block_q, block_k, scale,
              causal, seq_q, seq_k, seg_q=None, load_seg_k=None,
-             load_bias=None):
+             load_bias=None, window=None):
     """Shared dQ recurrence (replays blocked logits from lse; bf16 dots,
     f32 accumulation). One body for the per-head and all-heads-block dQ
     kernels. load_kv(j) -> (k, v). Returns dq [block_q, d] f32.
@@ -519,6 +595,9 @@ def _dq_loop(q, do, lse, delta, load_kv, *, iq, block_q, block_k, scale,
                 valid = k_ids < seq_k
                 if causal:
                     valid = jnp.logical_and(valid, q_ids + off >= k_ids)
+                if window is not None:
+                    valid = jnp.logical_and(valid,
+                                            q_ids + off - k_ids < window)
                 if segmented:
                     seg_k = load_seg_k(j)
                     valid = jnp.logical_and(
@@ -541,6 +620,12 @@ def _dq_loop(q, do, lse, delta, load_kv, *, iq, block_q, block_k, scale,
                              num_full, num_k_blocks)
         if segmented:
             dq = jax.lax.fori_loop(0, num_iters, make_body(True), dq0)
+        elif window is not None:   # the forward's three runs
+            lo, full_lo, full_hi = _band_k_blocks(
+                iq, block_q, block_k, off, window, num_full, num_iters)
+            dq = jax.lax.fori_loop(lo, full_lo, make_body(True), dq0)
+            dq = jax.lax.fori_loop(full_lo, full_hi, make_body(False), dq)
+            dq = jax.lax.fori_loop(full_hi, num_iters, make_body(True), dq)
         else:
             dq = jax.lax.fori_loop(0, num_full, make_body(False), dq0)
             dq = jax.lax.fori_loop(num_full, num_iters, make_body(True),
@@ -553,7 +638,7 @@ def _dq_loop(q, do, lse, delta, load_kv, *, iq, block_q, block_k, scale,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, *,
-                   scale, block_k, causal, seq_q, seq_k):
+                   scale, block_k, causal, seq_q, seq_k, window=None):
     block_q = q_ref.shape[0]
     delta = jnp.sum(do_ref[:].astype(jnp.float32) *
                     o_ref[:].astype(jnp.float32), axis=1, keepdims=True)
@@ -562,7 +647,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, *,
         lambda j: (k_ref[pl.ds(j * block_k, block_k), :],
                    v_ref[pl.ds(j * block_k, block_k), :]),
         iq=pl.program_id(2), block_q=block_q, block_k=block_k,
-        scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k)
+        scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k,
+        window=window)
     dq_ref[:] = dq.astype(dq_ref.dtype)
 
 
@@ -616,7 +702,8 @@ def _causal_q_blocks(jk, block_q, block_k, off, num_iters):
 
 
 def _dkv_loop(k, v, load_q, *, jk, block_q, block_k, scale, causal,
-              seq_q, seq_k, seg_k=None, load_seg_q=None, load_bias=None):
+              seq_q, seq_k, seg_k=None, load_seg_q=None, load_bias=None,
+              window=None):
     """Shared dK/dV recurrence. One body for the per-head and
     all-heads-block dKV kernels. load_q(i) -> (q, do, o, lse) blocks.
     Returns (dk, dv), each [block_k, d] f32.
@@ -645,6 +732,9 @@ def _dkv_loop(k, v, load_q, *, jk, block_q, block_k, scale, causal,
                 valid = q_ids < seq_q
                 if causal:
                     valid = jnp.logical_and(valid, q_ids + off >= k_ids)
+                if window is not None:
+                    valid = jnp.logical_and(valid,
+                                            q_ids + off - k_ids < window)
                 if segmented:
                     seg_q = load_seg_q(i)  # [block_q, 1]
                     valid = jnp.logical_and(
@@ -672,6 +762,15 @@ def _dkv_loop(k, v, load_q, *, jk, block_q, block_k, scale, causal,
         # (Segmented mode: boundaries cut anywhere, all blocks masked.)
         start_block, first_full = _causal_q_blocks(jk, block_q, block_k,
                                                    off, num_iters)
+        if window is not None:
+            first_full, full_hi, end = _band_q_blocks(
+                jk, block_q, block_k, off, window, start_block, first_full,
+                num_iters)
+            carry = jax.lax.fori_loop(start_block, first_full,
+                                      make_body(True), carry)
+            carry = jax.lax.fori_loop(first_full, full_hi,
+                                      make_body(tail_masked), carry)
+            return jax.lax.fori_loop(full_hi, end, make_body(True), carry)
         carry = jax.lax.fori_loop(start_block, first_full, make_body(True),
                                   carry)
         return jax.lax.fori_loop(first_full, num_iters,
@@ -680,7 +779,7 @@ def _dkv_loop(k, v, load_q, *, jk, block_q, block_k, scale, causal,
 
 
 def _fused_bwd_loop(k, v, load_q, add_dq, *, jk, block_q, block_k, scale,
-                    causal, seq_q, seq_k):
+                    causal, seq_q, seq_k, window=None):
     """The fused backward recurrence: ONE replay of the logits per
     (q block, KV block) makes all three gradients, where _dq_loop and
     _dkv_loop each replay them.  Walks the q blocks that see KV block
@@ -716,6 +815,9 @@ def _fused_bwd_loop(k, v, load_q, add_dq, *, jk, block_q, block_k, scale,
                     valid = jnp.logical_and(valid, k_ids < seq_k)
                 if causal:
                     valid = jnp.logical_and(valid, q_ids + off >= k_ids)
+                if window is not None:
+                    valid = jnp.logical_and(valid,
+                                            q_ids + off - k_ids < window)
                 st = jnp.where(valid, st, NEG_INF)
             pt = jnp.exp(st - lse)
             dv_new = dv + jax.lax.dot_general(
@@ -740,6 +842,15 @@ def _fused_bwd_loop(k, v, load_q, add_dq, *, jk, block_q, block_k, scale,
     if causal:
         start_block, first_full = _causal_q_blocks(jk, block_q, block_k,
                                                    off, num_iters)
+        if window is not None:
+            first_full, full_hi, end = _band_q_blocks(
+                jk, block_q, block_k, off, window, start_block, first_full,
+                num_iters)
+            carry = jax.lax.fori_loop(start_block, first_full,
+                                      make_body(True), carry)
+            carry = jax.lax.fori_loop(first_full, full_hi,
+                                      make_body(tail_masked), carry)
+            return jax.lax.fori_loop(full_hi, end, make_body(True), carry)
         carry = jax.lax.fori_loop(start_block, first_full, make_body(True),
                                   carry)
         return jax.lax.fori_loop(first_full, num_iters,
@@ -771,7 +882,7 @@ def _delta_row(do, o):
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
                       dk_ref, dv_ref, dq_acc, delta_ref, *, scale,
-                      block_q, causal, seq_q, seq_k, rep):
+                      block_q, causal, seq_q, seq_k, rep, window=None):
     """Grid (b, h_kv, kv_blocks), KV axis sequential.  q/o/do/dq refs
     carry the KV head's GROUP of `rep` query heads ([rep, seq_q, d]);
     lse_ref [rep, n_q_blocks, 1, block_q] f32, lane-dense; k/v/dk/dv
@@ -804,7 +915,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
             lambda i, r=r: (q_ref[r, rows(i), :], do_ref[r, rows(i), :],
                             lse_ref[r, i], delta_ref[r, i]),
             add_dq, jk=jk, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, seq_q=seq_q, seq_k=seq_k)
+            causal=causal, seq_q=seq_q, seq_k=seq_k, window=window)
         dk_acc = dk_acc + dk
         dv_acc = dv_acc + dv
     dk_ref[:] = dk_acc.astype(dk_ref.dtype)
@@ -835,7 +946,8 @@ def _bwd_dkv_kernel_bias(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dk_ref,
-                    dv_ref, *, scale, block_q, causal, seq_q, seq_k, rep):
+                    dv_ref, *, scale, block_q, causal, seq_q, seq_k, rep,
+                    window=None):
     """Grid (b, h_kv, kv_blocks). q/do/o refs carry the KV head's GROUP
     of `rep` query heads ([rep, seq_q, d]; lse [rep, seq_q, 1]): dK/dV
     for a KV head sum the contributions of every query head it serves
@@ -854,7 +966,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dk_ref,
                             o_ref[r, pl.ds(i * block_q, block_q), :],
                             lse_ref[r, pl.ds(i * block_q, block_q), :]),
             jk=jk, block_q=block_q, block_k=block_k,
-            scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k)
+            scale=scale, causal=causal, seq_q=seq_q, seq_k=seq_k,
+            window=window)
         dk_acc = dk_acc + dk
         dv_acc = dv_acc + dv
     dk_ref[:] = dk_acc.astype(dk_ref.dtype)
@@ -941,7 +1054,7 @@ def _count_backward(tier, fused):
 
 
 def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
-           seq_q_real=None, seq_k_real=None):
+           seq_q_real=None, seq_k_real=None, window=None):
     """Backward on head-major [B,H,S,D] operands; returns dq/dk/dv in the
     same head-major layout. The custom VJP saves residuals head-major
     (the forward already computed them), so backward only transposes the
@@ -979,9 +1092,9 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
         kv_spec = pl.BlockSpec((None, None, block_k, d),
                                lambda bi, hi, j: (bi, hi, j, 0))
         return pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, scale=scale,
-                              block_q=block_q, causal=causal, seq_q=sq_r,
-                              seq_k=sk_r, rep=rep),
+            functools.partial(_bwd_fused_kernel, **_win(dict(
+                scale=scale, block_q=block_q, causal=causal, seq_q=sq_r,
+                seq_k=sk_r, rep=rep), window)),
             grid=(b, h_kv, pl.cdiv(sk, block_k)),
             in_specs=[group_q, kv_spec, kv_spec, group_q, group_lse,
                       group_q],
@@ -994,11 +1107,18 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
                                        jnp.float32)],
             interpret=_interpret(),
             compiler_params=_compiler_params(),
-            name=_bwd_name("flash_transpose_bwd"),
+            name=_bwd_name("flash_transpose_bwd", window),
         )(qt, kt, vt, ot, lse, dot)
 
     with jax.named_scope(LAYOUT_SCOPE):
         lse = lse.reshape(b, h, sq, 1)
+    # the dK/dV kernel keeps its group's sequence-long q/o/do and the
+    # lane-padded lse column resident: past the compiler's default it
+    # asks for what it needs (sequences of 8192 at head size 128)
+    dkdv_vmem = _t_dkdv_vmem_bytes(sq, rep, d, qt.dtype.itemsize, block_q,
+                                   block_k)
+    if dkdv_vmem <= _T_VMEM_LIMIT:
+        dkdv_vmem = None
     q_spec = pl.BlockSpec((None, None, block_q, d),
                           lambda bi, hi, i: (bi, hi, i, 0))
     k_spec_full = pl.BlockSpec((None, None, sk, d),
@@ -1007,15 +1127,16 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
                             lambda bi, hi, i: (bi, hi, i, 0))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq_r, seq_k=sk_r),
+        functools.partial(_bwd_dq_kernel, **_win(dict(
+            scale=scale, block_k=block_k, causal=causal, seq_q=sq_r,
+            seq_k=sk_r), window)),
         grid=(b, h, pl.cdiv(sq, block_q)),
         in_specs=[q_spec, k_spec_full, k_spec_full, q_spec, lse_spec, q_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
         interpret=_interpret(),
         compiler_params=_compiler_params(),
-        name=_bwd_name("flash_transpose_dq"),
+        name=_bwd_name("flash_transpose_dq", window),
     )(qt, kt, vt, ot, lse, dot)
 
     # dK/dV: grid over KV heads; each instance reads its whole group of
@@ -1027,16 +1148,17 @@ def _bwd_t(qt, kt, vt, ot, lse, dot, causal, block_q, block_k,
     kv_spec = pl.BlockSpec((None, None, block_k, d),
                            lambda bi, hi, j: (bi, hi, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          causal=causal, seq_q=sq_r, seq_k=sk_r, rep=rep),
+        functools.partial(_bwd_dkv_kernel, **_win(dict(
+            scale=scale, block_q=block_q, causal=causal, seq_q=sq_r,
+            seq_k=sk_r, rep=rep), window)),
         grid=(b, h_kv, pl.cdiv(sk, block_k)),
         in_specs=[group_q, kv_spec, kv_spec, group_q, group_lse, group_q],
         out_specs=[kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct((b, h_kv, sk, d), kt.dtype),
                    jax.ShapeDtypeStruct((b, h_kv, sk, d), vt.dtype)],
         interpret=_interpret(),
-        compiler_params=_compiler_params(),
-        name=_bwd_name("flash_transpose_dkdv"),
+        compiler_params=_compiler_params(dkdv_vmem),
+        name=_bwd_name("flash_transpose_dkdv", window),
     )(qt, kt, vt, ot, lse, dot)
 
     return dq, dk, dv
@@ -1055,32 +1177,33 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
 
 # =========================== public entry ===========================
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_core(q, k, v, causal, block_q, block_k, seq_q_real=None,
-                seq_k_real=None):
+                seq_k_real=None, window=None):
     qt, kt, vt = _layout_swap(q, k, v)
     out, _ = _fwd_t(qt, kt, vt, causal, block_q, block_k,
-                    seq_q_real, seq_k_real)
+                    seq_q_real, seq_k_real, window=window)
     return _layout_swap(out)[0]
 
 
 def _flash_core_fwd(q, k, v, causal, block_q, block_k, seq_q_real=None,
-                    seq_k_real=None):
+                    seq_k_real=None, window=None):
     # residuals saved HEAD-MAJOR: forward already computed the [B,H,S,D]
     # transposes, so backward reuses them instead of re-transposing all
     # five operands from [B,S,H,D] — only the cotangent (in) and the three
     # grads (out) cross layouts in the backward pass
     qt, kt, vt = _layout_swap(q, k, v)
     out_t, lse = _fwd_t(qt, kt, vt, causal, block_q, block_k,
-                        seq_q_real, seq_k_real, diff=True)
+                        seq_q_real, seq_k_real, diff=True, window=window)
     return _layout_swap(out_t)[0], (qt, kt, vt, out_t, lse)
 
 
 def _flash_core_bwd(causal, block_q, block_k, seq_q_real, seq_k_real,
-                    res, g):
+                    window, res, g):
     qt, kt, vt, ot, lse = res
     dq, dk, dv = _bwd_t(qt, kt, vt, ot, lse, _layout_swap(g)[0],
-                        causal, block_q, block_k, seq_q_real, seq_k_real)
+                        causal, block_q, block_k, seq_q_real, seq_k_real,
+                        window)
     return _layout_swap(dq, dk, dv)
 
 
@@ -1782,12 +1905,14 @@ def _flash_core_flat_bwd(causal, block_q, block_k, res, g):
 _flash_core_flat.defvjp(_flash_core_flat_fwd, _flash_core_flat_bwd)
 
 
-def _count_dispatch(tier: str, block_q, block_k) -> None:
+def _count_dispatch(tier: str, block_q, block_k, window=None) -> None:
     """`flash.dispatch{tier}` plus the blocks that tier runs with
-    (`flash.blocks{tier,block_q,block_k}`) — trace-time counters."""
-    _metrics.inc("flash.dispatch", tier=tier)
+    (`flash.blocks{tier,block_q,block_k}`) — trace-time counters.  A
+    windowed call adds the label `window=<keys>` to both."""
+    extra = {} if window is None else {"window": window}
+    _metrics.inc("flash.dispatch", tier=tier, **extra)
     _metrics.inc("flash.blocks", tier=tier, block_q=block_q,
-                 block_k=block_k)
+                 block_k=block_k, **extra)
 
 
 def _gate_reject(gate: str, reason: str, q, k, blocks) -> None:
@@ -1891,6 +2016,20 @@ def _t_vmem_bytes(sq, sk, rep, d, esz, bq, bk, biased=False,
             + 2 * 4 * bk * _up(d, 128) * esz
             + 3 * _stat_rows_bytes(rep * (sq // bq), 1, bq)
             + _fused_tile_bytes(bq, bk, esz))
+
+
+def _t_dkdv_vmem_bytes(sq, rep, d, esz, bq, bk) -> int:
+    """Scoped VMEM the transpose core's split dK/dV kernel asks for: its
+    KV head's group of sequence-long q, o, do (pipelined: twice) and the
+    lse column, whose every value pads to 128 lanes, beside the
+    k/v/dk/dv blocks and four logits-sized f32 tiles, plus a sixth for
+    what the estimate leaves out.  At [2,8192,32,128] bf16 (512, 512)
+    the v5e compiler counts 21.0 MiB where this says 26.5; a window
+    changes none of it (the band bounds the loop, not the blocks)."""
+    dp = _up(d, 128)
+    need = (2 * 3 * rep * sq * dp * esz + 2 * rep * sq * 128 * 4
+            + 2 * 4 * bk * dp * esz + 4 * bq * bk * 4)
+    return need + need // 6
 
 
 def _kv_native_ok(q, k, block_q=512, block_k=512, _gate="kv") -> bool:
@@ -2118,7 +2257,7 @@ def _expand_gqa_kv(q, k, v):
     return q, k, v
 
 
-def _ref_attention(q, k, v, mask, is_causal):
+def _ref_attention(q, k, v, mask, is_causal, window=None):
     # flat-layout reference: the einsums contract directly on the native
     # [B,S,H,D] operands (dot_general batches over non-leading (b, h) —
     # no operand relayout), so the only explicit transpose left is the
@@ -2133,6 +2272,8 @@ def _ref_attention(q, k, v, mask, is_causal):
     if is_causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:   # 0 <= i + (sk - sq) - j < window
+            cm &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         logits = jnp.where(cm, logits, NEG_INF)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -2151,7 +2292,7 @@ _AUTOTUNE_OP = "flash_fwd_fusedbwd"
 
 
 def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
-                  biased=False, layout=None):
+                  biased=False, layout=None, window=None):
     """Autotuned (block_q, block_k) for this attention signature
     (paddle/phi/kernels/autotune role; cached per signature on disk).
 
@@ -2167,7 +2308,11 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
     wrong.  A transpose-tuned entry existing while the layout entry is
     cold is counted as `autotune.cross_layout_reject` (the refusal is
     deliberate and now visible).  `layout=None`/"transpose" keeps the
-    original signature, so existing on-disk caches stay valid."""
+    original signature, so existing on-disk caches stay valid.
+
+    window: a windowed call (transpose core only) tunes under its own
+    signature (`|w<keys>`): the band moves the best pair, and an
+    un-windowed signature and its cached winner stay as they were."""
     from . import autotune
 
     # curated candidate pairs, preference-ordered by the round-5 hardware
@@ -2242,16 +2387,19 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
             core = {"kv": _flash_core_kv, "flat": _flash_core_flat,
                     "mh": _flash_core_mh}.get(lt, _flash_core)
 
+            wargs = () if window is None else (None, None, window)
+
             def loss(qv, kv, vv):
-                return core(qv, kv, vv, causal, cfg[0],
-                            cfg[1]).astype(jnp.float32).sum()
+                return core(qv, kv, vv, causal, cfg[0], cfg[1],
+                            *wargs).astype(jnp.float32).sum()
 
         grads = jax.grad(loss, argnums=(0, 1, 2))
         return (lambda qkv: grads(*qkv)), (qv, kv, vv)
 
     sig = (f"{b}x{sq}x{sk}x{h}x{d}|{jnp.dtype(dtype).name}|c{int(causal)}"
            + (f"|kv{h_kv}" if h_kv and h_kv != h else "")
-           + ("|bias" if biased else ""))
+           + ("|bias" if biased else "")
+           + (f"|w{window}" if window is not None else ""))
     if lt:
         # layout-tagged signature; a transpose-tuned winner for the same
         # shape is NOT reused (it was measured on different kernels) —
@@ -2299,7 +2447,7 @@ def _per_shard(mesh, q, k, v, mask, **kw):
 
 def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
                         block_q=None, block_k=None,
-                        bias_grad_safe=False):
+                        bias_grad_safe=False, window=None):
     """[B, S, H, D] in/out. Pallas kernel for causal/full. Block sizes
     are autotuned per signature unless passed explicitly. Odd sequence
     lengths (ViT's 197, ragged batches) run zero-padded to a multiple of
@@ -2311,15 +2459,31 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
     no gradient — scaled_dot_product_attention checks stop_gradient),
     additive/boolean masks stream blockwise through the biased kernels
     ([Sq, Sk] scores never materialize); otherwise the fused-softmax
-    reference path runs."""
+    reference path runs.
+
+    window (causal, no mask): query i sees key j iff
+    0 <= i + (Sk - Sq) - j < window — a sliding window of `window` keys
+    ending at the query.  The transpose core runs it (KV blocks outside
+    the band never visited, the band's two edges masked, kernels named
+    `flash_transpose_window_*`); a window that covers the whole causal
+    half is the un-windowed call."""
     from ...distributed import topology as topo_mod
 
+    if window is not None:
+        if mask is not None or not is_causal:
+            raise ValueError("flash attention: window= needs is_causal=True "
+                             "and no mask")
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"flash attention: window={window}")
+        if window >= k.shape[1]:
+            window = None          # the band is the whole causal half
     mesh = topo_mod.traced_spmd_mesh()
     if mesh is not None and mesh.size > 1 and flash_attention_available(q) \
             and getattr(mask, "ndim", 4) == 4:
         return _per_shard(mesh, q, k, v, mask, is_causal=is_causal,
                           block_q=block_q, block_k=block_k,
-                          bias_grad_safe=bias_grad_safe)
+                          bias_grad_safe=bias_grad_safe, window=window)
     if mask is not None:
         if not (flash_attention_available(q) and bias_grad_safe
                 and _biased_flash_ok(q, k, mask)):
@@ -2353,7 +2517,13 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
     if not flash_attention_available(q):
         _metrics.inc("flash.dispatch", tier="fallback")
         _metrics.inc("flash.fallback_reason", reason="unavailable")
-        return _ref_attention(q, k, v, mask, is_causal)
+        return _ref_attention(q, k, v, mask, is_causal, window)
+    if window is not None and (q.shape[1] % 8 or k.shape[1] % 8):
+        # the kernels' real-length bounds and the band's were never put
+        # together, and no O(S^2) reference stands in for them
+        raise ValueError("flash attention: window= needs sequence lengths "
+                         f"that are multiples of 8, got {q.shape[1]} and "
+                         f"{k.shape[1]}")
     if k.shape[2] != q.shape[2]:
         # GQA feasibility: the grouped dK/dV kernel keeps a KV head's
         # whole query group (rep x seq_q x d of q, o, do) resident in
@@ -2370,9 +2540,12 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
         # default
         from ...core import flags as _flags
 
-        if _flags.get_flags(["FLAGS_flash_gqa_expand"])[
-                "FLAGS_flash_gqa_expand"] or \
-                group_bytes > 8 * 1024 * 1024:
+        by_flag = _flags.get_flags(["FLAGS_flash_gqa_expand"])[
+            "FLAGS_flash_gqa_expand"]
+        if by_flag or group_bytes > 8 * 1024 * 1024:
+            # the grouped path refused: K and V grow rep-fold in HBM
+            _metrics.inc("flash.gqa_expand",
+                         reason="flag" if by_flag else "group_bytes")
             q, k, v = _expand_gqa_kv(q, k, v)
     sq, sk = q.shape[1], k.shape[1]
     pad_q = (-sq) % 8
@@ -2386,8 +2559,9 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
     # tier intent from the layout flag (before block tuning: kv/flat/mh
     # blocks tune under their own layout-tagged autotune signature)
     layout = _layout_flag()
-    if pad_q or pad_k:
-        intended = "transpose"  # padded shapes run the transpose core
+    if pad_q or pad_k or window is not None:
+        # padded and windowed shapes run the transpose core
+        intended = "transpose"
     elif layout == "mh" and k.shape[2] == q.shape[2]:
         intended = "mh"  # the mh core is MHA-only; GQA stays grouped
     elif layout in ("flat", "auto"):
@@ -2407,7 +2581,7 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
         bq, bk = _tuned_blocks(q.shape[0], q.shape[1], k.shape[1],
                                q.shape[2], q.shape[3], q.dtype,
                                bool(is_causal), h_kv=k.shape[2],
-                               layout=tag)
+                               layout=tag, window=window)
         return (user_bq if user_bq is not None else bq,
                 user_bk if user_bk is not None else bk)
 
@@ -2444,5 +2618,9 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
                                   block_k)
         if user_bq is None or user_bk is None:
             block_q, block_k = _resolve("transpose")
+    if window is not None:
+        _count_dispatch("transpose", block_q, block_k, window)
+        return _flash_core(q, k, v, True, block_q, block_k, None, None,
+                           window)
     _count_dispatch("transpose", block_q, block_k)
     return _flash_core(q, k, v, bool(is_causal), block_q, block_k)

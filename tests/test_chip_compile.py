@@ -181,6 +181,74 @@ def test_fused_backward_compiles_padded_and_grouped(one_chip, monkeypatch,
         "flash.backward{kind=fused,tier=transpose}"] == 1
 
 
+# --- the trinity-mini-ep8.train.seq8192 cell's kernels ----------------------
+# 2 x 8192 positions, 32 query heads over 4 key/value heads of 128
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_windowed_flash_compiles_at_the_moe_cells_shape(one_chip, monkeypatch,
+                                                        flash_counters,
+                                                        window):
+    """The new cell's attention through the real dispatch, cold autotune
+    cache, forward and backward: the grouped path's resident group is
+    50 MB, so K and V are expanded eightfold (`flash.gqa_expand`); the
+    flat gate refuses 8192 positions; the transpose core's fused backward
+    does not fit (sequence-long q/o/do/dQ of one head), so the split pair
+    runs, its dK/dV kernel asking for more scoped VMEM than the compiler's
+    default (`_t_dkdv_vmem_bytes`; the parent's kernel was refused here:
+    21.0 MiB of 16).  A windowed call is named apart."""
+    monkeypatch.setattr(autotune, "_enabled", lambda: False)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn"):   # as a module scope names it
+            return fa.flash_attention_fwd(
+                q, k, v, is_causal=True,
+                window=window).astype(jnp.float32).sum()
+
+    text = _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    ((2, 8192, 32, 128), jnp.bfloat16),
+                    *[((2, 8192, 4, 128), jnp.bfloat16)] * 2)
+    tag = "transpose_window" if window else "transpose"
+    for kernel in ("jvp_flash_%s_fwd_", "transpose_jvp_flash_%s_dq__",
+                   "transpose_jvp_flash_%s_dkdv__"):
+        assert "%" + kernel % tag in text, kernel % tag
+    label = f",window={window}" if window else ""
+    got = flash_counters()
+    assert got[f"flash.dispatch{{tier=transpose{label}}}"] == 1
+    assert got["flash.gqa_expand{reason=group_bytes}"] == 1
+    assert got["flash.backward{kind=split,tier=transpose}"] == 1
+    assert ("flash.gate_reject{gate=flat,reason=vmem}" in got) == (not window)
+    assert fa._t_dkdv_vmem_bytes(8192, 1, 128, 2, 512, 512) > fa._T_VMEM_LIMIT
+    # the GPT cells' split pair stays under the default and sets no limit
+    assert fa._t_dkdv_vmem_bytes(2048, 1, 64, 2, 512, 512) <= fa._T_VMEM_LIMIT
+
+
+def test_grouped_expert_products_compile_to_the_chips_own_kernel(one_chip):
+    """The expert layer's routed part at the new cell's size, forward and
+    backward: every `ragged_dot` (and both transposes of it) becomes the
+    TPU compiler's grouped Mosaic kernel — none is left as a dense
+    per-expert expansion."""
+    import re
+
+    from paddle_tpu.incubate.distributed.models import routed_moe
+
+    t, h, f, e, held, k = 16384, 2048, 1024, 128, 16, 8
+
+    def loss(x, wr, wg, wu, wd):
+        y, sizes, counts = routed_moe._routed_part(
+            x, wr, jnp.zeros((e,), jnp.float32), wg, wu, wd, top_k=k,
+            route_scale=2.826, route_norm=True, expert_start=0)
+        return (y.astype(jnp.float32) ** 2).sum(), (sizes, counts)
+
+    bf = jnp.bfloat16
+    text = _compile(one_chip, jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True),
+        ((t, h), bf), ((h, e), bf), ((held, h, f), bf), ((held, h, f), bf),
+        ((held, f, h), bf))
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 11
+    assert not re.search(r" ragged-dot\(", text)
+
+
 def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
                                                   monkeypatch):
     """A Mosaic kernel inside a multi-device program is refused at
