@@ -7,28 +7,71 @@ the reference's RNG-state tracker for deterministic dropout replay is
 unnecessary because the PRNG key threading makes dropout functional.
 Eagerly it's a pass-through (tape autograd already frees per-op residuals
 after backward).
+
+What a recomputed segment keeps.  With no policy named (`recompute=True`
+in the models, `fleet.utils.recompute(f, x)`) the backward replays the
+segment EXCEPT the values the code inside it marked with `keep()`: results
+that are dear to replay and cheap to hold.  Marked today (`KEPT`): the flash
+cores' output and log-sum-exp — their VJP's residuals, so the backward
+does not run the flash forward kernel a second time — and the routed
+expert layer's result, which spares the replay the whole grouped forward
+(its VJP's residuals are its inputs), with the router's choice and the
+sort's small integer products (no `top_k`, no argsort in the replay).
+Projections, norms, rotary, layout swaps and the router's scores are
+replayed.  `policy="full"` replays everything
+(the last bytes: nothing but the segment's input is held); `"dots"` and
+`"dots_no_batch"` are JAX's matmul-output policies and keep no mark.
+
+A new kernel marks its residuals INSIDE its `custom_vjp` forward rule, on
+the values the rule returns as residuals and before anything else reads
+them: `out, lse = keep(out, "flash_out"), keep(lse, "flash_lse")`.  The
+kernel call then has no reader left in the replay and is dropped from it.
+Outside a recomputed segment a mark lowers to nothing.
 """
 from __future__ import annotations
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import flags
 from ..core.tensor import Tensor
 
-__all__ = ["recompute", "recompute_sequential"]
+__all__ = ["recompute", "recompute_sequential", "keep", "keeping", "KEPT"]
+
+# the names `keep()` takes: what the default policy holds across a replay
+KEPT = ("flash_out", "flash_lse", "moe_out", "moe_sort")
 
 # Named rematerialization policies (the TPU memory/FLOPs dial — SURVEY §7
-# hard part (c)). "full" replays everything in backward (max memory
-# savings); "dots" saves every matmul output (min recompute FLOPs);
-# "dots_no_batch" saves matmul outputs except batched dots — the
-# standard transformer sweet spot: the attention/mlp GEMMs whose
-# recompute costs real MXU time are saved, cheap elementwise replays.
+# hard part (c)). None keeps the marked values (above) and replays the
+# rest; "full" replays everything in backward (max memory savings); "dots"
+# saves every matmul output (min recompute FLOPs); "dots_no_batch" saves
+# matmul outputs except batched dots — the standard transformer sweet
+# spot: the attention/mlp GEMMs whose recompute costs real MXU time are
+# saved, cheap elementwise replays.
 _POLICIES = {
-    None: None,
+    None: jax.checkpoint_policies.save_only_these_names(*KEPT),
     "full": None,
-    "dots": "checkpoint_dots",
-    "dots_no_batch": "checkpoint_dots_with_no_batch_dims",
+    "dots": jax.checkpoint_policies.checkpoint_dots,
+    "dots_no_batch":
+        jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
 }
+
+# one entry a segment being traced: does its policy hold the marks?
+_segments = []
+
+
+def keep(value, name):
+    """Mark `value` (an array, inside traced code) as kept across
+    recomputation under the name `name`, one of `KEPT`."""
+    if name not in KEPT:
+        raise ValueError(f"recompute.keep: {name!r} is not one of {KEPT}")
+    return checkpoint_name(value, name)
+
+
+def keeping() -> bool:
+    """True while the body of a `recompute()` that holds the marks is being
+    traced: what the trace-time `*.recompute_kept` counters ask."""
+    return bool(_segments) and _segments[-1]
 
 
 def _resolve_policy(name):
@@ -36,8 +79,7 @@ def _resolve_policy(name):
         raise ValueError(
             f"recompute policy must be one of {sorted(k for k in _POLICIES if k)}"
             f" or None, got {name!r}")
-    attr = _POLICIES[name]
-    return getattr(jax.checkpoint_policies, attr) if attr else None
+    return _POLICIES[name]
 
 
 def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
@@ -58,7 +100,11 @@ def recompute(function, *args, use_reentrant=True, preserve_rng_state=True,
         for i, v in zip(tensor_idx, tvals):
             cur[i] = Tensor(v, stop_gradient=False)
         a, kw = jax.tree_util.tree_unflatten(treedef, cur)
-        out = function(*a, **kw)
+        _segments.append(policy is None)
+        try:
+            out = function(*a, **kw)
+        finally:
+            _segments.pop()
         return jax.tree_util.tree_map(
             lambda o: o._value if isinstance(o, Tensor) else o, out,
             is_leaf=lambda x: isinstance(x, Tensor))

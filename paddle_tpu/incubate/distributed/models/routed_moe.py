@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ....core.dispatch import apply
+from ....distributed.recompute import keep as _keep, keeping as _keeping
 from ....nn.initializer import Normal
 from ....nn.layer_base import Layer
 from ....observability import metrics as _metrics
@@ -59,15 +60,18 @@ def sigmoid_topk_route(x, w_router, expert_bias, top_k, route_scale,
     of `s + expert_bias` are chosen; their weights are `s` at the chosen
     (not `s + bias`), divided by their sum + 1e-20 (`route_norm`), times
     `route_scale`.  x [T, H], w_router [H, E].  Returns (idx [T, k] int32,
-    weights [T, k] float32)."""
+    weights [T, k] float32).  The choice is held across a block's
+    recomputation (`_keep`): the replay gathers the weights at the kept
+    `idx` and runs no `top_k`."""
     logits = jax.lax.dot_general(x, w_router, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + expert_bias.astype(jnp.float32), top_k)
+    idx = _keep(idx.astype(jnp.int32), "moe_sort")
     w = jnp.take_along_axis(s, idx, axis=1)
     if route_norm:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * route_scale
+    return idx, w * route_scale
 
 
 def sort_held(idx, expert_start, num_held, rows_pad):
@@ -252,8 +256,11 @@ def _routed_part(x, w_router, expert_bias, w_gate, w_up, w_down, *, top_k,
     # every assignment of every token fits: tokens x top_k >= the bound
     rows_pad = -(-t * top_k // rc) * rc
     with jax.named_scope("moe.sort"):
-        tok, slot, sizes, total = sort_held(idx, expert_start, num_held,
-                                            rows_pad)
+        # the sort's small products (2 MB a layer) are the grouped VJP's
+        # residuals: held, so that a block's replay runs no argsort
+        tok, slot, sizes, total = (
+            _keep(v, "moe_sort")
+            for v in sort_held(idx, expert_start, num_held, rows_pad))
         w_row = w.reshape(-1)[slot].astype(jnp.float32)
     y, counts = grouped_experts(x, w_gate, w_up, w_down, tok, w_row,
                                 jax.lax.stop_gradient(sizes),
@@ -310,6 +317,8 @@ class RoutedMoELayer(Layer):
         buffers: what a recomputed block calls (a buffer written inside
         `jax.checkpoint` would leak its tracer); `forward` adds them."""
         _metrics.inc("moe.dispatch", kernel="ragged_dot")
+        if _keeping():
+            _metrics.inc("moe.recompute_kept", what="out")
         kw = dict(top_k=self.top_k, route_scale=self.route_scale,
                   route_norm=self.route_norm, expert_start=self.expert_start)
         shared = self.shared_gate is not None
@@ -321,6 +330,10 @@ class RoutedMoELayer(Layer):
                 with jax.named_scope("moe.shared"):
                     sg, su, sd = sh
                     y = y + _silu_mul(flat @ sg, flat @ su) @ sd
+            # held across the block's recomputation: `post_mlp_norm` reads
+            # it, and the grouped VJP's residuals are its inputs, so the
+            # replay runs neither the chunk walk nor the shared down
+            y = _keep(y, "moe_out")
             return y.reshape(xv.shape), sizes, counts
 
         args = [x, self.router, self.expert_bias, self.w_gate, self.w_up,

@@ -43,7 +43,8 @@ class LlamaConfig:
         self.dropout = dropout
         self.tie_embeddings = tie_embeddings
         self.recompute = recompute
-        # named remat policy: None/'full' | 'dots' | 'dots_no_batch'
+        # named remat policy: None (replay all but the marked values:
+        # distributed/recompute.py) | 'full' | 'dots' | 'dots_no_batch'
         self.recompute_policy = recompute_policy
         self.sequence_parallel = sequence_parallel
         self.context_parallel = context_parallel

@@ -108,6 +108,16 @@ def _layout_swap(*xs):
         return tuple(jnp.swapaxes(x, 1, 2) for x in xs)
 
 
+def _kept(out, lse):
+    """A VJP forward rule's output and log-sum-exp, marked as kept across
+    per-layer recomputation (`distributed/recompute.py`): the rule returns
+    them as residuals, so a replay that holds them drops the forward
+    kernel.  Called before anything else reads them."""
+    from ...distributed.recompute import keep
+
+    return keep(out, "flash_out"), keep(lse, "flash_lse")
+
+
 def _windowed(kernel, window):
     """`flash_transpose_fwd` -> `flash_transpose_window_fwd` where the
     call has a window: windowed calls are named apart, so a device trace
@@ -1193,8 +1203,9 @@ def _flash_core_fwd(q, k, v, causal, block_q, block_k, seq_q_real=None,
     # five operands from [B,S,H,D] — only the cotangent (in) and the three
     # grads (out) cross layouts in the backward pass
     qt, kt, vt = _layout_swap(q, k, v)
-    out_t, lse = _fwd_t(qt, kt, vt, causal, block_q, block_k,
-                        seq_q_real, seq_k_real, diff=True, window=window)
+    out_t, lse = _kept(*_fwd_t(qt, kt, vt, causal, block_q, block_k,
+                               seq_q_real, seq_k_real, diff=True,
+                               window=window))
     return _layout_swap(out_t)[0], (qt, kt, vt, out_t, lse)
 
 
@@ -1221,7 +1232,7 @@ def _flash_core_mh(q, k, v, causal, block_q, block_k):
 
 
 def _flash_core_mh_fwd(q, k, v, causal, block_q, block_k):
-    out, lse = _fwd_mh(q, k, v, causal, block_q, block_k, diff=True)
+    out, lse = _kept(*_fwd_mh(q, k, v, causal, block_q, block_k, diff=True))
     return out, (q, k, v, out, lse)
 
 
@@ -1462,7 +1473,8 @@ def _flash_core_kv(q, k, v, causal, block_q, block_k):
 
 def _flash_core_kv_fwd(q, k, v, causal, block_q, block_k):
     qt = _to_hm(q)
-    out_t, lse = _fwd_kv(qt, k, v, causal, block_q, block_k, diff=True)
+    out_t, lse = _kept(*_fwd_kv(qt, k, v, causal, block_q, block_k,
+                                diff=True))
     return _from_hm(out_t), (qt, k, v, out_t, lse)
 
 
@@ -1883,8 +1895,8 @@ def _flash_core_flat_fwd(q, k, v, causal, block_q, block_k):
         qf = q.reshape(b, sq, h * d)
         kf = k.reshape(b, k.shape[1], -1)
         vf = v.reshape(b, v.shape[1], -1)
-    out, lse = _fwd_flat(qf, kf, vf, h, causal, block_q, block_k,
-                         diff=True)
+    out, lse = _kept(*_fwd_flat(qf, kf, vf, h, causal, block_q, block_k,
+                                diff=True))
     with jax.named_scope(LAYOUT_SCOPE):
         return out.reshape(b, sq, h, d), (qf, kf, vf, out, lse, h, d)
 
@@ -1908,11 +1920,17 @@ _flash_core_flat.defvjp(_flash_core_flat_fwd, _flash_core_flat_bwd)
 def _count_dispatch(tier: str, block_q, block_k, window=None) -> None:
     """`flash.dispatch{tier}` plus the blocks that tier runs with
     (`flash.blocks{tier,block_q,block_k}`) — trace-time counters.  A
-    windowed call adds the label `window=<keys>` to both."""
+    windowed call adds the label `window=<keys>` to both.  Inside a
+    recomputed segment that holds the cores' marked residuals (`_kept`):
+    `flash.recompute_kept{what=out_lse}`, one a call."""
+    from ...distributed.recompute import keeping
+
     extra = {} if window is None else {"window": window}
     _metrics.inc("flash.dispatch", tier=tier, **extra)
     _metrics.inc("flash.blocks", tier=tier, block_q=block_q,
                  block_k=block_k, **extra)
+    if keeping():
+        _metrics.inc("flash.recompute_kept", what="out_lse")
 
 
 def _gate_reject(gate: str, reason: str, q, k, blocks) -> None:
@@ -2214,8 +2232,8 @@ def _flash_core_b(q, k, v, bias, causal, block_q, block_k):
 
 def _flash_core_b_fwd(q, k, v, bias, causal, block_q, block_k):
     qt, kt, vt = _layout_swap(q, k, v)
-    out_t, lse = _fwd_tb(qt, kt, vt, bias, causal, block_q, block_k,
-                         diff=True)
+    out_t, lse = _kept(*_fwd_tb(qt, kt, vt, bias, causal, block_q, block_k,
+                                diff=True))
     return _layout_swap(out_t)[0], (qt, kt, vt, bias, out_t, lse)
 
 

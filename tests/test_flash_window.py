@@ -141,14 +141,19 @@ def test_dispatch_windowed_call_names_counts_and_falls_back(rng, monkeypatch):
                                is_causal=True, window=24)
 
 
-# sha256 of str(jaxpr) of the un-windowed transpose core's gradient, taken
-# on the commit BEFORE `window=` existed (a2c5d75, jax 0.9.0): the argument
-# costs the un-windowed path nothing, not one equation.  A PR that changes
-# these kernels on purpose records the hashes anew (the loop below prints
-# them in its failure).
+# sha256 of str(jaxpr) of the un-windowed transpose core's gradient (jax
+# 0.9.0): `window=` costs the un-windowed path nothing, not one equation.
+# Taken first on the commit BEFORE `window=` existed (a2c5d75:
+# 68783f827b2c8f57, 3ad82295dbebd273) and again in PR 29, whose text differs
+# from PR 28's in two equations and nothing else — `name[name=flash_out]` on
+# out_t and `name[name=flash_lse]` on lse in `_flash_core_fwd` (640 -> 642
+# and 1220 -> 1222 lines; every other line is the parent's with the later
+# variables' names moved on by two).  A PR that changes these kernels on
+# purpose records the hashes anew (the loop below prints them in its
+# failure).
 _UNWINDOWED = {
-    ((2, 256, 4, 64), 4, (128, 128)): "68783f827b2c8f57",
-    ((1, 256, 8, 32), 2, (64, 128)): "3ad82295dbebd273",
+    ((2, 256, 4, 64), 4, (128, 128)): "b18fe6c7d9876274",
+    ((1, 256, 8, 32), 2, (64, 128)): "78092cea31cd7807",
 }
 
 
