@@ -25,7 +25,7 @@ import time
 
 import numpy as np
 
-_PALLAS_FLASH = ("flat", "transpose", "kv", "mh")
+_PALLAS_FLASH = ("flat", "transpose")
 
 
 def _require(ok, what):

@@ -10,8 +10,7 @@
 
 ``enable_compile_cache(default_dir)``
     The ONE place that turns on jax's persistent compilation cache
-    (``chip_smoke.py``, ``bench.py``, ``tools/step_ab.py``,
-    ``tests/conftest.py``).
+    (``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``).
 """
 from __future__ import annotations
 

@@ -95,13 +95,9 @@ _flags = {
     "FLAGS_disable_pallas_conv_norm": _env_bool(
         "FLAGS_disable_pallas_conv_norm"),
     "FLAGS_use_autotune": _env_bool("FLAGS_use_autotune", "1"),
-    # force the expanded-KV MHA kernels for GQA attention (grouped is
-    # the default: less KV HBM traffic; the round-5 on-chip A/B showed
-    # backward can favor expanded at some block shapes — PERF.md)
-    "FLAGS_flash_gqa_expand": _env_bool("FLAGS_flash_gqa_expand"),
     # Extra scoped-VMEM budget for Pallas kernels (KiB, 0 = compiler
-    # default of 16 MiB). The round-5 kv-native flash kernels keep all
-    # heads' intermediates on the Mosaic stack and need ~32-64 MiB at
+    # default of 16 MiB). Kernels that walk heads in a static loop keep
+    # all heads' intermediates on the Mosaic stack and need ~32-64 MiB at
     # training block sizes; v5e has 128 MiB VMEM, so raising the limit
     # is real headroom, not overcommit. Applied via jit compiler_options
     # at the train-step jit sites (the local XLA_FLAGS parser rejects

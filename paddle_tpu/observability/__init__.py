@@ -49,10 +49,9 @@ __all__ = ["metrics", "flight", "step_stats", "trace", "xla_cost",
 # series under a different label set).
 _SCHEMA_COUNTERS = tuple(
     [("flash.dispatch", {"tier": t})
-     for t in ("transpose", "kv", "flat", "mh", "fallback", "biased")]
+     for t in ("transpose", "flat", "fallback", "biased")]
     + [("autotune.hit", {}), ("autotune.miss", {})]
-    + [("autotune.cross_layout_reject", {"layout": lt})
-       for lt in ("kv", "flat", "mh")]
+    + [("autotune.cross_layout_reject", {"layout": "flat"})]
     + [("jit.trace_cache.hit", {}), ("jit.trace_cache.miss", {}),
        ("jit.retrace", {})]
     + [("collective.calls", {"kind": k})

@@ -24,10 +24,10 @@ Design (TPU-first, not a CUDA translation):
     sequence in f32 VMEM, working on the transposed tile sT = k·qT so
     that lse and delta are lane-dense rows (_fused_bwd_loop: 5 block
     matmuls a tile, FlashAttention's count). Where the sequence-long
-    q/o/do/dQ do not fit the kernel's VMEM (_kv_vmem_bytes,
-    _t_vmem_bytes) they run the split pair the kv, mh, biased and varlen
-    tiers keep: one kernel for dQ (grid over q_blocks, _dq_loop), one
-    for dK/dV (grid over kv_blocks, _dkv_loop) — 7 matmuls and the
+    q/o/do/dQ do not fit the kernel's VMEM (_flat_vmem_bytes,
+    _t_vmem_bytes) they run the split pair the biased and varlen tiers
+    keep: one kernel for dQ (grid over q_blocks, _dq_loop), one for
+    dK/dV (grid over kv_blocks, _dkv_loop) — 7 matmuls and the
     per-logit chain twice. Same dots, same order: bit-identical.
   * block sizes are autotuned per signature on a fwd+bwd run (cached on
     disk; paddle/phi/kernels/autotune role). At B32 H12 S1024 D64 bf16 the
@@ -74,24 +74,24 @@ NEG_INF = -1e30
 
 _DIMSEM = (_PLL, _PLL, _ARB)
 
-# per-kernel scoped-VMEM limit of the kv/flat kernels (see _kv_dimsem);
-# the same number bounds the gates' estimate (_kv_vmem_bytes)
-_KV_VMEM_LIMIT = 34 * 1024 * 1024
+# per-kernel scoped-VMEM limit of the flat kernels (see
+# _flat_compiler_params); the same number bounds the flat gate's
+# estimate (_flat_vmem_bytes)
+_FLAT_VMEM_LIMIT = 34 * 1024 * 1024
 
-# Flash layout default: "auto" — the transpose-free FLAT tier
-# (everything on unpadded [B,S,H*D] views, zero relayouts — round-5
-# kernels, gradients bit-identical to the transpose core) wherever the
-# static lane/VMEM gates admit it, the transpose core everywhere else.
-# Flipped from "transpose" after the round-5 parity tests + compile
-# ladder proved flat correct and lowerable (docs/ATTENTION.md "The
-# layout story"); tools/step_ab.py re-measures the full-step win each
-# hardware window. Other tiers stay reachable via env
-# FLAGS_flash_layout: "transpose" (per-head kernels over [B,H,S,D]
-# with layout transposes around the call — the pre-flip default), "kv"
-# (mixed: K/V/dK/dV stay native [B,S,H,D]), "flat" (force flat), "mh"
-# (all-native all-heads blocks — rejected by the deployed server
-# Mosaic, kept for newer toolchains).
-_DEFAULT_LAYOUT = "auto"
+# Two cores run a call that carries no mask, and _choose_core picks one
+# from the call's shape — no flag selects a kernel:
+#   flat       everything on unpadded [B,S,H*D] views, zero transposes,
+#              heads as static lane slices; needs a 128-lane-aligned
+#              H*D, d % 64 == 0 and all heads' sequence-long operands
+#              inside _FLAT_VMEM_LIMIT (12 x 64 at sq = 1024: yes; at
+#              2048: no).
+#   transpose  per-head kernels over [B,H,S,D] with layout transposes
+#              around the call: every other shape, every padded length
+#              and every window.
+# Same recurrences, gradients bit-identical.  Masked calls take the
+# biased core (_flash_core_b).  docs/ATTENTION.md "The layout story"
+# tells which other layouts were tried and what the compiler refused.
 
 
 # Names in the program (docs/OBSERVABILITY.md "Scopes"): every
@@ -505,74 +505,6 @@ def _fwd(q, k, v, causal, block_q, block_k):
     return jnp.swapaxes(out, 1, 2), lse
 
 
-# ================== multi-head-block forward (no transposes) ==================
-
-def _fwd_kernel_mh(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
-                   causal, seq_q, seq_k, n_heads):
-    """All-heads-in-block variant: operates directly on [B,S,H,D] arrays.
-
-    Mosaic cannot lower a squeezed-H block over [B,S,H,D] (the last two
-    block dims must be divisible by (8,128) or EQUAL the array dims —
-    a squeezed H=12 between S and D is neither), but a block carrying the
-    FULL head dim is legal (equal-to-array-dim rule). The kernel then
-    walks heads with static slices — a sublane extract per head, O(bq*d),
-    negligible next to the O(bq*sk*d) dots — and the [B,S,H,D]<->[B,H,S,D]
-    transposes around every attention call (~25 ms/step, PERF.md) never
-    exist. VMEM holds K/V for ALL heads (seq_k*H*D*2*itemsize), so this
-    path suits moderate S*H*D; the dispatcher keeps the transpose path
-    for larger shapes.
-    q_ref/o_ref: [block_q, H, D]; k_ref/v_ref: [seq_k, H, D];
-    lse_ref: [H, block_q, 1].
-    """
-    block_q = q_ref.shape[0]
-    iq = pl.program_id(1)
-    for hh in range(n_heads):
-        out, lse = _online_softmax(
-            q_ref[:, hh, :],
-            lambda j, hh=hh: (k_ref[pl.ds(j * block_k, block_k), hh, :],
-                              v_ref[pl.ds(j * block_k, block_k), hh, :]),
-            iq=iq, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, seq_q=seq_q, seq_k=seq_k)
-        o_ref[:, hh, :] = out.astype(o_ref.dtype)
-        lse_ref[hh, :, :] = lse.astype(jnp.float32)
-
-
-def _fwd_mh(q, k, v, causal, block_q, block_k, diff=False):
-    """Forward on [B,S,H,D] with zero layout changes (see _fwd_kernel_mh).
-    Returns (out [B,S,H,D], lse [B,H,Sq,1])."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-    dimsem = None
-    if not _interpret():
-        dimsem = _TPUCompilerParams(
-            dimension_semantics=(_PLL, _ARB))
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel_mh, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq, seq_k=sk, n_heads=h),
-        grid=(b, pl.cdiv(sq, block_q)),
-        in_specs=[
-            pl.BlockSpec((None, block_q, h, d), lambda bi, qi: (bi, qi, 0, 0)),
-            pl.BlockSpec((None, sk, h, d), lambda bi, qi: (bi, 0, 0, 0)),
-            pl.BlockSpec((None, sk, h, d), lambda bi, qi: (bi, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, h, d), lambda bi, qi: (bi, qi, 0, 0)),
-            pl.BlockSpec((None, h, block_q, 1), lambda bi, qi: (bi, 0, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
-        compiler_params=dimsem,
-        name=_fwd_name("flash_mh_fwd", diff),
-    )(q, k, v)
-    return out, lse
-
-
 # =========================== backward kernels ===========================
 
 def _dq_loop(q, do, lse, delta, load_kv, *, iq, block_q, block_k, scale,
@@ -677,27 +609,6 @@ def _bwd_dq_kernel_bias(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         load_bias=lambda j: b_ref[:, pl.ds(j * block_k, block_k)]
         .astype(jnp.float32))
     dq_ref[:] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dq_kernel_mh(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
-                      *, scale, block_k, causal, seq_q, seq_k, n_heads):
-    """All-heads-block dQ: [B,S,H,D] operands in place (see
-    _fwd_kernel_mh). q/o/do/dq refs: [block_q, H, D]; k/v: [seq_k, H, D];
-    lse: [H, block_q, 1]."""
-    block_q = q_ref.shape[0]
-    iq = pl.program_id(1)
-    for hh in range(n_heads):
-        do = do_ref[:, hh, :]
-        delta = jnp.sum(do.astype(jnp.float32) *
-                        o_ref[:, hh, :].astype(jnp.float32),
-                        axis=1, keepdims=True)
-        dq = _dq_loop(
-            q_ref[:, hh, :], do, lse_ref[hh, :, :], delta,
-            lambda j, hh=hh: (k_ref[pl.ds(j * block_k, block_k), hh, :],
-                              v_ref[pl.ds(j * block_k, block_k), hh, :]),
-            iq=iq, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, seq_q=seq_q, seq_k=seq_k)
-        dq_ref[:, hh, :] = dq.astype(dq_ref.dtype)
 
 
 def _causal_q_blocks(jk, block_q, block_k, off, num_iters):
@@ -984,77 +895,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dk_ref,
     dv_ref[:] = dv_acc.astype(dv_ref.dtype)
 
 
-def _bwd_dkv_kernel_mh(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dk_ref,
-                       dv_ref, *, scale, block_q, causal, seq_q, seq_k,
-                       n_heads):
-    """All-heads-block dK/dV: [B,S,H,D] operands in place. k/v/dk/dv
-    refs: [block_k, H, D]; q/do/o: [seq_q, H, D]; lse: [H, seq_q, 1]."""
-    block_k = k_ref.shape[0]
-    jk = pl.program_id(1)
-    for hh in range(n_heads):
-        dk, dv = _dkv_loop(
-            k_ref[:, hh, :], v_ref[:, hh, :],
-            lambda i, hh=hh: (
-                q_ref[pl.ds(i * block_q, block_q), hh, :],
-                do_ref[pl.ds(i * block_q, block_q), hh, :],
-                o_ref[pl.ds(i * block_q, block_q), hh, :],
-                lse_ref[hh, pl.ds(i * block_q, block_q), :]),
-            jk=jk, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, seq_q=seq_q, seq_k=seq_k)
-        dk_ref[:, hh, :] = dk.astype(dk_ref.dtype)
-        dv_ref[:, hh, :] = dv.astype(dv_ref.dtype)
-
-
-def _bwd_mh(q, k, v, out, lse, do, causal, block_q, block_k):
-    """Backward on [B,S,H,D] with zero layout changes (mh kernels).
-    Returns dq/dk/dv in [B,S,H,D]."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-    dimsem = None
-    if not _interpret():
-        dimsem = _TPUCompilerParams(
-            dimension_semantics=(_PLL, _ARB))
-    q_spec = pl.BlockSpec((None, block_q, h, d),
-                          lambda bi, i: (bi, i, 0, 0))
-    full_q = pl.BlockSpec((None, sq, h, d), lambda bi, i: (bi, 0, 0, 0))
-    k_full = pl.BlockSpec((None, sk, h, d), lambda bi, i: (bi, 0, 0, 0))
-    lse_spec = pl.BlockSpec((None, h, block_q, 1),
-                            lambda bi, i: (bi, 0, i, 0))
-    full_lse = pl.BlockSpec((None, h, sq, 1), lambda bi, i: (bi, 0, 0, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_mh, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq, seq_k=sk, n_heads=h),
-        grid=(b, pl.cdiv(sq, block_q)),
-        in_specs=[q_spec, k_full, k_full, q_spec, lse_spec, q_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
-        interpret=_interpret(),
-        compiler_params=dimsem,
-        name=_bwd_name("flash_mh_dq"),
-    )(q, k, v, out, lse, do)
-
-    kv_spec = pl.BlockSpec((None, block_k, h, d),
-                           lambda bi, j: (bi, j, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_mh, scale=scale, block_q=block_q,
-                          causal=causal, seq_q=sq, seq_k=sk, n_heads=h),
-        grid=(b, pl.cdiv(sk, block_k)),
-        in_specs=[full_q, kv_spec, kv_spec, full_q, full_lse, full_q],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, sk, h, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, sk, h, d), v.dtype)],
-        interpret=_interpret(),
-        compiler_params=dimsem,
-        name=_bwd_name("flash_mh_dkdv"),
-    )(q, k, v, out, lse, do)
-
-    return dq, dk, dv
-
-
 def _count_backward(tier, fused):
     """`flash.backward{tier,kind}`: which backward a core's VJP traced,
     the fused kernel or the split dq + dkdv pair (a cold block search's
@@ -1221,385 +1061,11 @@ def _flash_core_bwd(causal, block_q, block_k, seq_q_real, seq_k_real,
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_core_mh(q, k, v, causal, block_q, block_k):
-    """Transpose-free core: all-heads-block kernels end to end. Same
-    numerics as _flash_core (shared loop bodies); no [B,H,S,D] arrays
-    ever materialize. Selected by FLAGS_flash_layout=mh once the on-chip
-    A/B (tools/chip_session.py layout_ab) proves it faster."""
-    out, _ = _fwd_mh(q, k, v, causal, block_q, block_k)
-    return out
-
-
-def _flash_core_mh_fwd(q, k, v, causal, block_q, block_k):
-    out, lse = _kept(*_fwd_mh(q, k, v, causal, block_q, block_k, diff=True))
-    return out, (q, k, v, out, lse)
-
-
-def _flash_core_mh_bwd(causal, block_q, block_k, res, g):
-    q, k, v, out, lse = res
-    return _bwd_mh(q, k, v, out, lse, g, causal, block_q, block_k)
-
-
-_flash_core_mh.defvjp(_flash_core_mh_fwd, _flash_core_mh_bwd)
-
-
-# ================= mixed-layout (kv-native) kernels =================
-#
-# Round-5 on-chip bisect (tools/chip_session.py phase_mh_bisect plus a
-# follow-up compile ladder on the real toolchain): the deployed Mosaic
-# rejects a middle-dim-squeezed load as a dot LHS ("infer-vector-layout:
-# unsupported shape cast") and any DYNAMIC index into a middle dim
-# ("cannot statically prove that index ... is a multiple of 4"), but it
-# accepts
-#   (a) STATIC middle-dim squeezes as dot RHS operands,
-#   (b) static middle-dim-squeezed stores, and
-#   (c) leading-dim indexing of head-major blocks (free: offset only).
-# Every dot in the shared flash loops uses K/V strictly as the RHS
-# (_online_softmax, _dq_loop, _dkv_loop), so K/V/dK/dV can stay in the
-# model's NATIVE [B,S,H,D] layout end to end while Q/O/dO/dQ travel
-# head-major: the K/V transposes in forward and the dK/dV transposes in
-# backward never exist. The round-5 xprof trace put the flash layout
-# transposes at ~66 ms/step (20%) of the GPT-125M bench step; this tier
-# removes half of them (the full-mh core that would remove the rest is
-# what the toolchain rejects, see docs/ATTENTION.md "layout A/B").
-
-
-def _fwd_kernel_kv(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                   block_k, causal, seq_q, seq_k, n_heads, rep):
-    """q_ref/o_ref: [H, block_q, D] head-major; k_ref/v_ref:
-    [seq_k, Hkv, D] native; lse_ref: [H, block_q, 1]. Heads walk a
-    static Python loop (dynamic head indices do not lower, see above);
-    per-head K/V loads are static middle-dim squeezes used only as dot
-    RHS."""
-    block_q = q_ref.shape[1]
-    iq = pl.program_id(1)
-    for hh in range(n_heads):
-        hkv = hh // rep
-        out, lse = _online_softmax(
-            q_ref[hh],
-            lambda j, hkv=hkv: (
-                k_ref[pl.ds(j * block_k, block_k), hkv, :],
-                v_ref[pl.ds(j * block_k, block_k), hkv, :]),
-            iq=iq, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, seq_q=seq_q, seq_k=seq_k)
-        o_ref[hh] = out.astype(o_ref.dtype)
-        lse_ref[hh] = lse.astype(jnp.float32)
-
-
-def _kv_dimsem():
-    # vmem_limit_bytes: the kv kernels keep all heads' loop intermediates
-    # on the Mosaic stack (statically unrolled head walk) and need
-    # ~20-35 MiB at training block sizes — above the 16 MiB default but
-    # real headroom on v5e's 128 MiB VMEM. Raising the limit PER KERNEL
-    # (instead of the program-wide xla_tpu_scoped_vmem_limit_kib flag)
-    # leaves XLA's own ops on the default budget — a program-wide raise
-    # makes large fusion/transpose ops pick >40 MiB scoped strategies
-    # that then fail allocation (observed on-chip this round).
-    if _interpret():
-        return None
-    return _TPUCompilerParams(
-        dimension_semantics=(_PLL, _ARB),
-        vmem_limit_bytes=_KV_VMEM_LIMIT)
-
-
-def _fwd_kv(qt, k, v, causal, block_q, block_k, diff=False):
-    """Forward with head-major Q/O ([B,H,Sq,D]) and native-layout K/V
-    ([B,Sk,Hkv,D]); GQA reads the shrunken KV directly (hh // rep).
-    Returns (out_t [B,H,Sq,D], lse [B,H,Sq,1])."""
-    b, h, sq, d = qt.shape
-    sk, h_kv = k.shape[1], k.shape[2]
-    assert h % h_kv == 0, (h, h_kv)
-    rep = h // h_kv
-    scale = 1.0 / math.sqrt(d)
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel_kv, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq, seq_k=sk, n_heads=h,
-                          rep=rep),
-        grid=(b, pl.cdiv(sq, block_q)),
-        in_specs=[
-            pl.BlockSpec((None, h, block_q, d),
-                         lambda bi, qi: (bi, 0, qi, 0)),
-            pl.BlockSpec((None, sk, h_kv, d),
-                         lambda bi, qi: (bi, 0, 0, 0)),
-            pl.BlockSpec((None, sk, h_kv, d),
-                         lambda bi, qi: (bi, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, h, block_q, d),
-                         lambda bi, qi: (bi, 0, qi, 0)),
-            pl.BlockSpec((None, h, block_q, 1),
-                         lambda bi, qi: (bi, 0, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
-        compiler_params=_kv_dimsem(),
-        name=_fwd_name("flash_kv_fwd", diff),
-    )(qt, k, v)
-    return out, lse
-
-
-def _bwd_dq_kernel_kv(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                      dq_ref, *, scale, block_k, causal, seq_q, seq_k,
-                      n_heads, rep):
-    """q/o/do/dq refs: [H, block_q, D] head-major; k/v: [seq_k, Hkv, D]
-    native; lse: [H, block_q, 1]."""
-    block_q = q_ref.shape[1]
-    iq = pl.program_id(1)
-    for hh in range(n_heads):
-        hkv = hh // rep
-        do = do_ref[hh]
-        delta = jnp.sum(do.astype(jnp.float32) *
-                        o_ref[hh].astype(jnp.float32),
-                        axis=1, keepdims=True)
-        dq = _dq_loop(
-            q_ref[hh], do, lse_ref[hh], delta,
-            lambda j, hkv=hkv: (
-                k_ref[pl.ds(j * block_k, block_k), hkv, :],
-                v_ref[pl.ds(j * block_k, block_k), hkv, :]),
-            iq=iq, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, seq_q=seq_q, seq_k=seq_k)
-        dq_ref[hh] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel_kv(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                       dk_ref, dv_ref, *, scale, block_q, causal, seq_q,
-                       seq_k, rep):
-    """k/v/dk/dv refs: [block_k, Hkv, D] native (squeezed static stores);
-    q/o/do: [H, seq_q, D] head-major; lse: [H, seq_q, 1]. dK/dV for a KV
-    head sum the contributions of its whole query group (rep == 1 is
-    plain MHA)."""
-    block_k = k_ref.shape[0]
-    jk = pl.program_id(1)
-    for hkv in range(k_ref.shape[1]):
-        k = k_ref[:, hkv, :]
-        v = v_ref[:, hkv, :]
-        dk_acc = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
-        dv_acc = jnp.zeros((block_k, v.shape[-1]), jnp.float32)
-        for r in range(rep):
-            hh = hkv * rep + r
-            dk, dv = _dkv_loop(
-                k, v,
-                lambda i, hh=hh: (
-                    q_ref[hh, pl.ds(i * block_q, block_q), :],
-                    do_ref[hh, pl.ds(i * block_q, block_q), :],
-                    o_ref[hh, pl.ds(i * block_q, block_q), :],
-                    lse_ref[hh, pl.ds(i * block_q, block_q), :]),
-                jk=jk, block_q=block_q, block_k=block_k, scale=scale,
-                causal=causal, seq_q=seq_q, seq_k=seq_k)
-            dk_acc = dk_acc + dk
-            dv_acc = dv_acc + dv
-        # The deployed Mosaic cannot shape-cast a dot-accumulator value
-        # into a middle-dim-squeezed STORE directly ("infer-vector-layout:
-        # unsupported shape cast"); storing a splat zero first (constants
-        # are layout-flexible) and re-loading gives the accumulator a
-        # store-compatible layout via a supported relayout. The extra
-        # VMEM round-trip is noise next to the dK/dV HBM transposes this
-        # kernel eliminates.
-        dk_ref[:, hkv, :] = jnp.zeros((block_k, k.shape[-1]),
-                                      dk_ref.dtype)
-        dv_ref[:, hkv, :] = jnp.zeros((block_k, v.shape[-1]),
-                                      dv_ref.dtype)
-        dk_ref[:, hkv, :] = (dk_ref[:, hkv, :].astype(jnp.float32) +
-                             dk_acc).astype(dk_ref.dtype)
-        dv_ref[:, hkv, :] = (dv_ref[:, hkv, :].astype(jnp.float32) +
-                             dv_acc).astype(dv_ref.dtype)
-
-
-def _bwd_kv(qt, k, v, ot, lse, dot, causal, block_q, block_k):
-    """Backward companion of _fwd_kv: head-major q/o/do in, head-major dq
-    + NATIVE-layout dk/dv out (no transposes behind dK/dV)."""
-    b, h, sq, d = qt.shape
-    sk, h_kv = k.shape[1], k.shape[2]
-    rep = h // h_kv
-    scale = 1.0 / math.sqrt(d)
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-
-    hm_spec = pl.BlockSpec((None, h, block_q, d),
-                           lambda bi, qi: (bi, 0, qi, 0))
-    hm_lse = pl.BlockSpec((None, h, block_q, 1),
-                          lambda bi, qi: (bi, 0, qi, 0))
-    kv_full = pl.BlockSpec((None, sk, h_kv, d),
-                           lambda bi, qi: (bi, 0, 0, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_kv, scale=scale, block_k=block_k,
-                          causal=causal, seq_q=sq, seq_k=sk, n_heads=h,
-                          rep=rep),
-        grid=(b, pl.cdiv(sq, block_q)),
-        in_specs=[hm_spec, kv_full, kv_full, hm_spec, hm_lse, hm_spec],
-        out_specs=hm_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
-        interpret=_interpret(),
-        compiler_params=_kv_dimsem(),
-        name=_bwd_name("flash_kv_dq"),
-    )(qt, k, v, ot, lse, dot)
-
-    hm_full = pl.BlockSpec((None, h, sq, d), lambda bi, kj: (bi, 0, 0, 0))
-    hm_full_lse = pl.BlockSpec((None, h, sq, 1),
-                               lambda bi, kj: (bi, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, block_k, h_kv, d),
-                           lambda bi, kj: (bi, kj, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_kv, scale=scale,
-                          block_q=block_q, causal=causal, seq_q=sq,
-                          seq_k=sk, rep=rep),
-        grid=(b, pl.cdiv(sk, block_k)),
-        in_specs=[hm_full, kv_spec, kv_spec, hm_full, hm_full_lse,
-                  hm_full],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, sk, h_kv, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, sk, h_kv, d), v.dtype)],
-        interpret=_interpret(),
-        compiler_params=_kv_dimsem(),
-        name=_bwd_name("flash_kv_dkdv"),
-    )(qt, k, v, ot, lse, dot)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_core_kv(q, k, v, causal, block_q, block_k):
-    """Mixed-layout core: only Q and O (and in backward dO/dQ) cross the
-    [B,S,H,D]<->[B,H,S,D] boundary; K/V/dK/dV stay native. Numerics are
-    the shared flash loops — bit-identical to _flash_core."""
-    out_t, _ = _fwd_kv(_to_hm(q), k, v, causal, block_q, block_k)
-    return _from_hm(out_t)
-
-
-def _flash_core_kv_fwd(q, k, v, causal, block_q, block_k):
-    qt = _to_hm(q)
-    out_t, lse = _kept(*_fwd_kv(qt, k, v, causal, block_q, block_k,
-                                diff=True))
-    return _from_hm(out_t), (qt, k, v, out_t, lse)
-
-
-def _flash_core_kv_bwd(causal, block_q, block_k, res, g):
-    qt, k, v, ot, lse = res
-    dq_t, dk, dv = _bwd_kv(qt, k, v, ot, lse, _to_hm(g),
-                           causal, block_q, block_k)
-    return _from_hm(dq_t), dk, dv
-
-
-_flash_core_kv.defvjp(_flash_core_kv_fwd, _flash_core_kv_bwd)
-
-# ----- Pallas layout relayout ([B,S,H,D] <-> [B,H,S,D]) -----
-#
-# Two reasons these are Pallas kernels instead of jnp.swapaxes:
-# 1. Speed: the round-5 xprof trace measured XLA's flash layout
-#    transposes at ~209 GB/s apparent bandwidth (~25% of v5e roofline)
-#    — ~66 ms/step at the GPT-125M bench shape.
-# 2. The kv-native kernels need a raised per-kernel VMEM limit, and the
-#    deployed toolchain applies the largest per-kernel limit to the
-#    WHOLE program's scoped-vmem check, under which XLA's own big
-#    transpose fusions pick >40 MiB stack strategies and fail to
-#    compile. Pallas relayouts keep every layout move inside kernels
-#    that carry their own budgets.
-# Only the VPU touches data here (squeezed loads/stores are the
-# bisect-proven headwalk pattern), so lowering is compile-safe on the
-# deployed Mosaic.
-
-
-def _relayout_kernel_to_hm(x_ref, o_ref, *, n_heads):
-    # x_ref: [block_s, H, D] native; o_ref: [H, block_s, D] head-major.
-    # A middle-squeezed LOAD and a leading-index STORE carry different
-    # Mosaic layout flavors; a bare store needs an unsupported shape
-    # cast. Storing a splat zero first (constants are layout-flexible)
-    # and accumulating routes the conversion through a supported
-    # relayout instead (same trick as the dKV store).
-    block_s, _, d = x_ref.shape
-    for hh in range(n_heads):
-        o_ref[hh] = jnp.zeros((block_s, d), o_ref.dtype)
-        o_ref[hh] = o_ref[hh] + x_ref[:, hh, :]
-
-
-def _relayout_kernel_from_hm(x_ref, o_ref, *, n_heads):
-    # x_ref: [H, block_s, D] head-major; o_ref: [block_s, H, D] native
-    _, block_s, d = x_ref.shape
-    for hh in range(n_heads):
-        o_ref[:, hh, :] = jnp.zeros((block_s, d), o_ref.dtype)
-        o_ref[:, hh, :] = o_ref[:, hh, :] + x_ref[hh]
-
-
-def _relayout_block(s):
-    # biggest multiple of 8 dividing s, capped at 512 rows per block
-    b = min(512, s)
-    b -= b % 8
-    while b > 8 and s % b:
-        b -= 8
-    return max(b, 8)
-
-
-@jax.custom_vjp
-def _to_hm(x):
-    """[B,S,H,D] -> [B,H,S,D] as a Pallas copy on TPU (jnp.swapaxes on
-    the interpreter). Adjoint is _from_hm."""
-    b, s, h, d = x.shape
-    if _interpret():
-        return jnp.swapaxes(x, 1, 2)
-    bs = _relayout_block(s)
-    return pl.pallas_call(
-        functools.partial(_relayout_kernel_to_hm, n_heads=h),
-        grid=(b, pl.cdiv(s, bs)),
-        in_specs=[pl.BlockSpec((None, bs, h, d),
-                               lambda bi, si: (bi, si, 0, 0))],
-        out_specs=pl.BlockSpec((None, h, bs, d),
-                               lambda bi, si: (bi, 0, si, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), x.dtype),
-        compiler_params=_kv_dimsem(),
-        name="flash_relayout_to_hm",
-    )(x)
-
-
-@jax.custom_vjp
-def _from_hm(xt):
-    """[B,H,S,D] -> [B,S,H,D]; adjoint is _to_hm."""
-    b, h, s, d = xt.shape
-    if _interpret():
-        return jnp.swapaxes(xt, 1, 2)
-    bs = _relayout_block(s)
-    return pl.pallas_call(
-        functools.partial(_relayout_kernel_from_hm, n_heads=h),
-        grid=(b, pl.cdiv(s, bs)),
-        in_specs=[pl.BlockSpec((None, h, bs, d),
-                               lambda bi, si: (bi, 0, si, 0))],
-        out_specs=pl.BlockSpec((None, bs, h, d),
-                               lambda bi, si: (bi, si, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), xt.dtype),
-        compiler_params=_kv_dimsem(),
-        name="flash_relayout_from_hm",
-    )(xt)
-
-
-def _to_hm_fwd(x):
-    return _to_hm(x), None
-
-
-def _to_hm_bwd(_, g):
-    return (_from_hm(g),)
-
-
-def _from_hm_fwd(xt):
-    return _from_hm(xt), None
-
-
-def _from_hm_bwd(_, g):
-    return (_to_hm(g),)
-
-
-_to_hm.defvjp(_to_hm_fwd, _to_hm_bwd)
-_from_hm.defvjp(_from_hm_fwd, _from_hm_bwd)
-
 # ================= flat-native kernels ([B, S, H*D] views) =================
 #
-# The end state of the round-5 layout work. The deployed Mosaic accepts
-# STATIC 64-lane slices of a flat [*, H*D] block as MXU dot operands and
-# as stores (compile-proven on-chip), which makes head-major arrays
-# unnecessary ALTOGETHER:
+# The deployed Mosaic accepts STATIC 64-lane slices of a flat [*, H*D]
+# block as MXU dot operands and as stores (compile-proven on-chip),
+# which makes head-major arrays unnecessary ALTOGETHER:
 #   - q/k/v/o and all gradients stay [B, S, H*D] — the trailing dims
 #     (S, 768) are tile-aligned, so none of the 2-2.7x T(8,128) padding
 #     that [B,H,S,D]/[B,S,H,D] 4-D arrays with D=64 pay in HBM;
@@ -1607,13 +1073,28 @@ _from_hm.defvjp(_from_hm_fwd, _from_hm_bwd)
 #     layout the surrounding GEMMs use (the [B,S,3,H,D] reshape/unbind
 #     around the qkv projection is a free bitcast);
 #   - no layout-pinned custom-call boundary for XLA to insert scoped-
-#     stack transpose copies around (the failure mode that killed the
-#     4-D kv-native tier at raised VMEM limits: those copies size
-#     themselves just over whatever per-kernel limit leaks into the
-#     program-wide scoped check).
+#     stack transpose copies around.
 # Heads walk a static Python loop; per-head operands are lane slices
 # hh*D:(hh+1)*D. The shared recurrences (_online_softmax, _dq_loop,
-# _dkv_loop) are reused as-is — numerics identical to every other core.
+# _dkv_loop, _fused_bwd_loop) are reused as-is — numerics identical to
+# the transpose core.
+
+
+def _flat_compiler_params():
+    # vmem_limit_bytes: the flat kernels keep all heads' loop
+    # intermediates on the Mosaic stack (statically unrolled head walk)
+    # and need ~20-35 MiB at training block sizes — above the 16 MiB
+    # default but real headroom on v5e's 128 MiB VMEM. Raising the limit
+    # PER KERNEL (instead of the program-wide
+    # xla_tpu_scoped_vmem_limit_kib flag) leaves XLA's own ops on the
+    # default budget — a program-wide raise makes large fusion/transpose
+    # ops pick >40 MiB scoped strategies that then fail allocation
+    # (observed on-chip).
+    if _interpret():
+        return None
+    return _TPUCompilerParams(
+        dimension_semantics=(_PLL, _ARB),
+        vmem_limit_bytes=_FLAT_VMEM_LIMIT)
 
 
 def _fwd_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
@@ -1682,7 +1163,7 @@ def _fwd_flat(q, k, v, h, causal, block_q, block_k, diff=False):
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         interpret=_interpret(),
-        compiler_params=_kv_dimsem(),
+        compiler_params=_flat_compiler_params(),
         name=_fwd_name("flash_flat_fwd", diff),
     )(q, k, v)
     return out, lse
@@ -1796,7 +1277,7 @@ def _bwd_fused_kernel_flat(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
 def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
     """Backward companion of _fwd_flat: everything stays [B,S,H*D]; lse
     is lane-dense, as _fwd_flat(diff=True) returns it.  One fused kernel
-    where _kv_vmem_bytes says the sequence-long dQ fits beside q/o/do,
+    where _flat_vmem_bytes says the sequence-long dQ fits beside q/o/do,
     the split pair where only theirs does."""
     b, sq, hd = q.shape
     d = hd // h
@@ -1806,9 +1287,9 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
     block_k = _pick_block(sk, block_k)
     rep = hd // hkvd
 
-    fused = _kv_vmem_bytes(sq, sk, h, h // rep, d, q.dtype.itemsize,
+    fused = _flat_vmem_bytes(sq, sk, h, h // rep, d, q.dtype.itemsize,
                            block_q, block_k,
-                           fused=True) <= _KV_VMEM_LIMIT
+                           fused=True) <= _FLAT_VMEM_LIMIT
     _count_backward("flat", fused)
     if fused:
         q_full = pl.BlockSpec((None, sq, hd), lambda bi, kj: (bi, 0, 0))
@@ -1830,7 +1311,7 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
             scratch_shapes=[pltpu.VMEM((sq, hd), jnp.float32),
                             pltpu.VMEM((n_q, h, block_q), jnp.float32)],
             interpret=_interpret(),
-            compiler_params=_kv_dimsem(),
+            compiler_params=_flat_compiler_params(),
             name=_bwd_name("flash_flat_bwd"),
         )(q, k, v, out, lse, do)
 
@@ -1849,7 +1330,7 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
         interpret=_interpret(),
-        compiler_params=_kv_dimsem(),
+        compiler_params=_flat_compiler_params(),
         name=_bwd_name("flash_flat_dq"),
     )(q, k, v, out, lse, do)
 
@@ -1867,7 +1348,7 @@ def _bwd_flat(q, k, v, out, lse, do, h, causal, block_q, block_k):
         out_shape=[jax.ShapeDtypeStruct((b, sk, hkvd), k.dtype),
                    jax.ShapeDtypeStruct((b, sk, hkvd), v.dtype)],
         interpret=_interpret(),
-        compiler_params=_kv_dimsem(),
+        compiler_params=_flat_compiler_params(),
         name=_bwd_name("flash_flat_dkdv"),
     )(q, k, v, out, lse, do)
     return dq, dk, dv
@@ -1954,21 +1435,21 @@ def _stat_rows_bytes(lead, sub, bq) -> int:
     return lead * _up(sub, 8) * _up(bq, 128) * 4
 
 
-def _kv_vmem_bytes(sq, sk, h, h_kv, d, esz, bq, bk, fused=False) -> int:
-    """Scoped-VMEM estimate of the kv-native AND flat kernels (same
-    block geometry) at blocks (bq, bk): the larger of the forward (full
-    K+V per batch row) and the backward's KV-grid kernel, which keeps
-    full-sequence q/o/do resident for the head walk.  Pipelined operands
-    count twice (double buffering); the f32 logits-sized temporaries
-    (s, p, dp, ds) count once.  The split pair's dKV kernel holds the
-    column lse, lane-padded.  Checked against what the v5e compiler
-    accepts and refuses at [32,1024,12,64] bf16
-    (tests/test_chip_compile.py): (512,512) and (256,512) compile, the
-    backward at (512,1024) and (1024,1024) is RESOURCE_EXHAUSTED.
+def _flat_vmem_bytes(sq, sk, h, h_kv, d, esz, bq, bk, fused=False) -> int:
+    """Scoped-VMEM estimate of the flat kernels at blocks (bq, bk): the
+    larger of the forward (full K+V per batch row) and the backward's
+    KV-grid kernel, which keeps full-sequence q/o/do resident for the
+    head walk.  Pipelined operands count twice (double buffering); the
+    f32 logits-sized temporaries (s, p, dp, ds) count once.  The split
+    pair's dKV kernel holds the column lse, lane-padded.  Checked
+    against what the v5e compiler accepts and refuses at
+    [32,1024,12,64] bf16 (tests/test_chip_compile.py): (512,512) and
+    (256,512) compile, the backward at (512,1024) and (1024,1024) is
+    RESOURCE_EXHAUSTED.
 
-    This, the split pair's estimate, is what the kv AND flat gates and
-    their candidate lists hold to _KV_VMEM_LIMIT: the tiers' reach is
-    the split pair's.  fused=True describes the flat tier's fused
+    This, the split pair's estimate, is what the flat gate and its
+    candidate list hold to _FLAT_VMEM_LIMIT: the tier's reach is the
+    split pair's.  fused=True describes the flat tier's fused
     backward, which holds lane-dense lse and delta instead, plus the
     sequence-long dQ (its output block and the f32 accumulator);
     _bwd_flat runs it inside that reach where it fits (19.4–20.2 MiB by
@@ -2050,30 +1531,6 @@ def _t_dkdv_vmem_bytes(sq, rep, d, esz, bq, bk) -> int:
     return need + need // 6
 
 
-def _kv_native_ok(q, k, block_q=512, block_k=512, _gate="kv") -> bool:
-    """VMEM feasibility of the kv-native AND flat kernels: past the
-    per-kernel limit the transpose core (block-sliced K/V) is the safe
-    path.
-
-    block_q/block_k are the blocks that will REALLY run (the dispatch
-    site passes the tuned values), resolved through _pick_block exactly
-    as the kernels will resolve them."""
-    b, sq, h, d = q.shape
-    sk, h_kv = k.shape[1], k.shape[2]
-    if sq % 8 != 0 or sk % 8 != 0:
-        # off-8 lengths run padded through the transpose core (the
-        # dispatch pads before gating); a direct probe gets False, not
-        # the _pick_block ValueError
-        return False
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
-    if _kv_vmem_bytes(sq, sk, h, h_kv, d, q.dtype.itemsize, bq,
-                      bk) > _KV_VMEM_LIMIT:
-        _gate_reject(_gate, "vmem", q, k, (bq, bk))
-        return False
-    return True
-
-
 def _flat_static_ok(q, k) -> bool:
     """Block-INDEPENDENT flat eligibility: lane alignment — the flat
     kernels slice per-head lane windows out of an [*, H*D] block and
@@ -2096,19 +1553,30 @@ def _flat_static_ok(q, k) -> bool:
 
 
 def _flat_native_ok(q, k, block_q=512, block_k=512) -> bool:
-    """Full flat-kernel eligibility: the block-independent gates of
-    _flat_static_ok plus the VMEM bound of _kv_native_ok at the blocks
-    that will really run.  (The kv-native kernels index 4-D [S,Hkv,D]
-    blocks and need neither flat-specific gate.)"""
+    """The flat tier's one gate: the block-independent part
+    (_flat_static_ok, which counts nothing where it passes), then VMEM
+    feasibility — past the per-kernel limit the transpose core
+    (block-sliced K/V) is the safe path.
+
+    block_q/block_k are the blocks that will REALLY run (the dispatch
+    site passes the tuned values), resolved through _pick_block exactly
+    as the kernels will resolve them."""
     if not _flat_static_ok(q, k):
         return False
-    return _kv_native_ok(q, k, block_q, block_k, _gate="flat")
-
-
-def _layout_flag() -> str:
-    import os
-
-    return os.environ.get("FLAGS_flash_layout", _DEFAULT_LAYOUT)
+    b, sq, h, d = q.shape
+    sk, h_kv = k.shape[1], k.shape[2]
+    if sq % 8 != 0 or sk % 8 != 0:
+        # off-8 lengths run padded through the transpose core (the
+        # dispatch pads before gating); a direct probe gets False, not
+        # the _pick_block ValueError
+        return False
+    bq = _pick_block(sq, block_q)
+    bk = _pick_block(sk, block_k)
+    if _flat_vmem_bytes(sq, sk, h, h_kv, d, q.dtype.itemsize, bq,
+                        bk) > _FLAT_VMEM_LIMIT:
+        _gate_reject("flat", "vmem", q, k, (bq, bk))
+        return False
+    return True
 
 
 # ===================== biased (additive-mask) core =====================
@@ -2319,14 +1787,13 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
     the custom VJP. Measured at B32 H12 S1024 D64 bf16: tuned (1024,1024)
     fwd ≈ 1.3 ms vs 128x128 ≈ 6.0 ms (PERF.md).
 
-    layout: the kernel tier that will consume the blocks.  kv/flat/mh
-    layouts tune under their OWN cache signature (``|Lkv`` etc.) —
-    advisor-low r5: the kv/flat cores have different VMEM geometry than
-    the transpose core, so silently reusing transpose-tuned blocks is
-    wrong.  A transpose-tuned entry existing while the layout entry is
-    cold is counted as `autotune.cross_layout_reject` (the refusal is
-    deliberate and now visible).  `layout=None`/"transpose" keeps the
-    original signature, so existing on-disk caches stay valid.
+    layout: "flat" where the flat core will consume the blocks, None
+    for the transpose core.  Flat tunes under its OWN cache signature
+    (``|Lflat``): its VMEM geometry differs from the transpose core's,
+    so silently reusing transpose-tuned blocks is wrong.  A
+    transpose-tuned entry existing while the flat entry is cold is
+    counted as `autotune.cross_layout_reject` (the refusal is deliberate
+    and visible).  The transpose core keeps the bare signature.
 
     window: a windowed call (transpose core only) tunes under its own
     signature (`|w<keys>`): the band moves the best pair, and an
@@ -2342,16 +1809,16 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
     pairs = ((512, 1024), (1024, 1024), (512, 512), (256, 512),
              (256, 256), (128, 128))
 
-    lt = layout if layout in ("kv", "flat", "mh") else None
+    assert layout in ("flat", None), layout
     itemsize = jnp.dtype(dtype).itemsize
 
     def fits(bq, bk, tight=False):
-        if lt in ("kv", "flat"):
-            # the dispatch gate's own arithmetic (_kv_native_ok): a pair
-            # it would reject is never a candidate
-            return _kv_vmem_bytes(
+        if layout == "flat":
+            # the dispatch gate's own arithmetic (_flat_native_ok): a
+            # pair it would reject is never a candidate
+            return _flat_vmem_bytes(
                 sq, sk, h, h_kv or h, d, itemsize, bq, bk) <= (
-                    0.9 if tight else 1.0) * _KV_VMEM_LIMIT
+                    0.9 if tight else 1.0) * _FLAT_VMEM_LIMIT
         # must leave headroom in the ~16 MB/core VMEM budget; a pair
         # whose fused backward does not fit runs the split pair
         # (_bwd_t), so the fused kernel never narrows the candidates
@@ -2369,7 +1836,7 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
     # instead of a conservative constant.  The default is also what a
     # failed tuning run falls back to, and it runs UNVALIDATED — so it
     # gets a tighter bound (8 of 12 MB for the transpose core, whose
-    # estimate omits backward-only accumulators; 0.9 of the kv/flat
+    # estimate omits backward-only accumulators; 0.9 of the flat
     # limit), falling back to the smallest fitting pair rather than the
     # most aggressive one
     default = next(
@@ -2399,11 +1866,10 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
                 return _flash_core_b(qv, kv, vv, bias_v, causal, cfg[0],
                                      cfg[1]).astype(jnp.float32).sum()
         else:
-            # per-layout signatures time the layout's OWN core — caching
-            # transpose-core timings under a kv/flat key would be the
-            # same silent mismatch the layout tag exists to prevent
-            core = {"kv": _flash_core_kv, "flat": _flash_core_flat,
-                    "mh": _flash_core_mh}.get(lt, _flash_core)
+            # the flat signature times the flat core — caching
+            # transpose-core timings under its key would be the same
+            # silent mismatch the layout tag exists to prevent
+            core = _flash_core_flat if layout == "flat" else _flash_core
 
             wargs = () if window is None else (None, None, window)
 
@@ -2418,18 +1884,56 @@ def _tuned_blocks(b, sq, sk, h, d, dtype, causal, h_kv=None,
            + (f"|kv{h_kv}" if h_kv and h_kv != h else "")
            + ("|bias" if biased else "")
            + (f"|w{window}" if window is not None else ""))
-    if lt:
+    if layout == "flat":
         # layout-tagged signature; a transpose-tuned winner for the same
         # shape is NOT reused (it was measured on different kernels) —
         # count the refusal so cold layout caches are visible
-        lsig = sig + f"|L{lt}"
+        lsig = sig + "|Lflat"
         if autotune.cached_config(_AUTOTUNE_OP, lsig) is None and \
                 autotune.cached_config(_AUTOTUNE_OP, sig) is not None:
-            _metrics.inc("autotune.cross_layout_reject", layout=lt)
-            _flight.record("autotune.cross_layout_reject", layout=lt,
+            _metrics.inc("autotune.cross_layout_reject", layout="flat")
+            _flight.record("autotune.cross_layout_reject", layout="flat",
                            signature=sig)
         sig = lsig
     return autotune.pick(_AUTOTUNE_OP, sig, cands, run, default)
+
+
+# What the grouped dK/dV kernel may keep resident in VMEM for one KV
+# head: its query group's sequence-long q, o and do,
+# 3 · rep · sq · d · itemsize.  Past it a GQA call runs the MHA kernels
+# on K and V expanded rep-fold (correct, without the KV-traffic saving)
+# rather than compile an infeasible kernel.
+_GQA_GROUP_BYTES_MAX = 8 * 1024 * 1024
+
+
+def _choose_core(q, k, causal, padded, window, block_q, block_k):
+    """The core an unmasked call runs on and the blocks it runs with, as
+    ("flat" | "transpose", block_q, block_k) — decided here and nowhere
+    else, from the shape: a padded or windowed call takes the transpose
+    core; a shape the flat tier's static gates admit takes the flat core
+    at the flat signature's tuned blocks unless its VMEM bound refuses
+    them; everything else the transpose core at the transpose
+    signature's.  block_q/block_k: the caller's, kept where given.
+
+    The static gates run BEFORE the flat block search: an off-gate shape
+    must not launch an autotune search that times (and on TPU,
+    Mosaic-compiles) the flat core it can never run.  The VMEM gate
+    estimates with the blocks that will REALLY run."""
+    def blocks(layout):
+        if block_q is not None and block_k is not None:
+            return block_q, block_k
+        bq, bk = _tuned_blocks(q.shape[0], q.shape[1], k.shape[1],
+                               q.shape[2], q.shape[3], q.dtype, causal,
+                               h_kv=k.shape[2], layout=layout,
+                               window=window)
+        return (block_q if block_q is not None else bq,
+                block_k if block_k is not None else bk)
+
+    if not padded and window is None and _flat_static_ok(q, k):
+        bq, bk = blocks("flat")
+        if _flat_native_ok(q, k, bq, bk):
+            return "flat", bq, bk
+    return ("transpose",) + blocks(None)
 
 
 def _per_shard(mesh, q, k, v, mask, **kw):
@@ -2543,102 +2047,32 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False,
                          f"that are multiples of 8, got {q.shape[1]} and "
                          f"{k.shape[1]}")
     if k.shape[2] != q.shape[2]:
-        # GQA feasibility: the grouped dK/dV kernel keeps a KV head's
-        # whole query group (rep x seq_q x d of q, o, do) resident in
-        # VMEM; past the budget, fall back to expanded-KV MHA kernels
-        # (correct, just without the KV-traffic saving) rather than
-        # compile an infeasible kernel
         rep = q.shape[2] // k.shape[2]
         group_bytes = 3 * rep * q.shape[1] * q.shape[3] * q.dtype.itemsize
-        # FLAGS_flash_gqa_expand: operator escape hatch — the round-5
-        # on-chip A/B (chip_session gqa_ab) measured grouped winning
-        # forward (1.6x at B4 S2048 32q/8kv D128) but LOSING backward at
-        # 512x512 blocks (4.06 vs 2.87 ms), so the best choice is
-        # shape-dependent; grouped (less KV HBM traffic) stays the
-        # default
-        from ...core import flags as _flags
-
-        by_flag = _flags.get_flags(["FLAGS_flash_gqa_expand"])[
-            "FLAGS_flash_gqa_expand"]
-        if by_flag or group_bytes > 8 * 1024 * 1024:
+        if group_bytes > _GQA_GROUP_BYTES_MAX:
             # the grouped path refused: K and V grow rep-fold in HBM
-            _metrics.inc("flash.gqa_expand",
-                         reason="flag" if by_flag else "group_bytes")
+            _metrics.inc("flash.gqa_expand", reason="group_bytes")
             q, k, v = _expand_gqa_kv(q, k, v)
     sq, sk = q.shape[1], k.shape[1]
     pad_q = (-sq) % 8
     pad_k = (-sk) % 8
-    if pad_q or pad_k:
+    padded = bool(pad_q or pad_k)
+    if padded:
         widths = lambda p: ((0, 0), (0, p), (0, 0), (0, 0))
         with jax.named_scope(LAYOUT_SCOPE):
             q = jnp.pad(q, widths(pad_q))
             k = jnp.pad(k, widths(pad_k))
             v = jnp.pad(v, widths(pad_k))
-    # tier intent from the layout flag (before block tuning: kv/flat/mh
-    # blocks tune under their own layout-tagged autotune signature)
-    layout = _layout_flag()
-    if pad_q or pad_k or window is not None:
-        # padded and windowed shapes run the transpose core
-        intended = "transpose"
-    elif layout == "mh" and k.shape[2] == q.shape[2]:
-        intended = "mh"  # the mh core is MHA-only; GQA stays grouped
-    elif layout in ("flat", "auto"):
-        # block-independent flat gates run BEFORE layout-tagged tuning:
-        # an off-gate shape must not launch an autotune search that
-        # times (and on TPU, Mosaic-compiles) the flat core it can
-        # never run (review finding on the r6 dispatch restructure)
-        intended = "flat" if _flat_static_ok(q, k) else "transpose"
-    elif layout == "kv":
-        intended = "kv"
-    else:
-        intended = "transpose"
-
-    user_bq, user_bk = block_q, block_k
-
-    def _resolve(tag):
-        bq, bk = _tuned_blocks(q.shape[0], q.shape[1], k.shape[1],
-                               q.shape[2], q.shape[3], q.dtype,
-                               bool(is_causal), h_kv=k.shape[2],
-                               layout=tag, window=window)
-        return (user_bq if user_bq is not None else bq,
-                user_bk if user_bk is not None else bk)
-
-    if user_bq is None or user_bk is None:
-        block_q, block_k = _resolve(intended)
-    if pad_q or pad_k:
-        _count_dispatch("transpose", block_q, block_k)
-        out = _flash_core(q, k, v, bool(is_causal), block_q, block_k,
-                          sq, sk)
+    core, block_q, block_k = _choose_core(q, k, bool(is_causal), padded,
+                                          window, block_q, block_k)
+    _count_dispatch(core, block_q, block_k, window)
+    if core == "flat":
+        # unpadded [B,S,H*D] views, zero transposes
+        return _flash_core_flat(q, k, v, bool(is_causal), block_q, block_k)
+    real = (sq, sk) if padded else (None, None)
+    out = _flash_core(q, k, v, bool(is_causal), block_q, block_k, *real,
+                      window)
+    if padded:
         with jax.named_scope(LAYOUT_SCOPE):
             return out[:, :sq]
-    if intended == "mh":
-        _count_dispatch("mh", block_q, block_k)
-        return _flash_core_mh(q, k, v, bool(is_causal), block_q, block_k)
-    # the VMEM gates estimate with the blocks that will REALLY run (the
-    # tuned values above, resolved via _pick_block exactly as the kernels
-    # resolve them) — advisor-medium r5; gate rejects fall back to the
-    # transpose core with transpose-signature blocks
-    if intended == "flat":
-        # static gates already passed above; only the block-dependent
-        # VMEM bound remains
-        if _kv_native_ok(q, k, block_q, block_k, _gate="flat"):
-            # flat-native: unpadded [B,S,H*D] views, zero transposes
-            _count_dispatch("flat", block_q, block_k)
-            return _flash_core_flat(q, k, v, bool(is_causal), block_q,
-                                    block_k)
-        if user_bq is None or user_bk is None:
-            block_q, block_k = _resolve("transpose")
-    elif intended == "kv":
-        if _kv_native_ok(q, k, block_q, block_k):
-            # mixed layout: K/V/dK/dV never transpose (GQA-native via rep)
-            _count_dispatch("kv", block_q, block_k)
-            return _flash_core_kv(q, k, v, bool(is_causal), block_q,
-                                  block_k)
-        if user_bq is None or user_bk is None:
-            block_q, block_k = _resolve("transpose")
-    if window is not None:
-        _count_dispatch("transpose", block_q, block_k, window)
-        return _flash_core(q, k, v, True, block_q, block_k, None, None,
-                           window)
-    _count_dispatch("transpose", block_q, block_k)
-    return _flash_core(q, k, v, bool(is_causal), block_q, block_k)
+    return out

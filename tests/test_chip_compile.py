@@ -82,20 +82,19 @@ def flash_counters():
         metrics.disable()
 
 
-@pytest.mark.parametrize("layout,tier,blocks", [
-    ("auto", "flat", (256, 512)),          # what GPT-125M trains with
-    ("transpose", "transpose", (512, 1024)),
+@pytest.mark.parametrize("tier,blocks", [
+    ("flat", (256, 512)),          # what GPT-125M trains with
+    ("transpose", (512, 1024)),    # the same shape, refused at the gate
 ])
 @pytest.mark.parametrize("b", [8, 32])
 def test_flash_cold_default_compiles_fwd_bwd(one_chip, monkeypatch,
-                                             flash_counters, b, layout,
-                                             tier, blocks):
+                                             flash_counters, b, tier,
+                                             blocks):
     """What `scaled_dot_product_attention` runs in the train step: the
     real dispatch (`flash_attention_fwd`), cold autotune cache (the
-    static default blocks), forward and backward.  `auto` resolves to
-    flat or transpose — never the kv core, whose backward the v5e
-    compiler refuses at these shapes."""
-    monkeypatch.setenv("FLAGS_flash_layout", layout)
+    static default blocks), forward and backward, on either core."""
+    if tier == "transpose":
+        monkeypatch.setattr(fa, "_flat_static_ok", lambda q, k: False)
     monkeypatch.setattr(autotune, "_enabled", lambda: False)
 
     def fwd(q, k, v):
@@ -166,7 +165,7 @@ def test_fused_backward_compiles_padded_and_grouped(one_chip, monkeypatch,
     (at 512 x 512; the cold default (512, 1024) needs 17.8 MB there and
     takes the split pair)."""
     monkeypatch.setattr(autotune, "_enabled", lambda: False)
-    monkeypatch.setenv("FLAGS_flash_layout", "transpose")
+    monkeypatch.setattr(fa, "_flat_static_ok", lambda q, k: False)
     b, s, _, d = q_shape
 
     def loss(q, k, v):
@@ -278,28 +277,31 @@ def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
 
 
 def test_flash_candidates_fit_the_gate(monkeypatch):
-    """No kv/flat candidate list holds a pair the dispatch gate's own
+    """The flat candidate list holds no pair the dispatch gate's own
     arithmetic rejects (the compiler refuses the split pair's
-    (512,1024) and (1024,1024) backward at this shape).  Both tiers'
-    reach is the split pair's estimate; inside it the flat tier's fused
-    backward fits with room to spare."""
+    (512,1024) and (1024,1024) backward at this shape).  The tier's
+    reach is the split pair's estimate; inside it the fused backward
+    fits with room to spare.  And the two cells' searches are keyed as
+    they always were: a winner cached on disk is still found."""
     seen = {}
 
     def spy(op, sig, cands, run, default):
-        seen[sig] = (list(cands), default)
+        seen[op, sig] = (list(cands), default)
         return default
 
     monkeypatch.setattr(autotune, "pick", spy)
     q = jax.ShapeDtypeStruct((32, S, H, D), jnp.bfloat16)
-    for lt in ("flat", "kv"):
-        fa._tuned_blocks(32, S, S, H, D, q.dtype, True, layout=lt)
-    assert len(seen) == 2
-    for cands, default in seen.values():
-        assert default in cands
-        assert (512, 1024) not in cands and (1024, 1024) not in cands
-        assert all(fa._kv_native_ok(q, q, *c) for c in cands)
-        assert all(fa._kv_vmem_bytes(S, S, H, H, D, 2, *c, fused=True)
-                   < fa._kv_vmem_bytes(S, S, H, H, D, 2, *c) for c in cands)
+    fa._tuned_blocks(32, S, S, H, D, q.dtype, True, layout="flat")
+    fa._tuned_blocks(16, 2048, 2048, H, D, q.dtype, True)
+    flat_key = ("flash_fwd_fusedbwd", "32x1024x1024x12x64|bfloat16|c1|Lflat")
+    assert list(seen) == [
+        flat_key, ("flash_fwd_fusedbwd", "16x2048x2048x12x64|bfloat16|c1")]
+    cands, default = seen[flat_key]
+    assert default in cands
+    assert (512, 1024) not in cands and (1024, 1024) not in cands
+    assert all(fa._flat_native_ok(q, q, *c) for c in cands)
+    assert all(fa._flat_vmem_bytes(S, S, H, H, D, 2, *c, fused=True)
+               < fa._flat_vmem_bytes(S, S, H, H, D, 2, *c) for c in cands)
     # the flat gate still refuses the seq2048 cell's shape at EVERY
     # candidate (the fused estimate alone would let (256, 256) through,
     # and the chip's compiler refuses that kernel): that cell exists to
@@ -307,8 +309,7 @@ def test_flash_candidates_fit_the_gate(monkeypatch):
     q2 = jax.ShapeDtypeStruct((16, 2048, H, D), jnp.bfloat16)
     pairs = ((512, 1024), (1024, 1024), (512, 512), (256, 512),
              (256, 256), (128, 128))
-    assert not any(fa._kv_native_ok(q2, q2, *c, _gate="flat")
-                   for c in pairs)
+    assert not any(fa._flat_native_ok(q2, q2, *c) for c in pairs)
 
 
 def _engine_shapes():

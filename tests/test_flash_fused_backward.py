@@ -22,7 +22,7 @@ from paddle_tpu.observability import metrics
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 CORES = {"transpose": (fa._flash_core, "_T_VMEM_LIMIT"),
-         "flat": (fa._flash_core_flat, "_KV_VMEM_LIMIT")}
+         "flat": (fa._flash_core_flat, "_FLAT_VMEM_LIMIT")}
 
 
 def _rand(shape, seed):

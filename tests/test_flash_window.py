@@ -94,7 +94,6 @@ def test_dispatch_windowed_call_names_counts_and_falls_back(rng, monkeypatch):
     sequence is the un-windowed call; a mask, no causality or a length
     that needs padding is refused; `flash.gqa_expand{reason}` says why K
     and V grew."""
-    from paddle_tpu.core import flags
     from paddle_tpu.observability import metrics
 
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
@@ -119,14 +118,13 @@ def test_dispatch_windowed_call_names_counts_and_falls_back(rng, monkeypatch):
         assert c["flash.dispatch{tier=transpose}"] == 1  # no window left
         assert not any("window" in n for n in c)
 
-        flags.set_flags({"FLAGS_flash_gqa_expand": True})
-        try:
-            before = dict(metrics.snapshot()["counters"])
-            fa.flash_attention_fwd(q, k, v, is_causal=True, window=24)
-            assert _counters(metrics, before)[
-                "flash.gqa_expand{reason=flag}"] == 1
-        finally:
-            flags.set_flags({"FLAGS_flash_gqa_expand": False})
+        # the group past the bound (here 3 * 2 * 64 * 32 * 4 bytes)
+        monkeypatch.setattr(fa, "_GQA_GROUP_BYTES_MAX",
+                            3 * 2 * 64 * 32 * 4 - 1)
+        before = dict(metrics.snapshot()["counters"])
+        fa.flash_attention_fwd(q, k, v, is_causal=True, window=24)
+        assert _counters(metrics, before)[
+            "flash.gqa_expand{reason=group_bytes}"] == 1
     finally:
         if not was:
             metrics.disable()

@@ -1,8 +1,10 @@
 """Layout-parity suite (ISSUE 10): the transpose-free FLAT attention
-layout is the default — these tests hold it bit-identical to the
-transpose core at the kernel level AND at the real model call sites
-(GPT causal MHA, LLaMA GQA+RoPE, ERNIE bidirectional + additive mask),
-so the default flip can never silently change training numerics.
+core runs wherever its gates admit the shape — these tests hold it
+bit-identical to the transpose core at the kernel level AND at the real
+model call sites (GPT causal MHA, LLaMA GQA+RoPE, ERNIE bidirectional +
+additive mask), so which core a shape takes can never silently change
+training numerics — and hold the dispatch to choosing the core from the
+shape alone.
 
 All kernels run through the Pallas interpreter on CPU (the fake-backend
 strategy, SURVEY §4.5): every layout executes the same shared
@@ -53,12 +55,21 @@ def test_flat_vs_transpose_core_bit_identical(hq, hkv):
                 f"d{name} differs between layouts (causal={causal})"
 
 
+_FLAT_STATIC_OK = fa._flat_static_ok
+
+
+def _force_core(monkeypatch, layout):
+    """`flat` leaves the choice to the shape (these call sites' widths
+    pass the static gates where their head counts allow); the
+    `transpose` side of a comparison refuses every shape there."""
+    monkeypatch.setattr(fa, "_flat_static_ok", {
+        "flat": _FLAT_STATIC_OK,
+        "transpose": lambda q_, k_: False}[layout])
+
+
 def test_default_layout_is_flat(monkeypatch):
-    """With no FLAGS_flash_layout set, eligible shapes route to the
-    flat core (the ISSUE-10 default flip: _DEFAULT_LAYOUT='auto'
-    prefers flat wherever the static gates admit it)."""
-    monkeypatch.delenv("FLAGS_flash_layout", raising=False)
-    assert fa._DEFAULT_LAYOUT == "auto"
+    """Eligible shapes route to the flat core and match the reference;
+    a shape the static gates refuse lands on transpose."""
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
     B, S, H, D = 2, 64, 2, 64
     q = _rand((B, S, H, D))
@@ -91,13 +102,109 @@ def test_default_layout_is_flat(monkeypatch):
         "gate-rejected shape did not fall back to the transpose core"
 
 
+def _blocks(tier, bq, bk, **extra):
+    labels = dict(block_k=bk, block_q=bq, tier=tier, **extra)
+    return "flash.blocks{%s}" % ",".join(
+        f"{k}={v}" for k, v in sorted(labels.items()))
+
+
+# One case per arm of _choose_core and of the GQA expansion rule:
+# (q shape, KV heads, call keywords, what the case sets up) ->
+# (core reached, KV heads it sees, every flash.* counter of the trace).
+_DISPATCH = {
+    "flat": (
+        (2, 128, 12, 64), 12, {}, None,
+        "flat", 12, {"flash.dispatch{tier=flat}": 1,
+                     _blocks("flat", 128, 128): 1}),
+    "head_width": (   # 4 x 32 = 128 lanes, but a 32-lane head slice
+        (2, 128, 4, 32), 4, {}, None,
+        "transpose", 4, {"flash.gate_reject{gate=flat,reason=head_width}": 1,
+                         "flash.dispatch{tier=transpose}": 1,
+                         _blocks("transpose", 128, 128): 1}),
+    "lane_align": (   # 3 x 64 = 192: off the 128-lane tile
+        (2, 128, 3, 64), 3, {}, None,
+        "transpose", 3, {"flash.gate_reject{gate=flat,reason=lane_align}": 1,
+                         "flash.dispatch{tier=transpose}": 1,
+                         _blocks("transpose", 128, 128): 1}),
+    "vmem": (         # gpt3-125m.train.seq2048's call, at its blocks
+        (16, 2048, 12, 64), 12, dict(block_q=512, block_k=512), None,
+        "transpose", 12, {"flash.gate_reject{gate=flat,reason=vmem}": 1,
+                          "flash.dispatch{tier=transpose}": 1,
+                          _blocks("transpose", 512, 512): 1}),
+    "padded": (       # ViT's 197 runs padded to 200: no flat gate is asked
+        (2, 197, 12, 64), 12, {}, None,
+        "transpose", 12, {"flash.dispatch{tier=transpose}": 1,
+                          _blocks("transpose", 200, 200): 1}),
+    "window": (
+        (2, 256, 2, 64), 2, dict(window=64), None,
+        "transpose", 2, {"flash.dispatch{tier=transpose,window=64}": 1,
+                         _blocks("transpose", 256, 256, window=64): 1}),
+    "gqa_grouped": (  # the group fits: the core reads the 2 KV heads
+        (2, 128, 4, 64), 2, {}, None,
+        "flat", 2, {"flash.dispatch{tier=flat}": 1,
+                    _blocks("flat", 128, 128): 1}),
+    "gqa_expanded": (  # 3 * 2 * 128 * 64 * 2 bytes a group, bound one under
+        (2, 128, 4, 64), 2, {}, "group_bytes",
+        "flat", 4, {"flash.gqa_expand{reason=group_bytes}": 1,
+                    "flash.dispatch{tier=flat}": 1,
+                    _blocks("flat", 128, 128): 1}),
+    "env_ignored": (  # the variable that once chose a core is not read
+        (2, 128, 12, 64), 12, {}, "env",
+        "flat", 12, {"flash.dispatch{tier=flat}": 1,
+                     _blocks("flat", 128, 128): 1}),
+}
+
+
+@pytest.mark.parametrize("case", list(_DISPATCH))
+def test_dispatch_chooses_core_from_shape(monkeypatch, case):
+    """The dispatch decides the core from what it can observe — shape,
+    padding, window — and counts what it decided.  Traced only
+    (`jax.eval_shape`): the counters are trace-time, no kernel runs."""
+    from paddle_tpu.observability import metrics
+
+    shape, h_kv, kw, setup, core, kv_heads, counters = _DISPATCH[case]
+    if setup == "env":
+        monkeypatch.setenv("FLAGS_flash_layout", "kv")
+    elif setup == "group_bytes":
+        monkeypatch.setattr(fa, "_GQA_GROUP_BYTES_MAX",
+                            3 * 2 * 128 * 64 * 2 - 1)
+    monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
+    reached = []
+    for name, attr in (("transpose", "_flash_core"),
+                       ("flat", "_flash_core_flat")):
+        def spy(q_, k_, *a, _name=name, _orig=getattr(fa, attr)):
+            reached.append((_name, k_.shape[2]))
+            return _orig(q_, k_, *a)
+
+        monkeypatch.setattr(fa, attr, spy)
+    b, s, _, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16)
+    was = metrics.enabled()
+    metrics.enable()
+    before = dict(metrics.snapshot()["counters"])
+    try:
+        out = jax.eval_shape(
+            lambda q_, k_, v_: fa.flash_attention_fwd(
+                q_, k_, v_, is_causal=True, **kw), q, k, k)
+        now = metrics.snapshot()["counters"]
+    finally:
+        if not was:
+            metrics.disable()
+    assert out.shape == shape
+    assert reached == [(core, kv_heads)]
+    delta = {n: v - before.get(n, 0) for n, v in now.items()
+             if n.startswith("flash.") and v - before.get(n, 0)}
+    assert delta == counters
+
+
 def _llama_attention_grads(monkeypatch, layout):
     """One LLaMA attention call site (GQA + RoPE + row/col projections)
     forward + backward under the given layout; returns (out, dx, dw)."""
     import paddle_tpu.ops.pallas as _pl
     from paddle_tpu.models.llama import LlamaAttention, LlamaConfig
 
-    monkeypatch.setenv("FLAGS_flash_layout", layout)
+    _force_core(monkeypatch, layout)
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
     monkeypatch.setattr(_pl, "flash_attention_available",
                         lambda q_: True)
@@ -130,7 +237,7 @@ def _gpt_attention_grads(monkeypatch, layout):
     import paddle_tpu.ops.pallas as _pl
     from paddle_tpu.models.gpt import GPTAttention, GPTConfig
 
-    monkeypatch.setenv("FLAGS_flash_layout", layout)
+    _force_core(monkeypatch, layout)
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
     monkeypatch.setattr(_pl, "flash_attention_available",
                         lambda q_: True)
@@ -164,7 +271,7 @@ def _ernie_encoder_grads(monkeypatch, layout):
     import paddle_tpu.ops.pallas as _pl
     from paddle_tpu.models.ernie import ErnieConfig, ErnieModel
 
-    monkeypatch.setenv("FLAGS_flash_layout", layout)
+    _force_core(monkeypatch, layout)
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
     monkeypatch.setattr(_pl, "flash_attention_available",
                         lambda q_: True)

@@ -45,14 +45,14 @@ def test_counter_labels_and_snapshot():
     metrics.enable()
     metrics.inc("flash.dispatch", tier="flat")
     metrics.inc("flash.dispatch", tier="flat")
-    metrics.inc("flash.dispatch", tier="kv")
+    metrics.inc("flash.dispatch", tier="transpose")
     metrics.inc("plain")
     metrics.set_gauge("mem.peak_bytes_in_use", 123)
     metrics.observe("step.wall_ms", 2.0)
     metrics.observe("step.wall_ms", 4.0)
     snap = metrics.snapshot()
     assert snap["counters"]["flash.dispatch{tier=flat}"] == 2
-    assert snap["counters"]["flash.dispatch{tier=kv}"] == 1
+    assert snap["counters"]["flash.dispatch{tier=transpose}"] == 1
     assert snap["counters"]["plain"] == 1
     assert snap["gauges"]["mem.peak_bytes_in_use"] == 123
     h = snap["histograms"]["step.wall_ms"]
@@ -63,10 +63,10 @@ def test_counter_labels_and_snapshot():
 def test_declare_pre_registers_zero():
     # declare works even while disabled — schema, not a hot path
     metrics.declare("autotune.hit")
-    metrics.declare("flash.dispatch", tier="mh")
+    metrics.declare("flash.dispatch", tier="biased")
     snap = metrics.snapshot()
     assert snap["counters"]["autotune.hit"] == 0
-    assert snap["counters"]["flash.dispatch{tier=mh}"] == 0
+    assert snap["counters"]["flash.dispatch{tier=biased}"] == 0
 
 
 def test_disabled_path_is_noop_and_cheap():
@@ -222,9 +222,9 @@ def _flash_fa():
 
 def test_flash_dispatch_tier_counters(monkeypatch):
     """End-to-end dispatch-tier counters for representative shapes: the
-    layout flag routes to flat/kv/transpose (interpret-mode kernels on
-    CPU) and each dispatch increments its tier counter; the CPU
-    fallback increments tier=fallback."""
+    shape routes to flat or transpose (interpret-mode kernels on CPU)
+    and each dispatch increments its tier counter; the CPU fallback
+    increments tier=fallback."""
     fa = _flash_fa()
     metrics.enable()
     q = _rand((1, 128, 2, 64))
@@ -237,14 +237,13 @@ def test_flash_dispatch_tier_counters(monkeypatch):
         "flash.fallback_reason{reason=unavailable}"] == 1
 
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
-    for layout, tier in (("transpose", "transpose"), ("kv", "kv"),
-                         ("flat", "flat"), ("auto", "flat")):
-        monkeypatch.setenv("FLAGS_flash_layout", layout)
-        fa.flash_attention_fwd(q, q, q, is_causal=True)
+    # H*D = 128 at head size 64 takes flat; a length off 8 is padded
+    # and takes transpose
+    for x, tier in ((q, "flat"), (_rand((1, 60, 2, 64)), "transpose")):
+        fa.flash_attention_fwd(x, x, x, is_causal=True)
         snap = metrics.snapshot()
         assert snap["counters"].get(
-            "flash.dispatch{tier=%s}" % tier, 0) >= 1, (layout, snap)
-    assert snap["counters"]["flash.dispatch{tier=flat}"] == 2  # flat+auto
+            "flash.dispatch{tier=%s}" % tier, 0) == 1, (tier, snap)
 
 
 def test_flash_gate_reject_metric_and_flight(monkeypatch):
@@ -253,7 +252,6 @@ def test_flash_gate_reject_metric_and_flight(monkeypatch):
     fa = _flash_fa()
     metrics.enable()
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
-    monkeypatch.setenv("FLAGS_flash_layout", "flat")
     # d=32: lane-aligned (4*32=128) but head width not compile-proven
     q = _rand((1, 128, 4, 32))
     fa.flash_attention_fwd(q, q, q, is_causal=True)
@@ -271,14 +269,14 @@ def test_flash_gate_reject_metric_and_flight(monkeypatch):
         shape = (1, 1024, 12, 64)
         dtype = jnp.dtype(jnp.bfloat16)
 
-    assert not fa._kv_native_ok(_Mid(), _Mid(), 1024, 1024)
+    assert not fa._flat_native_ok(_Mid(), _Mid(), 1024, 1024)
     snap = metrics.snapshot()
-    assert snap["counters"]["flash.gate_reject{gate=kv,reason=vmem}"] == 1
+    assert snap["counters"]["flash.gate_reject{gate=flat,reason=vmem}"] == 1
 
 
 def test_autotune_cross_layout_reject(monkeypatch):
     """Satellite: a transpose-tuned cache entry is NOT silently reused
-    by the kv/flat cores — the refusal counts
+    by the flat core — the refusal counts
     autotune.cross_layout_reject."""
     fa = _flash_fa()
     from paddle_tpu.ops.pallas import autotune
@@ -296,8 +294,7 @@ def test_autotune_cross_layout_reject(monkeypatch):
     assert snap["counters"][
         "autotune.cross_layout_reject{layout=flat}"] == 1
     # transpose signature itself does NOT count a refusal
-    fa._tuned_blocks(b, sq, sk, h, d, jnp.bfloat16, True,
-                     layout="transpose")
+    fa._tuned_blocks(b, sq, sk, h, d, jnp.bfloat16, True)
     snap = metrics.snapshot()
     assert snap["counters"][
         "autotune.cross_layout_reject{layout=flat}"] == 1
@@ -489,14 +486,13 @@ def test_attach_snapshot_schema_end_to_end(monkeypatch):
     # dispatch tiers all present (pre-declared), fallback actually fired
     # ON the declared key — declared schema keys carry exactly the label
     # sets the live increments use
-    for tier in ("transpose", "kv", "flat", "mh", "fallback", "biased"):
+    for tier in ("transpose", "flat", "fallback", "biased"):
         assert "flash.dispatch{tier=%s}" % tier in c
     assert c["flash.dispatch{tier=fallback}"] >= 1
     assert c["flash.fallback_reason{reason=unavailable}"] >= 1
     # autotune + retrace + collective schema present even when cold
     for key in ("autotune.hit", "autotune.miss",
                 "autotune.cross_layout_reject{layout=flat}",
-                "autotune.cross_layout_reject{layout=kv}",
                 "jit.retrace", "jit.trace_cache.hit",
                 "jit.trace_cache.miss",
                 "collective.calls{kind=all_reduce}",

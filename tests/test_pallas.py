@@ -296,21 +296,6 @@ def test_flash_indivisible_seq_raises_loud():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_mh_forward_matches_transpose_path(causal):
-    """All-heads-in-block forward (_fwd_mh, zero layout changes) must be
-    numerically identical to the transpose path — including the LSE, so
-    either forward can feed the same backward."""
-    B, S, H, D = 2, 128, 3, 32
-    q, k, v = _rand((B, S, H, D)), _rand((B, S, H, D)), _rand((B, S, H, D))
-    out_mh, lse_mh = fa._fwd_mh(q, k, v, causal, 64, 64)
-    out_t, lse_t = fa._fwd(q, k, v, causal, 64, 64)
-    np.testing.assert_allclose(out_mh, out_t, atol=1e-6, rtol=1e-6)
-    np.testing.assert_allclose(lse_mh, lse_t, atol=1e-6, rtol=1e-6)
-    ref = fa._ref_attention(q, k, v, None, causal)
-    np.testing.assert_allclose(out_mh, ref, atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("causal", [False, True])
 def test_flash_padded_odd_lengths_match_reference(causal):
     """Odd (ViT-style) sequence lengths: zero-pad to a multiple of 8,
     mask on the REAL lengths inside the kernels, slice the output.
@@ -338,77 +323,6 @@ def test_flash_padded_odd_lengths_match_reference(causal):
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_mh_backward_matches_transpose_path(causal):
-    """End-to-end mh core (fwd+bwd, zero layout changes) must produce the
-    same gradients as the transpose core — both share _dq_loop/_dkv_loop,
-    so any drift means the layouts plumb different data."""
-    B, S, H, D = 2, 128, 3, 32
-    q, k, v = _rand((B, S, H, D)), _rand((B, S, H, D)), _rand((B, S, H, D))
-
-    def loss(core, q_, k_, v_):
-        return (core(q_, k_, v_, causal, 64, 64)
-                .astype(jnp.float32) * 0.01).sum()
-
-    g_t = jax.grad(lambda *a: loss(fa._flash_core, *a),
-                   argnums=(0, 1, 2))(q, k, v)
-    g_mh = jax.grad(lambda *a: loss(fa._flash_core_mh, *a),
-                    argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_t, g_mh):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_kv_native_matches_transpose_path(causal):
-    """Mixed-layout core (K/V/dK/dV stay [B,S,H,D]; round-5 kv kernels):
-    forward, LSE, and all three gradients must be numerically identical
-    to the transpose core — the loop bodies are shared, so any drift
-    means the layouts plumb different data."""
-    B, S, H, D = 2, 128, 3, 32
-    q, k, v = _rand((B, S, H, D)), _rand((B, S, H, D)), _rand((B, S, H, D))
-    out_kv, lse_kv = fa._fwd_kv(jnp.swapaxes(q, 1, 2), k, v, causal,
-                                64, 64)
-    out_t, lse_t = fa._fwd(q, k, v, causal, 64, 64)
-    np.testing.assert_allclose(jnp.swapaxes(out_kv, 1, 2), out_t,
-                               atol=1e-6, rtol=1e-6)
-    np.testing.assert_allclose(lse_kv, lse_t, atol=1e-6, rtol=1e-6)
-
-    def loss(core, q_, k_, v_):
-        return (core(q_, k_, v_, causal, 64, 64)
-                .astype(jnp.float32) * 0.01).sum()
-
-    g_t = jax.grad(lambda *a: loss(fa._flash_core, *a),
-                   argnums=(0, 1, 2))(q, k, v)
-    g_kv = jax.grad(lambda *a: loss(fa._flash_core_kv, *a),
-                    argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_t, g_kv):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_kv_native_gqa_matches_transpose_path(causal):
-    """kv-native GQA: the grouped-KV read (hh // rep) and the
-    group-summed dK/dV must match the transpose grouped core."""
-    B, S, HQ, HKV, D = 2, 128, 4, 2, 32
-    q = _rand((B, S, HQ, D))
-    k = _rand((B, S, HKV, D))
-    v = _rand((B, S, HKV, D))
-
-    def loss(core, q_, k_, v_):
-        return (core(q_, k_, v_, causal, 64, 64)
-                .astype(jnp.float32) * 0.01).sum()
-
-    out_kv = fa._flash_core_kv(q, k, v, causal, 64, 64)
-    out_t = fa._flash_core(q, k, v, causal, 64, 64)
-    np.testing.assert_allclose(out_kv, out_t, atol=1e-6, rtol=1e-6)
-    g_t = jax.grad(lambda *a: loss(fa._flash_core, *a),
-                   argnums=(0, 1, 2))(q, k, v)
-    g_kv = jax.grad(lambda *a: loss(fa._flash_core_kv, *a),
-                    argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_t, g_kv):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -449,65 +363,47 @@ def test_flash_flat_native_matches_transpose_path(causal):
         np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
 
 
-def test_flash_kv_native_dispatch_gate(monkeypatch):
-    """FLAGS_flash_layout=kv routes eligible unpadded shapes through the
-    kv-native core and leaves VMEM-infeasible shapes on the transpose
-    path (_kv_native_ok)."""
+def test_flash_flat_gate_and_default_dispatch(monkeypatch):
+    """The flat tier's gate (_flat_native_ok: lane alignment, head
+    width, then VMEM at the blocks that will REALLY run), and the
+    dispatch behind it: an eligible unpadded shape reaches the flat core
+    with no flag set and matches the reference."""
     B, S, H, D = 2, 128, 2, 64
     q = _rand((B, S, H, D))
-    assert fa._kv_native_ok(q, q)
-    big = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    assert fa._flat_native_ok(q, q)  # H*D = 128: lane-aligned, D%64==0
 
-    class _Fake:
-        shape = big.shape
+    class _Fake:  # VMEM-infeasible: all heads' sequence-long operands
+        shape = (1, 8192, 32, 128)
         dtype = jnp.dtype(jnp.bfloat16)
 
-    assert not fa._kv_native_ok(_Fake(), _Fake())
-    assert fa._flat_native_ok(q, q)  # H*D = 128: lane-aligned, D%64==0
+    assert not fa._flat_native_ok(_Fake(), _Fake())
 
     class _OffTile:  # H*D = 64 — below the 128-lane tile
         shape = (2, 128, 4, 16)
         dtype = jnp.dtype(jnp.bfloat16)
 
-    assert fa._kv_native_ok(_OffTile(), _OffTile())  # kv: no lane gate
     assert not fa._flat_native_ok(_OffTile(), _OffTile())
 
     class _OffHead:  # H*D = 128 lane-aligned but D=32: not compile-proven
         shape = (2, 128, 4, 32)
         dtype = jnp.dtype(jnp.bfloat16)
 
-    assert fa._kv_native_ok(_OffHead(), _OffHead())  # kv: no width gate
     assert not fa._flat_native_ok(_OffHead(), _OffHead())
 
     class _Mid:  # VMEM-borderline: feasible at 512 blocks, not at 1024
         shape = (1, 1024, 12, 64)
         dtype = jnp.dtype(jnp.bfloat16)
 
-    # advisor-medium r5: the gate estimates with the blocks that will
-    # REALLY run — tuned 1024-blocks must be gated as 1024, not as the
-    # old hardcoded 512 estimate
-    assert fa._kv_native_ok(_Mid(), _Mid(), 512, 512)
-    assert not fa._kv_native_ok(_Mid(), _Mid(), 1024, 1024)
+    # the gate estimates with the blocks that will REALLY run — tuned
+    # 1024-blocks must be gated as 1024, not as a hardcoded 512 estimate
+    assert fa._flat_native_ok(_Mid(), _Mid(), 512, 512)
+    assert not fa._flat_native_ok(_Mid(), _Mid(), 1024, 1024)
 
-    monkeypatch.setenv("FLAGS_flash_layout", "kv")
     # on CPU the public entry routes to the reference path
     # (flash_attention_available gates on TPU); force the interpreter
     # kernels so the dispatch decision itself is what's under test
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
     called = {}
-    orig = fa._flash_core_kv
-
-    def spy(*a, **kw):
-        called["kv"] = True
-        return orig(*a, **kw)
-
-    monkeypatch.setattr(fa, "_flash_core_kv", spy)
-    out = fa.flash_attention_fwd(q, q, q, is_causal=True)
-    assert called.get("kv"), "kv layout flag did not route to the kv core"
-    ref = fa._ref_attention(q, q, q, None, True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-    # flat (and auto, which prefers flat) route to the flat core
     orig_flat = fa._flash_core_flat
 
     def spy_flat(*a, **kw):
@@ -515,47 +411,39 @@ def test_flash_kv_native_dispatch_gate(monkeypatch):
         return orig_flat(*a, **kw)
 
     monkeypatch.setattr(fa, "_flash_core_flat", spy_flat)
-    for flag in ("flat", "auto"):
-        called.pop("flat", None)
-        monkeypatch.setenv("FLAGS_flash_layout", flag)
-        out = fa.flash_attention_fwd(q, q, q, is_causal=True)
-        assert called.get("flat"), (
-            f"layout {flag!r} did not route to the flat core")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+    out = fa.flash_attention_fwd(q, q, q, is_causal=True)
+    assert called.get("flat"), "an eligible shape did not reach the flat core"
+    ref = fa._ref_attention(q, q, q, None, True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
 
 
-def test_flash_gqa_expand_flag_routes(monkeypatch):
-    """FLAGS_flash_gqa_expand forces the expanded-KV path: the kernels
-    then see Hkv == Hq (and the result still matches the reference)."""
-    from paddle_tpu.core import flags as _flags
-
+def test_flash_gqa_expand_routes_by_group_bytes(monkeypatch):
+    """A KV head's resident query group past _GQA_GROUP_BYTES_MAX takes
+    the expanded-KV path: the kernels then see Hkv == Hq (and the result
+    still matches the reference); under the bound KV stays shrunk."""
     B, S, HQ, HKV, D = 2, 128, 4, 2, 32
     q = _rand((B, S, HQ, D))
     k = _rand((B, S, HKV, D))
     v = _rand((B, S, HKV, D))
     monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
-    # pin the layout: an inherited FLAGS_flash_layout=flat/kv would route
-    # past the _flash_core spy and fail this test spuriously
-    monkeypatch.setenv("FLAGS_flash_layout", "transpose")
     seen = {}
-    orig = fa._flash_core
+    orig = fa._flash_core  # d = 32: the flat gate refuses, transpose runs
 
     def spy(q_, k_, v_, *a, **kw):
         seen["h_kv"] = k_.shape[2]
         return orig(q_, k_, v_, *a, **kw)
 
     monkeypatch.setattr(fa, "_flash_core", spy)
-    _flags.set_flags({"FLAGS_flash_gqa_expand": True})
-    try:
-        out = fa.flash_attention_fwd(q, k, v, is_causal=True)
-    finally:
-        _flags.set_flags({"FLAGS_flash_gqa_expand": False})
-    assert seen.get("h_kv") == HQ, "expand flag did not expand KV heads"
+    # the group here: 3 * 2 * 128 * 32 * 4 bytes
+    monkeypatch.setattr(fa, "_GQA_GROUP_BYTES_MAX", 3 * 2 * S * D * 4 - 1)
+    out = fa.flash_attention_fwd(q, k, v, is_causal=True)
+    assert seen.get("h_kv") == HQ, "a group past the bound was not expanded"
     ref = fa._ref_attention(q, k, v, None, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-    # default: grouped (KV stays shrunk)
+    # at the bound: grouped (KV stays shrunk)
+    monkeypatch.setattr(fa, "_GQA_GROUP_BYTES_MAX", 3 * 2 * S * D * 4)
     seen.clear()
     out = fa.flash_attention_fwd(q, k, v, is_causal=True)
     assert seen.get("h_kv") == HKV
@@ -904,13 +792,13 @@ def test_autotune_pick_searches_inside_a_trace(monkeypatch, tmp_path):
     assert autotune.cached_config("testop", "sig") == timed[0]
 
 
-@pytest.mark.slow
-def test_train_step_layout_parity(monkeypatch):
-    """FULL GPT train step, loss parity across flash layouts: on the
-    interpreter every layout runs the same shared recurrences, so three
-    steps of training must produce identical losses whether the flash
-    dispatch routes transpose, kv-native, or flat-native. Guards the
-    opt-in layouts at the train-step level (not just the kernel level)."""
+@pytest.mark.parametrize("layout", ["transpose", "flat"])
+def test_train_step_layout_parity(monkeypatch, layout):
+    """FULL GPT train step against the reference attention: on the
+    interpreter both cores run the same shared recurrences, so three
+    steps of training must produce the reference path's losses whether
+    the dispatch reaches the transpose or the flat core. Guards the
+    cores at the train-step level (not just the kernel level)."""
     import paddle_tpu as P
     from paddle_tpu.distributed import fleet, topology
     from paddle_tpu.models.gpt import (
@@ -919,30 +807,15 @@ def test_train_step_layout_parity(monkeypatch):
 
     import paddle_tpu.ops.pallas as _pl
 
-    # BOTH bindings: fa.flash_attention_fwd consults the module global,
-    # but nn.functional.attention gates on the package re-export — the
-    # unpatched one silently routes everything to the reference path
-    monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
-    monkeypatch.setattr(_pl, "flash_attention_available",
-                        lambda q_: True)
     # hidden 128 / 2 heads -> head_dim 64, H*D = 128: satisfies both the
-    # lane-alignment gate AND the d%64 head-width gate (_flat_native_ok)
-    # so kv/flat route
+    # lane-alignment gate AND the d%64 head-width gate (_flat_static_ok),
+    # so the shape takes flat by itself; the transpose case refuses it
     kw = dict(vocab_size=211, hidden_size=128, num_layers=2, num_heads=2,
               max_seq_len=32, dropout=0.0, attn_dropout=0.0)
-    losses = {}
-    routed = {}
-    cores = {"transpose": "_flash_core", "kv": "_flash_core_kv",
-             "flat": "_flash_core_flat"}
-    for layout in ("transpose", "kv", "flat"):
-        monkeypatch.setenv("FLAGS_flash_layout", layout)
-        orig_core = getattr(fa, cores[layout])
+    core = {"transpose": "_flash_core", "flat": "_flash_core_flat"}[layout]
+    routed = []
 
-        def spy(*a, _oc=orig_core, _ly=layout, **kw2):
-            routed[_ly] = True
-            return _oc(*a, **kw2)
-
-        monkeypatch.setattr(fa, cores[layout], spy)
+    def three_steps():
         topology.reset_topology()
         strategy = fleet.DistributedStrategy()
         strategy.hybrid_configs = {
@@ -960,12 +833,26 @@ def test_train_step_layout_parity(monkeypatch):
         rs = np.random.RandomState(3)
         ids = P.to_tensor(rs.randint(0, 211, (2, 32)), "int32")
         lab = P.to_tensor(rs.randint(0, 211, (2, 32)), "int32")
-        losses[layout] = [float(step(ids, lab)) for _ in range(3)]
-        monkeypatch.setattr(fa, cores[layout], orig_core)
-        assert routed.get(layout), (
-            f"layout {layout!r} never reached its flash core — "
-            "dispatch fell back, the parity comparison would be vacuous")
-    np.testing.assert_allclose(losses["transpose"], losses["kv"],
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(losses["transpose"], losses["flat"],
-                               rtol=1e-6, atol=1e-6)
+        return [float(step(ids, lab)) for _ in range(3)]
+
+    reference = three_steps()   # flash unavailable on CPU: _ref_attention
+    # BOTH bindings: fa.flash_attention_fwd consults the module global,
+    # but nn.functional.attention gates on the package re-export — the
+    # unpatched one silently routes everything to the reference path
+    monkeypatch.setattr(fa, "flash_attention_available", lambda q_: True)
+    monkeypatch.setattr(_pl, "flash_attention_available",
+                        lambda q_: True)
+    if layout == "transpose":
+        monkeypatch.setattr(fa, "_flat_static_ok", lambda q_, k_: False)
+    orig_core = getattr(fa, core)
+
+    def spy(*a, **kw2):
+        routed.append(layout)
+        return orig_core(*a, **kw2)
+
+    monkeypatch.setattr(fa, core, spy)
+    losses = three_steps()
+    assert routed, (
+        f"{layout!r} never reached its flash core — dispatch fell "
+        "back, the parity comparison would be vacuous")
+    np.testing.assert_allclose(losses, reference, rtol=1e-5, atol=1e-5)

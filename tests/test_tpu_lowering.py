@@ -194,19 +194,6 @@ def test_gate_catches_bad_blockspec():
         _lower_for_tpu(bad, x)
 
 
-@pytest.mark.parametrize("shape", [(8, 1024, 12, 64), (2, 2048, 32, 128)])
-def test_flash_mh_fwd_lowers(shape):
-    """The multi-head-block forward reads [B,S,H,D] in place (full-H
-    blocks — the equal-to-array-dim rule); the squeezed-H alternative is
-    un-lowerable, so this gate is what keeps the transpose-free path
-    honest."""
-    b, s, h, d = shape
-    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
-    f = lambda q, k, v: fa._fwd_mh(q, k, v, True, 128, 128)[0]
-    mlir = _lower_for_tpu(f, q, q, q)
-    _assert_mosaic(mlir)
-
-
 def test_flash_padded_vit_length_lowers():
     """The padded odd-length path (flash_attention_fwd at ViT's S=197)
     must lower: pad -> kernel with real-length masking -> slice."""
@@ -218,19 +205,6 @@ def test_flash_padded_vit_length_lowers():
                                       block_q=128, block_k=128)
 
     mlir = _lower_for_tpu(f, q, q, q)
-    _assert_mosaic(mlir)
-
-
-@pytest.mark.parametrize("shape", [(8, 1024, 12, 64), (2, 2048, 32, 128)])
-def test_flash_mh_bwd_lowers(shape):
-    b, s, h, d = shape
-    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
-
-    def loss(q, k, v):
-        return jnp.sum(
-            fa._flash_core_mh(q, k, v, True, 128, 128).astype(jnp.float32))
-
-    mlir = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
     _assert_mosaic(mlir)
 
 
@@ -276,25 +250,6 @@ def test_flash_fused_backward_lowers(tier, shape):
     assert mlir.count("stablehlo.custom_call @tpu_custom_call") == 2, (
         "expected the forward and one fused backward kernel")
     assert f"flash_{tier}_bwd" in mlir
-
-
-def test_flash_kv_native_fwd_bwd_lowers():
-    """The kv-native core (K/V/dK/dV native layout, Pallas relayouts for
-    Q/O) must lower for both directions."""
-    b, s, h, d = 2, 1024, 12, 64
-    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
-
-    def loss(q, k, v):
-        return jnp.sum(
-            fa._flash_core_kv(q, k, v, True, 128, 128)
-            .astype(jnp.float32))
-
-    mlir = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    _assert_mosaic(mlir)
-    n_calls = mlir.count("tpu_custom_call")
-    assert n_calls >= 6, (
-        f"kv core backward should contain relayout + fwd + dq + dkv "
-        f"kernels (got {n_calls} custom calls)")
 
 
 @pytest.mark.parametrize("shape", [(4, 2048, 32, 8, 128)])

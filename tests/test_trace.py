@@ -227,7 +227,7 @@ def test_record_event_emits_span():
 def test_flight_events_become_instants():
     trace.enable()
     flight.get_recorder().enabled = True
-    flight.record("flash.gate_reject", gate="kv", reason="vmem")
+    flight.record("flash.gate_reject", gate="flat", reason="vmem")
     evts = [e for e in trace.events() if e["ph"] == "i"]
     assert evts and evts[0]["name"] == "flash.gate_reject"
     assert evts[0]["args"]["reason"] == "vmem"

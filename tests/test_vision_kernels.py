@@ -33,9 +33,22 @@ def _swin_mask(H, W, ws, shift):
 # ===================== window attention =====================
 
 
+def _assert_forward_matches(out, ref, msg=""):
+    """The kernel's float32 forward against the jnp reference: the same
+    operations in the same order, but XLA:CPU contracts and vectorises
+    the two programs as it likes, so they agree to the last few ulp and
+    not to the bit (bit-equal under one jaxlib, off by up to 2 ulp of
+    the output's largest value under another).  Held to 8 ulp of that
+    value; a wrong window, shift, bias or mask is off by O(1)."""
+    ref = np.asarray(ref)
+    np.testing.assert_allclose(
+        np.asarray(out), ref, rtol=0, err_msg=msg,
+        atol=8 * np.finfo(np.float32).eps * np.abs(ref).max())
+
+
 def test_window_attention_kernel_matches_ref_unshifted():
-    """Unshifted windows, every band size: the kernel's forward is
-    bit-exact against the jnp reference (identical op order)."""
+    """Unshifted windows, every band size: the kernel's forward matches
+    the jnp reference to float32 rounding (_assert_forward_matches)."""
     rs = np.random.RandomState(0)
     B, H, W, C, heads, ws = 2, 8, 8, 12, 3, 4
     P_ = ws * ws
@@ -45,13 +58,13 @@ def test_window_attention_kernel_matches_ref_unshifted():
                                   shift=0, num_heads=heads)
     for band in (1, 2):
         out = WA._fwd_pallas(qkv, bias, None, ws, 0, heads, band)
-        assert np.array_equal(np.asarray(out), np.asarray(ref)), \
-            f"band={band} forward differs from the reference"
+        _assert_forward_matches(
+            out, ref, f"band={band} forward differs from the reference")
 
 
 def test_window_attention_kernel_matches_ref_shifted_masked():
-    """Shifted windows WITH the swin attention mask: forward bit-exact,
-    gradients (dqkv from the analytic backward kernel, dbias summed
+    """Shifted windows WITH the swin attention mask: forward to float32
+    rounding, gradients (dqkv from the analytic backward kernel, dbias summed
     over batch/windows) match jax-AD of the reference."""
     rs = np.random.RandomState(1)
     B, H, W, C, heads, ws, shift = 2, 8, 8, 8, 2, 4, 2
@@ -62,7 +75,7 @@ def test_window_attention_kernel_matches_ref_shifted_masked():
     ref = WA.window_attention_ref(qkv, bias, mask, window_size=ws,
                                   shift=shift, num_heads=heads)
     out = WA._fwd_pallas(qkv, bias, mask, ws, shift, heads, H // ws)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
+    _assert_forward_matches(out, ref)
 
     core = WA._build_core(ws, shift, heads, H // ws, True)
     gk = jax.grad(lambda q, b: core(q, b, mask).sum(),
@@ -91,7 +104,7 @@ def test_window_attention_single_window_edge():
     ref = WA.window_attention_ref(qkv, bias, None, window_size=ws,
                                   shift=0, num_heads=heads)
     out = WA._fwd_pallas(qkv, bias, None, ws, 0, heads, 1)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
+    _assert_forward_matches(out, ref)
 
 
 def test_window_attention_dispatch_counters(monkeypatch):
