@@ -218,7 +218,7 @@ class AfmoeForCausalLM(nn.Layer):
         self.cfg = cfg
         self.model = AfmoeModel(cfg)
         # untied head, held [vocab, hidden] as the embedding is: the layout
-        # the fused head + cross-entropy scan slices by vocabulary chunk
+        # the fused head + cross-entropy scan (`gpt._fused_linear_ce`) takes
         self.lm_head = self.create_parameter(
             [cfg.vocab_size, cfg.hidden_size],
             default_initializer=Normal(0.0, cfg.initializer_range))

@@ -276,6 +276,114 @@ def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
     assert "tpu_custom_call" in text
 
 
+def _head_ce_loss(h, w, labels):
+    from paddle_tpu.models.gpt import _fused_linear_ce
+
+    with jax.named_scope("head_ce"):
+        total, count = _fused_linear_ce(h, w, labels, -100)
+    return total / jnp.maximum(count, 1.0)
+
+
+def _computations(text):
+    """{name: body text} of a compiled module's computations."""
+    import re
+
+    parts = re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \(.*\) -> .* \{)", text)
+    return {("ENTRY" if p.startswith("ENTRY") else p.split(" ", 1)[0]): p
+            for p in parts}
+
+
+@pytest.mark.parametrize("b,s,hd,v,temp_gb", [
+    (32, 1024, 768, 50304, 1.0),     # gpt3-125m.train.seq1024
+    (16, 2048, 768, 50304, 1.0),     # gpt3-125m.train.seq2048
+    (2, 8192, 2048, 25024, 0.36),    # trinity-mini-ep8.train.seq8192
+])
+def test_head_ce_compiles_at_the_cells_shapes(one_chip, b, s, hd, v,
+                                              temp_gb):
+    """The head + cross-entropy scan, differentiated, at the three cells'
+    shapes: 16 slices of the sequence, one while loop (the gradient is
+    formed in the forward scan; no second loop replays it), and
+    temporaries that leave `peak_hbm_share.train` where it is."""
+    import re
+
+    from paddle_tpu.models.gpt import _token_slices
+
+    assert _token_slices(b, s, v) == (s // 16, 16)
+    h = jax.ShapeDtypeStruct((b, s, hd), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((v, hd), jnp.bfloat16, sharding=one_chip)
+    labels = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(_head_ce_loss, (0, 1))).lower(
+        h, w, labels).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= temp_gb * 1e9
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert f"f32[{b * s},{v}]" not in text and f"f32[{b},{s},{v}]" not in text
+
+
+def test_head_ce_reduces_dw_once_under_dp(topo, one_chip):
+    """B on a dp axis of 4 (what the ZeRO-1 step of four chips shards):
+    the scan cuts the sequence, so no slice crosses a shard — the loop
+    body holds no collective, and the head's dW (a partial sum a chip)
+    is reduced ONCE, after the loop.  The chip's own compiler decides
+    where the reduction goes; this pins what it decided."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    b, s, hd, v = 32, 1024, 768, 50304
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1, 1),
+                ("dp", "sep", "mp"))
+
+    def shaped(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    text = jax.jit(jax.value_and_grad(_head_ce_loss, (0, 1))).lower(
+        shaped((b, s, hd), jnp.bfloat16, P("dp")),
+        shaped((v, hd), jnp.bfloat16, P()),
+        shaped((b, s), jnp.int32, P("dp"))).compile().as_text()
+    comps = _computations(text)
+    collective = re.compile(
+        r" (all-reduce|all-gather|reduce-scatter|all-to-all|"
+        r"collective-permute)(-start)?\(")
+    bodies = set(re.findall(r"body=(%?[\w.\-]+)", text))
+    assert len(bodies) == 1
+    loops = [c for name, c in comps.items() if name in bodies]
+    assert loops and not any(collective.search(c) for c in loops)
+    # a slice's logits cover a chip's rows only
+    assert f"f32[{b // 4 * s // 16},{v}]" in loops[0]
+    dw_reduces = re.findall(rf"f32\[{v},{hd}\]\S* all-reduce\(", text)
+    assert len(dw_reduces) == 1 and dw_reduces[0] in comps["ENTRY"]
+
+
+def test_head_ce_values_on_a_cpu_mesh():
+    """The same sharding on the CPU's virtual devices, for the VALUES:
+    loss, dh and dW with B over dp = 4 equal the one-device call's."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devices = jax.devices("cpu")
+    if len(devices) < 4:
+        pytest.skip("needs four CPU devices")
+    mesh = Mesh(np.array(devices[:4]).reshape(4, 1, 1), ("dp", "sep", "mp"))
+    rs = np.random.RandomState(9)
+    h = jnp.asarray(rs.randn(8, 32, 16), jnp.float32)
+    w = jnp.asarray(0.3 * rs.randn(317, 16), jnp.float32)
+    labels = rs.randint(0, 317, (8, 32)).astype(np.int32)
+    labels[rs.rand(8, 32) < 0.2] = -100
+    labels = jnp.asarray(labels)
+    f = jax.jit(jax.value_and_grad(_head_ce_loss, (0, 1)))
+    want = f(h, w, labels)
+    got = f(jax.device_put(h, NamedSharding(mesh, P("dp"))),
+            jax.device_put(w, NamedSharding(mesh, P())),
+            jax.device_put(labels, NamedSharding(mesh, P("dp"))))
+    for a, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5,
+                                   atol=1e-6)
+
+
 def test_flash_candidates_fit_the_gate(monkeypatch):
     """The flat candidate list holds no pair the dispatch gate's own
     arithmetic rejects (the compiler refuses the split pair's

@@ -553,6 +553,190 @@ def test_fused_head_ce_matches_unfused():
     np.testing.assert_allclose(losses[False], losses[True], rtol=2e-5)
 
 
+def _head_ce_case(b, s, v, hd, ignore, seed=5):
+    """Hidden states, a [V, Hd] head and labels; `ignore` picks the rows
+    that carry `ignore_index`."""
+    rs = np.random.RandomState(seed)
+    h = rs.randn(b, s, hd).astype(np.float32)
+    w = (0.3 * rs.randn(v, hd)).astype(np.float32)
+    labels = rs.randint(0, v, (b, s)).astype(np.int32)
+    if ignore == "scattered":
+        labels[rs.rand(b, s) < 0.3] = -100
+    elif ignore == "first_slice":      # every row of the scan's first slice
+        from paddle_tpu.models.gpt import _token_slices
+        labels[:, :_token_slices(b, s, v)[0]] = -100
+    elif ignore == "all":
+        labels[:] = -100
+    return h, w, labels
+
+
+def _unfused_head_ce(h, w, labels):
+    """The plain path: the [B, S, V] logits, then `F.cross_entropy`."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.nn.functional as F
+
+    def loss(hh, ww):
+        logits = jnp.einsum("bsh,vh->bsv", hh, ww,
+                            precision=jax.lax.Precision.HIGHEST)
+        return F.cross_entropy(P.Tensor(logits), P.Tensor(jnp.asarray(labels)),
+                               reduction="mean", ignore_index=-100)._value
+
+    return jax.value_and_grad(loss, (0, 1))(jnp.asarray(h), jnp.asarray(w))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape,ignore", [
+    ((2, 32, 317, 16), None),           # V no multiple of 128, 16 slices
+    ((2, 32, 317, 16), "scattered"),
+    ((2, 32, 317, 16), "first_slice"),  # a slice with no valid row
+    ((2, 65, 317, 16), "scattered"),    # 65 = 5 x 13: the last slice padded
+    ((3, 31, 200, 8), None),            # a prime length: slices of one
+    ((1, 48, 1031, 24), "scattered"),   # batch of one, V prime
+    ((2, 32, 317, 16), "all"),          # nothing valid: loss 0, no NaN
+])
+def test_fused_linear_ce_token_scan_matches_unfused_head(shape, ignore,
+                                                         dtype):
+    """Loss, dh and dW of the sequence scan against the unfused head +
+    `F.cross_entropy`: to 1e-5 in float32; in bfloat16 (what amp hands
+    it) to the rounding of `d` and of the two results, 2**-8 each."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.gpt import _fused_linear_ce
+
+    h, w, labels = _head_ce_case(*shape, ignore)
+    h, w = jnp.asarray(h, dtype), jnp.asarray(w, dtype)
+
+    def fused(hh, ww):
+        total, count = _fused_linear_ce(hh, ww, jnp.asarray(labels), -100)
+        return total / jnp.maximum(count, 1.0)
+
+    got, (dh, dw) = jax.value_and_grad(fused, (0, 1))(h, w)
+    assert dh.dtype == h.dtype and dw.dtype == w.dtype
+    want, (rh, rw) = _unfused_head_ce(h.astype(jnp.float32),
+                                      w.astype(jnp.float32), labels)
+    if ignore == "all":
+        assert float(got) == 0.0 and not np.asarray(dh, np.float32).any() \
+            and not np.asarray(dw, np.float32).any()
+        return
+    tol = 1e-5 if dtype == "float32" else 3 * 2.0 ** -8
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, r in ((dh, rh), (dw, rw)):
+        r = np.asarray(r)
+        np.testing.assert_allclose(np.asarray(a, np.float32), r, rtol=tol,
+                                   atol=tol * np.abs(r).max())
+    # the evaluation loss (no gradient asked) is the same number
+    assert abs(float(fused(h, w)) - float(got)) <= 1e-6 * abs(float(got))
+
+
+@pytest.mark.parametrize("bsv,slices", [
+    ((32, 1024, 50304), (64, 16)),     # the three cells: 1/16 of the tokens
+    ((16, 2048, 50304), (128, 16)),
+    ((2, 8192, 25024), (512, 16)),
+    ((64, 2048, 50304), (32, 64)),     # 1/16 would pass the byte cap
+    ((1, 1000, 50304), (50, 20)),      # the largest divisor under the aim
+    ((2, 65, 317), (4, 17)),           # none near it: 17 x 4 = 68, padded
+    ((3, 31, 200), (1, 31)),
+    ((4, 8, 317), (1, 8)),             # fewer than 16 positions
+])
+def test_token_slices_come_from_the_shape(bsv, slices):
+    from paddle_tpu.models.gpt import _token_slices
+
+    assert _token_slices(*bsv) == slices
+
+
+@pytest.mark.parametrize("differentiated,products", [(True, 3), (False, 1)])
+def test_fused_linear_ce_runs_three_products_and_counts_them(differentiated,
+                                                             products):
+    """Differentiated, the scan's body holds the logits, dh and dW
+    products and the backward rule none (nothing is replayed); the plain
+    call holds the logits product alone.  The trace-time counters say
+    how the scan was cut and where the gradient was formed."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.gpt import _fused_linear_ce
+    from paddle_tpu.observability import metrics
+
+    h, w, labels = _head_ce_case(2, 32, 317, 16, "scattered")
+
+    def fused(hh, ww):
+        with jax.named_scope("head_ce"):
+            total, count = _fused_linear_ce(hh, ww, jnp.asarray(labels), -100)
+        return total / jnp.maximum(count, 1.0)
+
+    was = metrics.enabled()
+    metrics.enable()
+    before = dict(metrics.snapshot()["counters"])
+    try:
+        f = jax.value_and_grad(fused, (0, 1)) if differentiated else fused
+        jaxpr = jax.make_jaxpr(f)(jnp.asarray(h), jnp.asarray(w))
+        now = metrics.snapshot()["counters"]
+    finally:
+        if not was:
+            metrics.disable()
+    def eqns(jp, scope=""):    # every equation with its whole name stack
+        for e in jp.eqns:
+            inner = f"{scope}/{e.source_info.name_stack}"
+            yield e, inner
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub, inner)
+
+    found = list(eqns(jaxpr.jaxpr))
+    scans = [e for e, _ in found if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    assert str(scans[0].params["jaxpr"]).count("dot_general") == products
+    dots = [scope for e, scope in found if e.primitive.name == "dot_general"]
+    assert len(dots) == products and all("head_ce" in d for d in dots)
+    delta = {k: c - before.get(k, 0) for k, c in now.items()
+             if k.startswith("head_ce.") and c - before.get(k, 0)}
+    want = {"head_ce.scan{axis=tokens,chunks=16}": 1}
+    if differentiated:
+        want["head_ce.grad{where=forward}"] = 1
+    assert delta == want
+
+
+@pytest.mark.parametrize("family", ["gpt_tied", "afmoe_untied"])
+def test_fused_head_ce_gradients_match_unfused_model(family):
+    """The tied GPT head (the embedding gets its head-side gradient from
+    the scan's dW carry) and afmoe's untied [V, Hd] head: every
+    parameter's gradient as the unfused model's, eager tape."""
+    from paddle_tpu.models import afmoe
+    from paddle_tpu.models.gpt import (
+        GPTConfig, GPTForCausalLM, GPTPretrainingCriterion,
+    )
+
+    rs = np.random.RandomState(2)
+    labels = rs.randint(0, 317, (2, 32))
+    labels[:, :3] = -100
+    ids = P.to_tensor(rs.randint(0, 317, (2, 32)), "int32")
+    grads = {}
+    for fused in (False, True):
+        P.seed(11)
+        if family == "gpt_tied":
+            model = GPTForCausalLM(GPTConfig(
+                vocab_size=317, hidden_size=32, num_layers=1, num_heads=2,
+                max_seq_len=32, dropout=0.0, fused_head_ce=fused))
+        else:
+            model = afmoe.AfmoeForCausalLM(afmoe.afmoe_tiny(
+                vocab_size=317, fused_head_ce=fused))
+        model.train()
+        crit = GPTPretrainingCriterion(model=model if fused else None,
+                                       fused=fused)
+        loss = crit(model(ids), P.to_tensor(labels, "int32"))
+        loss.backward()
+        grads[fused] = (float(loss), {
+            n: np.asarray(p.grad._value) for n, p in model.named_parameters()
+            if p.grad is not None})
+    assert abs(grads[True][0] - grads[False][0]) < 1e-5 * grads[False][0]
+    assert grads[True][1].keys() == grads[False][1].keys()
+    for name, g in grads[False][1].items():
+        np.testing.assert_allclose(grads[True][1][name], g, rtol=1e-4,
+                                   atol=1e-5 * np.abs(g).max(), err_msg=name)
+
+
 def test_fused_head_ce_mismatched_criterion_raises():
     """A fused_head_ce model paired with a PLAIN criterion must fail
     loudly — hidden states silently scored as logits was the failure
