@@ -111,7 +111,7 @@ def run(ctx):
         step._state["params"], weights.key_of(seed)).items()}
     float(step(*feed(FIRST_STEPS)))       # step 4 keeps that state; warm
     c1 = dict(metrics.snapshot()["counters"])
-    log("dispatch", common.counters_delta(c0, c1, ("flash.", "autotune.")))
+    log("dispatch", common.counters_delta(c0, c1, ("flash.", "autotune.", "head_ce.")))
     compiled_before = compiles.n
 
     # --- the window -------------------------------------------------------
@@ -120,6 +120,7 @@ def run(ctx):
     setup_s = time.time() - common.T_PROCESS_START
     t_start = time.perf_counter()
     n, last, traced = 0, None, False
+    fetched = []      # seconds into the window at which each loss fetch returned
     pause_s, pause_steps = 0.0, 0
     while time.perf_counter() - t_start < seconds:
         if tr and not traced and time.perf_counter() - t_start > 0.4 * seconds:
@@ -143,11 +144,13 @@ def run(ctx):
         n += 1
         if n % fetch_every == 0:
             float(last)
+            fetched.append(round(time.perf_counter() - t_start, 3))
     final_loss = float(last)              # the value fetch closes the window
     window = time.perf_counter() - t_start
     in_window = compiles.n - compiled_before
     log("window", {"steps": n, "seconds": window, "final_loss": final_loss,
-                   "compilations_in_window": in_window})
+                   "compilations_in_window": in_window,
+                   "fetched_at_s": fetched})
     held, reserved = common.memory_peak_parts(ctx["devices"])
     mem = held + reserved
     log("memory", {"peak_bytes_in_use": held, "peak_bytes_reserved": reserved})

@@ -342,7 +342,7 @@ def run(ctx):
     float(one(feed(FIRST_STEPS)))         # step 4 keeps that state; warm
     c1 = dict(metrics.snapshot()["counters"])
     moe0 = sums.read()
-    dispatch = common.counters_delta(c0, c1, ("flash.", "autotune.", "moe."))
+    dispatch = common.counters_delta(c0, c1, ("flash.", "autotune.", "moe.", "head_ce."))
     dispatch.update({f"moe.rows{{kind={k}}}": sum(l[k] for l in moe0.values())
                      for k in ("routed", "computed", "dropped")})
     log("dispatch", dispatch)
@@ -354,6 +354,7 @@ def run(ctx):
     setup_s = time.time() - common.T_PROCESS_START
     t_start = time.perf_counter()
     n, last, traced = 0, None, False
+    fetched = []      # seconds into the window at which each loss fetch returned
     pause_s, pause_steps = 0.0, 0
     moe_traced = None
     while time.perf_counter() - t_start < seconds:
@@ -382,12 +383,14 @@ def run(ctx):
         n += 1
         if n % fetch_every == 0:
             float(last)
+            fetched.append(round(time.perf_counter() - t_start, 3))
     final_loss = float(last)              # the value fetch closes the window
     window = time.perf_counter() - t_start
     in_window = compiles.n - compiled_before
     moe_all = _moe_delta(moe0, sums.read(), n, per_step)
     log("window", {"steps": n, "seconds": window, "final_loss": final_loss,
-                   "compilations_in_window": in_window, "moe_rows": moe_all})
+                   "compilations_in_window": in_window,
+                   "fetched_at_s": fetched, "moe_rows": moe_all})
     held, reserved = common.memory_peak_parts(ctx["devices"])
     mem = held + reserved
     log("memory", {"peak_bytes_in_use": held, "peak_bytes_reserved": reserved})
