@@ -35,10 +35,19 @@ Env knobs (read when the matching ctor arg is None):
   PADDLE_TPU_ENGINE_PREFIX_CACHE   prefix caching on/off      (1)
   PADDLE_TPU_ENGINE_PREFIX_CACHE_MAX_TOKENS  cache bound      (0=pool)
 
-Observability: `engine.schedule/prefill/decode/detokenize` spans on
-the request-trace timeline, `engine.*` gauges (active/waiting
-sequences, page utilization, batch occupancy) and counters
-(`engine.sequences{event}`, `engine.tokens`) in the attach() schema.
+Observability: `engine.schedule/prefill/decode/detokenize` spans
+on the request-trace timeline, `engine.*` gauges (active/waiting
+sequences, page utilization, batch occupancy), counters
+(`engine.sequences{event}`, `engine.tokens`, `engine.steps{kind=decode}`,
+`engine.decode_slots`, `engine.decode_live_tokens`,
+`engine.prefill_tokens{cache}`) and histograms (`engine.submit_wait_ms`,
+`engine.admit_wait_ms`, `engine.lock_wait_ms{who=loop}`) in the attach()
+schema — docs/OBSERVABILITY.md says what each one counts.
+
+Locks: `_lock` serializes whole steps against each other and against
+maintenance (defrag, cache clear, close) and is held through the
+device wait; `_table_lock` guards only the handle and timeline tables,
+so `submit()` and `cancel()` never wait for a step.
 """
 from __future__ import annotations
 
@@ -81,6 +90,25 @@ def _precision_knob(explicit, env, valid):
             f"{sorted(k for k in valid if k)} (or unset for the exact "
             f"tier)")
     return valid[key]
+
+
+def _choose(logits):
+    """Greedy choice at the last position of `logits` [B, S, V]:
+    ``(token int32 [B], logprob float32 [B])`` — the argmax over the
+    float32 row and its log-probability, ``logit[token] -
+    logsumexp(row)``.  EVERY program that picks a token picks it here,
+    so what is delivered and the number that says how sure the model
+    was come from one float32 row."""
+    row = logits[:, -1, :].astype(jnp.float32)
+    tok = jnp.argmax(row, axis=-1).astype(jnp.int32)
+    picked = jnp.take_along_axis(row, tok[:, None], axis=-1)[:, 0]
+    return tok, picked - jax.nn.logsumexp(row, axis=-1)
+
+
+def _observe_since(name, t0, **labels):
+    """Histogram `name` gets the milliseconds since `t0`
+    (`time.perf_counter()`)."""
+    _metrics.observe(name, (time.perf_counter() - t0) * 1e3, **labels)
 
 
 class EngineConfig:
@@ -170,8 +198,9 @@ class EngineConfig:
 
 class RequestHandle:
     """One submitted request's delivery side: a token stream plus a
-    completion event.  Tokens arrive as the engine accepts them;
-    `result()` blocks for the full prompt+generated ids."""
+    completion event.  Tokens arrive as the engine accepts them, each
+    with its log-probability; `result()` blocks for the full
+    prompt+generated ids."""
 
     def __init__(self, seq: Sequence):
         self._seq = seq
@@ -181,8 +210,8 @@ class RequestHandle:
         self.done = threading.Event()
         self.finish_reason = None
 
-    def _push(self, tok: int) -> None:
-        self._q.put(int(tok))
+    def _push(self, tok: int, logprob: float) -> None:
+        self._q.put((int(tok), float(logprob)))
 
     def _finish(self, reason: str) -> None:
         if self.done.is_set():
@@ -192,13 +221,14 @@ class RequestHandle:
         self._q.put(None)          # stream sentinel
 
     # --- consumer side ------------------------------------------------------
-    def stream(self, timeout: float = 120.0):
-        """Yield generated tokens as they land; returns at completion."""
+    def stream(self, timeout: float = 120.0, with_logprobs=False):
+        """Yield generated tokens as they land; returns at completion.
+        With `with_logprobs`, yields ``(token, logprob)`` pairs."""
         while True:
-            tok = self._q.get(timeout=timeout)
-            if tok is None:
+            item = self._q.get(timeout=timeout)
+            if item is None:
                 return
-            yield tok
+            yield item if with_logprobs else item[0]
 
     def result(self, timeout: float = 120.0) -> np.ndarray:
         """Blocking: full int32 [s0 + n_generated] ids (prompt
@@ -211,6 +241,13 @@ class RequestHandle:
     @property
     def tokens(self) -> list:
         return list(self._seq.tokens)
+
+    @property
+    def logprobs(self) -> list:
+        """Log-probability of each delivered token (`tokens`' twin):
+        `logit[token] - logsumexp(logits)` over the float32 logits of
+        the program that chose it."""
+        return list(self._seq.logprobs)
 
     @property
     def cancelled(self) -> bool:
@@ -389,10 +426,17 @@ class InferenceEngine:
             self._init_draft_pools()
         self._programs = {}
         self._handles = {}         # request_id -> RequestHandle
+        # the step lock: one step (or one maintenance call) at a time,
+        # held through the device wait
         self._lock = threading.RLock()
+        # the table lock: `_handles` and `_timelines` only, never held
+        # across anything slower than a dictionary operation — what
+        # lets submit() and cancel() run beside a step
+        self._table_lock = threading.Lock()
         self._work = threading.Condition()
         self._thread = None
         self._running = False
+        self._closed = False
         self.steps = 0
         self._publish_tier_gauges()
 
@@ -460,7 +504,7 @@ class InferenceEngine:
                 out[name] = leaf["q"].astype(dt)
         return out
 
-    def effective_params(self):
+    def effective_params(self):  # pt-lint: ok[PT102] (_params is bound at construction and dropped only by close())
         """The de-quantized params the engine's programs actually
         compute with (identity when no weight tier is active) — bind
         these into the model to reproduce engine streams with plain
@@ -562,8 +606,9 @@ class InferenceEngine:
 
     def _prefill_program(self, sb: int, which="target"):
         """One left-padded sequence at bucket length sb: greedy first
-        token + the dense K/V (capacity sb+page_size so the pack
-        program's last page slice never clamps)."""
+        token, its log-probability + the dense K/V (capacity
+        sb+page_size so the pack program's last page slice never
+        clamps)."""
         key = ("prefill", sb, which)
         hit = self._programs.get(key)
         if hit is not None:
@@ -578,9 +623,8 @@ class InferenceEngine:
                       for _ in range(layers)]
             logits, new = run(params, buffers, ids, caches,
                               jnp.zeros((), jnp.int32), start)
-            tok = jnp.argmax(logits[:, -1, :].astype(jnp.float32),
-                             axis=-1).astype(jnp.int32)
-            return tok, [c[0] for c in new], [c[1] for c in new]
+            tok, lp = _choose(logits)
+            return tok, lp, [c[0] for c in new], [c[1] for c in new]
 
         label = f"prefill_s{sb}" + ("" if which == "target" else f"_{which}")
         prefill = _xla_cost.instrument(prefill, label)
@@ -693,9 +737,8 @@ class InferenceEngine:
         pcap = npp * ps
 
         def finish(logits, new):
-            tok = jnp.argmax(logits[:, -1, :].astype(jnp.float32),
-                             axis=-1).astype(jnp.int32)
-            return tok, [c[0] for c in new], [c[1] for c in new]
+            tok, lp = _choose(logits)
+            return tok, lp, [c[0] for c in new], [c[1] for c in new]
 
         if quant:
             @jax.jit
@@ -773,14 +816,15 @@ class InferenceEngine:
                 if quant:
                     kss = [c[3] for c in new]
                     vss = [c[4] for c in new]
-                nxt = jnp.argmax(logits[:, -1, :].astype(jnp.float32),
-                                 axis=-1).astype(jnp.int32)
-                return (nxt, kps, vps, kss, vss, lengths + 1), nxt
+                nxt, lp = _choose(logits)
+                return (nxt, kps, vps, kss, vss, lengths + 1), (nxt, lp)
 
-            (tok, kps, vps, kss, vss, lengths), toks = jax.lax.scan(
-                body, (tok, k_pools, v_pools, k_scales, v_scales,
-                       lengths), None, length=n)
-            return jnp.swapaxes(toks, 0, 1), kps, vps, kss, vss
+            (tok, kps, vps, kss, vss, lengths), (toks, lps) = \
+                jax.lax.scan(
+                    body, (tok, k_pools, v_pools, k_scales, v_scales,
+                           lengths), None, length=n)
+            return (jnp.swapaxes(toks, 0, 1), jnp.swapaxes(lps, 0, 1),
+                    kps, vps, kss, vss)
 
         decode = _xla_cost.instrument(decode, f"decode_n{n}")
         self._programs[key] = decode
@@ -798,7 +842,8 @@ class InferenceEngine:
         sequential decode step at that position computes (same shapes,
         same masks), which is what makes the accepted stream
         bit-identical to sequential greedy.  Accept/reject runs on
-        device; the host reads (g, counts) and commits g[:, :counts].
+        device; the host reads (g, lp, counts) and commits
+        g[:, :counts] with the target's log-probabilities lp[:, :counts].
         """
         quant = self.config.kv_precision == "int8"
         key = ("spec", k, quant)
@@ -841,8 +886,8 @@ class InferenceEngine:
                                     caches, pos_eff, None)
                 dkp = [c[0] for c in new]
                 dvp = [c[1] for c in new]
-                nxt = jnp.argmax(logits[:, -1, :].astype(jnp.float32),
-                                 axis=-1).astype(jnp.int32)
+                nxt, _ = _choose(logits)   # the draft's own
+                # log-probability is never delivered (XLA drops it)
                 return (nxt, dkp, dvp, pos + 1), nxt
 
             (_, dkp, dvp, _), d_all = jax.lax.scan(
@@ -869,13 +914,16 @@ class InferenceEngine:
             vps = [c[1] for c in new]
             kss = [c[3] for c in new] if quant else k_scales
             vss = [c[4] for c in new] if quant else v_scales
-            g = jnp.argmax(logits[:, -1, :].astype(jnp.float32),
-                           axis=-1).astype(jnp.int32).reshape(s_, k + 1)
+            # the TARGET's choice and its log-probability at every
+            # position: what a committed token carries, accepted draft
+            # proposal or not
+            g, lp = _choose(logits)
+            g, lp = g.reshape(s_, k + 1), lp.reshape(s_, k + 1)
             # --- greedy accept: longest prefix with d_{i+1} == g_i ---
             match = (props == g[:, :k]).astype(jnp.int32)
             acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
             counts = acc + 1       # committed tokens = g[:, :acc+1]
-            return g, counts, kps, vps, kss, vss, dkp, dvp
+            return g, lp, counts, kps, vps, kss, vss, dkp, dvp
 
         spec = _xla_cost.instrument(spec, f"spec_k{k}")
         self._programs[key] = spec
@@ -900,6 +948,7 @@ class InferenceEngine:
         `prebilled_tokens` marks the first N accepted tokens as
         already billed by a prior replica (ISSUE 20 mid-stream resume
         — the decode books must conserve across the failover)."""
+        t_in = time.perf_counter()
         seq = Sequence(input_ids, max_new_tokens,
                        eos_token_id=eos_token_id, request_id=request_id,
                        tenant_id=tenant_id, priority_class=priority_class,
@@ -923,7 +972,9 @@ class InferenceEngine:
         # loop thread running, a short request can be admitted,
         # finished, and its handle popped before submit() returns — a
         # post-hoc insert would leave a stale entry in _handles forever
-        with self._lock:
+        with self._table_lock:
+            if self._closed:
+                raise RuntimeError("engine is closed")
             self._handles[seq.request_id] = handle
             if seq.timeline is not None:
                 # the timeline map is a bounded LRU that OUTLIVES the
@@ -944,14 +995,21 @@ class InferenceEngine:
                         victim = next(iter(self._timelines))
                     self._timelines.pop(victim)
         try:
-            self.scheduler.submit(seq)  # validates vs max_pages_per_seq
+            # validates vs max_pages_per_seq; stamps the timeline's
+            # `queued` event under the scheduler's own lock
+            self.scheduler.submit(seq)
         except Exception:
-            with self._lock:
+            with self._table_lock:
                 self._handles.pop(seq.request_id, None)
                 # a refused request must not occupy a timeline slot (or
                 # answer /debug/requests with a ghost 'submitted' row)
                 self._timelines.pop(seq.request_id, None)
             raise
+        # entry of submit() -> the sequence stands in the scheduler's
+        # queue: everything an arrival waits for before it CAN be
+        # admitted
+        _metrics.observe("engine.submit_wait_ms",
+                         (seq.queued_at - t_in) * 1e3)
         _metrics.inc("engine.sequences", event="submitted")
         with self._work:
             self._work.notify_all()
@@ -964,7 +1022,7 @@ class InferenceEngine:
         ok = self.scheduler.cancel(request_id)
         if ok:
             _metrics.inc("engine.sequences", event="cancelled")
-            with self._lock:
+            with self._table_lock:
                 handle = self._handles.pop(request_id, None)
             if handle is not None:
                 handle._finish("cancelled")
@@ -977,25 +1035,41 @@ class InferenceEngine:
         """One engine iteration: schedule -> prefill admissions ->
         ragged decode chunk -> detokenize/deliver.  Returns True when
         any work happened."""
+        t_ask = time.perf_counter()
         with self._lock:
+            _observe_since("engine.lock_wait_ms", t_ask, who="loop")
+            # pt-lint: ok[PT504] (close() sets _closed holding BOTH locks; a reader holds either)
+            if self._closed:
+                return False
             # spec mode writes up to spec_tokens+1 cache positions per
             # pass — the scheduler must provision pages for the whole
             # pass, not just the committed prefix
             chunk = (self.config.spec_tokens + 1 if self._draft
                      else self.config.decode_chunk)
-            with _trace.span("engine.schedule", cat="engine"):
+            with _trace.span("engine.schedule", cat="engine") as sp:
                 out = self.scheduler.schedule(chunk)
+                if sp is not None:
+                    # a request left waiting beside a free slot was
+                    # held back by pages (or class), not by the engine
+                    sp.args.update(
+                        admitted=len(out.prefills), waiting=out.waiting,
+                        free_slots=self.config.max_slots
+                        - len(out.running))
             for seq in out.evicted:
                 _metrics.inc("engine.sequences", event="evicted")
-            for seq in out.finished:
+            if out.finished:
                 # released this schedule (completed earlier, or
                 # cancelled while waiting/running): close the handle
                 # and drop the engine's reference — a long-running
                 # server must not accumulate one handle per cancelled
                 # request
-                self._handles.pop(seq.request_id, None)
-                if seq.handle is not None:
-                    seq.handle._finish(seq.finish_reason or "finished")
+                with self._table_lock:
+                    for seq in out.finished:
+                        self._handles.pop(seq.request_id, None)
+                for seq in out.finished:
+                    if seq.handle is not None:
+                        seq.handle._finish(
+                            seq.finish_reason or "finished")
             did = bool(out.finished or out.evicted)
             for seq in out.prefills:
                 self._prefill(seq)
@@ -1024,22 +1098,31 @@ class InferenceEngine:
         prompt = seq.resume_prompt()
         s0 = prompt.size
         shared = int(seq.shared_len or 0)
+        if not seq.evictions:
+            # queued -> its FIRST prefill begins (a preempted
+            # sequence's later prefills are the scheduler's doing, not
+            # an arrival's wait)
+            _observe_since("engine.admit_wait_ms", seq.queued_at)
         if seq.timeline is not None:
             seq.timeline.event("prefill_start", tokens=s0,
                                shared=shared,
                                resumed=bool(seq.evictions))
+        # tokens = prompt positions this prefill computes; the other
+        # `cached_tokens` came off prefix-cache pages
         with _trace.span("engine.prefill", cat="engine",
-                         request=seq.request_id, tokens=s0,
-                         shared=shared, pages=len(seq.pages)):
+                         request=seq.request_id, tokens=s0 - shared,
+                         cached_tokens=shared, pages=len(seq.pages)):
             if shared > 0:
-                t0, kbufs, vbufs, start = self._warm_prefill(
+                t0, lp0, kbufs, vbufs, start = self._warm_prefill(
                     seq, prompt, shared)
             else:
-                t0, kbufs, vbufs, start = self._cold_prefill(
+                t0, lp0, kbufs, vbufs, start = self._cold_prefill(
                     seq, prompt)
             self._commit_prefix(seq, kbufs, vbufs, start)
             seq.length = s0
             seq.last_token = t0
+        _metrics.inc("engine.prefill_tokens", s0 - shared,
+                     cache=seq.cache_state or "miss")
         if seq.timeline is not None:
             seq.timeline.event("prefill_end", tokens=s0)
         if self._prefix is not None:
@@ -1059,13 +1142,13 @@ class InferenceEngine:
             self.tenant_ledger.record_prefill(
                 seq.tenant_id, s0 - shared, saved=shared)
         _metrics.inc("engine.sequences", event="admitted")
-        self._accept(seq, t0)
+        self._accept(seq, t0, lp0)
 
     def _cold_prefill(self, seq, prompt):  # pt-lint: ok[PT101,PT102] (step holds _lock)
         """Dense prefill from token 0 (no cached prefix): the PR 8
-        path.  Returns (first_token, k_bufs, v_bufs, pad_start) — the
-        dense buffers feed `_commit_prefix` (prompt token t sits at
-        buffer offset pad_start + t)."""
+        path.  Returns (first_token, its log-probability, k_bufs,
+        v_bufs, pad_start) — the dense buffers feed `_commit_prefix`
+        (prompt token t sits at buffer offset pad_start + t)."""
         s0 = prompt.size
         sb = self._bucket(s0)
         start = sb - s0
@@ -1073,7 +1156,7 @@ class InferenceEngine:
         ids = np.zeros((1, sb), np.int32)
         ids[0, start:] = prompt
         prefill = self._prefill_program(sb)
-        tok, kbufs, vbufs = prefill(
+        tok, lp, kbufs, vbufs = prefill(
             self._params, self._buffers, jnp.asarray(ids),
             jnp.asarray([start], jnp.int32))
         ps = self.config.page_size
@@ -1098,15 +1181,20 @@ class InferenceEngine:
             # pools (same page ids) so proposals continue from the
             # full prompt context
             dprefill = self._prefill_program(sb, "draft")
-            _, dkb, dvb = dprefill(
+            _, _, dkb, dvb = dprefill(
                 self._draft["params"], self._draft["buffers"],
                 jnp.asarray(ids), jnp.asarray([start], jnp.int32))
             dpack = self._pack_program(sb, "draft")
             self._draft["k_pools"], self._draft["v_pools"] = dpack(
                 self._draft["k_pools"], self._draft["v_pools"],
                 dkb, dvb, pages_j, start_j)
-        return int(np.asarray(jax.device_get(tok))[0]), kbufs, vbufs, \
-            start
+        return (*self._first_choice(tok, lp), kbufs, vbufs, start)
+
+    @staticmethod
+    def _first_choice(tok, lp):
+        """A prefill's (token, logprob) on the host, one fetch."""
+        tok, lp = jax.device_get((tok, lp))
+        return int(tok[0]), float(lp[0])
 
     @staticmethod
     def _prefix_bucket(n_pages: int) -> int:
@@ -1147,12 +1235,12 @@ class InferenceEngine:
         cpre = self._cached_prefill_program(sb, npp)
         if quant:
             ek, ev = self._sidecar_prefix(seq, npa, npp)
-            tok, kbufs, vbufs = cpre(self._params, self._buffers,
-                                     ids_j, start_j, plen, ek, ev)
+            tok, lp, kbufs, vbufs = cpre(self._params, self._buffers,
+                                         ids_j, start_j, plen, ek, ev)
         else:
-            tok, kbufs, vbufs = cpre(self._params, self._buffers,
-                                     ids_j, start_j, pages_j, plen,
-                                     self._k_pools, self._v_pools)
+            tok, lp, kbufs, vbufs = cpre(self._params, self._buffers,
+                                         ids_j, start_j, pages_j, plen,
+                                         self._k_pools, self._v_pools)
         # pack the tail into the PRIVATE tail pages; in the returned
         # buffers prompt token t sits at offset start + t (the write
         # landed at [shared, shared+sb), tail token j at shared+start+j)
@@ -1178,7 +1266,7 @@ class InferenceEngine:
             # the donor's draft K/V — a pure function of the prefix
             # tokens, so they are this prompt's draft prefix too
             dcpre = self._cached_prefill_program(sb, npp, "draft")
-            _, dkb, dvb = dcpre(
+            _, _, dkb, dvb = dcpre(
                 self._draft["params"], self._draft["buffers"], ids_j,
                 start_j, pages_j, plen, self._draft["k_pools"],
                 self._draft["v_pools"])
@@ -1192,8 +1280,7 @@ class InferenceEngine:
         # shared+j) is at shared + start + j = start + (shared+j).
         # Returning shared+start here would shift every sidecar slice
         # one whole prefix past the real tokens.
-        return int(np.asarray(jax.device_get(tok))[0]), kbufs, vbufs, \
-            start
+        return (*self._first_choice(tok, lp), kbufs, vbufs, start)
 
     def _sidecar_prefix(self, seq, npa, npp):  # pt-lint: ok[PT101,PT102] (step holds _lock)
         """int8-KV tier: stack the matched radix nodes' commit-time
@@ -1249,6 +1336,13 @@ class InferenceEngine:
                             seq.pages[:n_full], exact=exact)
 
     def _batch_arrays(self, running):  # pt-lint: ok[PT101,PT102] (step holds _lock)
+        """The decode programs' host inputs (last token, page table,
+        cached length per slot) and, from the same arrays, what the
+        dispatch runs: `live_tokens`, the cached positions its
+        `len(running)` slots attend (the `engine.decode` span's field).
+        Counts the dispatch — plain or speculative, one
+        `engine.steps{kind=decode}` each — so the `engine.decode_*`
+        sums over that count are the means a step in either mode."""
         s_, p_ = self.config.max_slots, self.max_pages_per_seq
         tok = np.zeros((s_,), np.int32)
         pt = np.zeros((s_, p_), np.int32)
@@ -1257,7 +1351,12 @@ class InferenceEngine:
             tok[seq.slot] = seq.last_token
             pt[seq.slot, :len(seq.pages)] = seq.pages
             lengths[seq.slot] = seq.length
-        return jnp.asarray(tok), jnp.asarray(pt), jnp.asarray(lengths)
+        live_tokens = int(lengths.sum())
+        _metrics.inc("engine.steps", kind="decode")
+        _metrics.inc("engine.decode_slots", len(running))
+        _metrics.inc("engine.decode_live_tokens", live_tokens)
+        return (jnp.asarray(tok), jnp.asarray(pt), jnp.asarray(lengths),
+                live_tokens)
 
     def _scales_args(self):  # pt-lint: ok[PT101,PT102] (step holds _lock)
         if self._k_scales is None:
@@ -1267,32 +1366,35 @@ class InferenceEngine:
     def _decode(self, running) -> None:  # pt-lint: ok[PT101,PT102] (step holds _lock)
         cfg = self.config
         t_step = time.perf_counter()
-        tok, pt, lengths = self._batch_arrays(running)
+        tok, pt, lengths, live = self._batch_arrays(running)
         # ALWAYS dispatch the configured chunk: shrinking the scan to
         # the batch's max remaining would compile one program per
         # distinct tail length — a compile per shape costs far more
         # than the few discarded tail tokens, and a single decode
-        # program is the fixed-compiled-shape contract
+        # program is the fixed-compiled-shape contract (the
+        # log-probabilities ride the same program for the same reason:
+        # always computed, never a second program for a flag)
         n = cfg.decode_chunk
         decode = self._decode_program(n)
         ks, vs = self._scales_args()
         with _trace.span("engine.decode", cat="engine", batch=len(running),
-                         chunk=n, occupancy=len(running) / cfg.max_slots):
-            toks, self._k_pools, self._v_pools, ks, vs = decode(
+                         chunk=n, occupancy=len(running) / cfg.max_slots,
+                         live_tokens=live):
+            toks, lps, self._k_pools, self._v_pools, ks, vs = decode(
                 self._params, self._buffers, self._k_pools,
                 self._v_pools, ks, vs, tok, pt, lengths)
             if self._k_scales is not None:
                 self._k_scales, self._v_scales = ks, vs
         with _trace.span("engine.detokenize", cat="engine",
                          batch=len(running), chunk=n):
-            toks = np.asarray(jax.device_get(toks))
+            toks, lps = jax.device_get((toks, lps))
             for seq in running:
-                row = toks[seq.slot]
+                row, lrow = toks[seq.slot], lps[seq.slot]
                 for j in range(n):
                     if seq.done:
                         break  # mid-chunk finish: later tokens are the
                         # frozen-slot continuation, not output
-                    self._accept(seq, int(row[j]))
+                    self._accept(seq, int(row[j]), float(lrow[j]))
                 seq.length += n
                 seq.last_token = int(row[n - 1])
         self._bill_decode_slots(running, t_step)
@@ -1301,7 +1403,7 @@ class InferenceEngine:
         cfg = self.config
         k = cfg.spec_tokens
         t_step = time.perf_counter()
-        tok, pt, lengths = self._batch_arrays(running)
+        tok, pt, lengths, live = self._batch_arrays(running)
         # per-slot lifetime cap (prompt+max_new cache positions): rows
         # of the pass at or past it are masked to the scratch page
         # inside the program (free slots stay at 0 = fully masked)
@@ -1313,8 +1415,9 @@ class InferenceEngine:
         d = self._draft
         with _trace.span("engine.decode", cat="engine",
                          batch=len(running), chunk=k + 1, spec=True,
-                         occupancy=len(running) / cfg.max_slots):
-            (g, counts, self._k_pools, self._v_pools, ks, vs,
+                         occupancy=len(running) / cfg.max_slots,
+                         live_tokens=live):
+            (g, lps, counts, self._k_pools, self._v_pools, ks, vs,
              d["k_pools"], d["v_pools"]) = spec(
                 self._params, self._buffers, d["params"], d["buffers"],
                 self._k_pools, self._v_pools, ks, vs,
@@ -1324,10 +1427,9 @@ class InferenceEngine:
                 self._k_scales, self._v_scales = ks, vs
         with _trace.span("engine.detokenize", cat="engine",
                          batch=len(running), chunk=k + 1):
-            g = np.asarray(jax.device_get(g))
-            counts = np.asarray(jax.device_get(counts))
+            g, lps, counts = jax.device_get((g, lps, counts))
             for seq in running:
-                row = g[seq.slot]
+                row, lrow = g[seq.slot], lps[seq.slot]
                 cnt = int(counts[seq.slot])
                 # cnt-1 draft proposals were accepted; the rest of the
                 # pass's k proposals were rejected (their cache slots
@@ -1340,7 +1442,7 @@ class InferenceEngine:
                     if seq.done:
                         break  # mid-pass finish (eos): later tokens are
                         # the frozen continuation, not output
-                    self._accept(seq, int(row[j]))
+                    self._accept(seq, int(row[j]), float(lrow[j]))
                 seq.length += cnt
                 seq.last_token = int(row[cnt - 1])
         self._bill_decode_slots(running, t_step)
@@ -1361,11 +1463,13 @@ class InferenceEngine:
                     seq.tenant_id, step_ms)
             self.scheduler.note_decode_slot_ms(seq.tenant_id, step_ms)
 
-    def _accept(self, seq: Sequence, tok: int) -> None:
-        """One generated token passes the host: record, deliver,
-        finish on eos / length (mirrors generate()'s freezing: the eos
-        itself is emitted, nothing after it)."""
+    def _accept(self, seq: Sequence, tok: int, logprob: float) -> None:
+        """One generated token passes the host with its
+        log-probability: record, deliver, finish on eos / length
+        (mirrors generate()'s freezing: the eos itself is emitted,
+        nothing after it)."""
         seq.tokens.append(int(tok))
+        seq.logprobs.append(float(logprob))
         if seq.timeline is not None:
             seq.timeline.token()
         if len(seq.tokens) <= seq.prebilled_tokens:
@@ -1382,7 +1486,7 @@ class InferenceEngine:
         else:
             _metrics.inc("engine.tokens")
         if seq.handle is not None:
-            seq.handle._push(tok)
+            seq.handle._push(tok, logprob)
         if seq.eos_token_id is not None and int(tok) == seq.eos_token_id:
             self._finish(seq, "eos")
         elif len(seq.tokens) >= seq.max_new_tokens:
@@ -1402,7 +1506,7 @@ class InferenceEngine:
         _metrics.inc("engine.sequences", event="completed")
         if seq.handle is not None:
             seq.handle._finish(reason)
-        with self._lock:
+        with self._table_lock:
             self._handles.pop(seq.request_id, None)
 
     def _publish_gauges(self) -> None:  # pt-lint: ok[PT102] (_prefix set once at construction, never rebound)
@@ -1498,7 +1602,7 @@ class InferenceEngine:
         line.  None for unknown / aged-out ids.  Works for completed
         requests until `_TIMELINE_LRU` newer submissions age them
         out."""
-        with self._lock:
+        with self._table_lock:
             tl = self._timelines.get(request_id)
         if tl is None:
             return None
@@ -1538,7 +1642,7 @@ class InferenceEngine:
         """Bounded per-request timeline summaries, newest last — what
         /debug/telemetry and the exporter dumps embed (full detail
         stays behind /debug/requests/<id>)."""
-        with self._lock:
+        with self._table_lock:
             tls = list(self._timelines.values())[-int(n):]
         return [tl.summary() for tl in tls]
 
@@ -1547,6 +1651,8 @@ class InferenceEngine:
         """Run the engine loop on a daemon thread (the serving mode);
         `step()` remains callable inline for tests."""
         with self._lock:
+            if self._closed:
+                raise RuntimeError("engine is closed")
             if self._thread is not None:
                 return self
             self._running = True
@@ -1556,8 +1662,10 @@ class InferenceEngine:
         return self
 
     def _loop(self):
-        # _running is a stop flag: a stale read costs one extra step;
-        # taking the lock here would serialize the loop against submit()
+        # _running is a stop flag: a stale read costs one extra step,
+        # and step() takes the step lock itself.  submit() and cancel()
+        # take only the table lock, so a loop that steps back to back
+        # keeps nobody out
         waiting = None  # the open `engine.wait_request` span: ONE per
         # idle stretch (an empty batch, blocked on the next request),
         # closed before the step that serves the request begins
@@ -1586,6 +1694,34 @@ class InferenceEngine:
             self._work.notify_all()
         if thread is not None:
             thread.join(timeout=timeout)
+
+    def close(self, timeout: float = 10.0) -> None:
+        """Stop the loop and give the device back: cancels whatever is
+        still in flight, then drops the page pools, their scale
+        tables, the weights and every compiled program (the draft's
+        too), so a caller can put something else on the chip — a
+        reference, another engine — without reaching into privates.
+        Host-side state (`pool`, `scheduler`, `stats()`, timelines)
+        stays readable.  Idempotent; `submit()` and `start()` raise
+        afterwards and `step()` does nothing."""
+        self.stop(timeout=timeout)
+        with self._lock:
+            if self._closed:
+                return
+            with self._table_lock:
+                self._closed = True
+                live = list(self._handles.values())
+                self._handles.clear()
+            for handle in live:
+                self.scheduler.cancel(handle.request_id)
+                handle._finish("cancelled")
+            self.scheduler.schedule()      # slots and pages go back
+            self._k_pools = self._v_pools = None
+            self._k_scales = self._v_scales = None
+            self._params = self._buffers = None
+            self._draft = None
+        # no step runs any more, so the memo needs no lock
+        self._programs.clear()
 
     # --- convenience (tests / bench / equivalence) --------------------------
     def generate(self, prompts, max_new_tokens=32, eos_token_id=None,

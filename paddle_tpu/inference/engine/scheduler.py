@@ -104,6 +104,10 @@ class Sequence:
         self.priority_class = (_qos.normalize_class(priority_class)
                                or _qos.DEFAULT_CLASS)
         self.arrived_at = float(arrived_at)
+        # time.perf_counter() at which the scheduler queued it (the
+        # engine's submit/admit wait histograms are measured from here;
+        # `arrived_at` is on the injectable clock and orders admission)
+        self.queued_at = None
         # absolute monotonic instant (scheduler clock) after which this
         # request is worthless to its client (ISSUE 20 / ROADMAP 4):
         # admission sheds an already-expired sequence instead of
@@ -118,6 +122,7 @@ class Sequence:
         self.timeline = None       # optional RequestTimeline (ISSUE 15)
         self.state = WAITING
         self.tokens = []           # accepted generated tokens
+        self.logprobs = []         # each one's log-probability
         self.pages = []            # live page ids (engine's pools)
         self.length = 0            # tokens materialized in the cache
         self.shared_len = 0        # cached-prefix tokens (page-aligned)
@@ -162,11 +167,12 @@ class SchedulerOutput:
     """One schedule() decision: which sequences need a prefill this
     step, who is running, and who was preempted."""
 
-    def __init__(self, prefills, running, evicted, finished):
+    def __init__(self, prefills, running, evicted, finished, waiting=0):
         self.prefills = prefills   # newly admitted (pages allocated)
         self.running = running     # every live slot after admission
         self.evicted = evicted     # preempted back to waiting
         self.finished = finished   # released this schedule()
+        self.waiting = waiting     # still queued after admission
 
 
 class Scheduler:
@@ -227,8 +233,11 @@ class Scheduler:
                 raise ValueError(
                     f"duplicate request id {seq.request_id!r}")
             seq.arrived_at = self.clock()
+            seq.queued_at = time.perf_counter()
             self._by_id[seq.request_id] = seq
             self._waiting.append(seq)
+            if seq.timeline is not None:
+                seq.timeline.event("queued")
 
     def cancel(self, request_id) -> bool:
         """Mark a live sequence cancelled; its slot/pages release at the
@@ -541,7 +550,8 @@ class Scheduler:
                         cache_state=seq.cache_state or "miss")
 
             running = [self._running[s] for s in sorted(self._running)]
-            return SchedulerOutput(prefills, running, evicted, finished)
+            return SchedulerOutput(prefills, running, evicted, finished,
+                                   waiting=len(self._waiting))
 
     def _lookup_prefix_locked(self, seq, prompt):  # pt-lint: ok[PT101,PT102] (schedule holds _lock)
         """Cached-prefix lookup for one admission candidate: pins the

@@ -593,7 +593,8 @@ class Router:
                             fingerprint=fingerprint,
                             max_new_tokens=parsed.get(
                                 "max_new_tokens"),
-                            eos_token_id=parsed.get("eos_token_id"))
+                            eos_token_id=parsed.get("eos_token_id"),
+                            logprobs=bool(parsed.get("logprobs")))
                     except Exception as e:
                         # best effort: before any stream bytes this is
                         # a clean 500; afterwards the socket just
@@ -1104,7 +1105,7 @@ class Router:
 
     def forward_generate(self, body, prompt_ids, ctx, handler,
                          fingerprint=None, max_new_tokens=None,
-                         eos_token_id=None):
+                         eos_token_id=None, logprobs=False):
         """Proxy one /generate stream to the client behind `handler`.
 
         Failover contract (ISSUE 9 (b) + ISSUE 20): attempts rotate
@@ -1333,7 +1334,7 @@ class Router:
                 resumes += 1
                 cur_body, verify_expect = self._resume_body(
                     prompt_ids, delivered, max_new, eos_token_id,
-                    resumes)
+                    resumes, logprobs)
                 pending_ok = verify_expect is None
                 if last_token_at is None:
                     last_token_at = self.clock()
@@ -1386,7 +1387,7 @@ class Router:
 
     @staticmethod
     def _resume_body(prompt_ids, delivered, max_new, eos_token_id,
-                     leg):
+                     leg, logprobs=False):
         """The resume leg's request body + the verify token.
 
         `prompt + delivered[:-1]` is resubmitted as the prompt — by
@@ -1414,6 +1415,10 @@ class Router:
                 "prebilled_tokens": 0 if verify is None else 1}
         if eos_token_id is not None:
             body["eos_token_id"] = int(eos_token_id)
+        if logprobs:
+            # the resume leg's token lines keep carrying the value the
+            # client asked for on the first leg
+            body["logprobs"] = True
         return json.dumps(body).encode(), verify
 
     def _resume_established(self, rid, last_token_at, n_delivered):

@@ -486,12 +486,18 @@ class InferenceServer:
                 """POST /generate: continuous-batching token streaming.
 
                 Body: JSON ``{"input_ids": [ints] (one sequence),
-                "max_new_tokens": int, "eos_token_id": optional int}``.
+                "max_new_tokens": int, "eos_token_id": optional int,
+                "logprobs": optional bool}``.
                 Response: 200 + newline-delimited JSON — one
                 ``{"token": t}`` line per generated token as the engine
                 emits it, then a final ``{"done": true, "output_ids":
                 [...], "finish_reason": ...}`` line (connection closes;
-                no Content-Length — the stream IS the progress).  Sheds
+                no Content-Length — the stream IS the progress).  With
+                ``"logprobs": true`` every token line also carries
+                ``"logprob"`` (the token's log-probability under the
+                float32 logits of the program that chose it) and the
+                final line ``"logprobs"``, one per generated token, in
+                order; without it the stream carries neither.  Sheds
                 and deadline overruns map exactly like /predict
                 (429/503 + Retry-After), and a client that disconnects
                 mid-stream gets its sequence cancelled so its pages
@@ -519,6 +525,7 @@ class InferenceServer:
                         is_resume = bool(req.get("resume"))
                         prebilled = max(0, int(req.get(
                             "prebilled_tokens", 0)))
+                        want_lp = bool(req.get("logprobs"))
                     except Exception as e:
                         status = "client_error"
                         return self._json(
@@ -564,6 +571,14 @@ class InferenceServer:
                         status = "client_error"
                         return self._json(
                             400, {"error": f"{type(e).__name__}: {e}"})
+                    if want_lp and not hasattr(handle, "logprobs"):
+                        # an engine duck-type without logits (ToyEngine)
+                        # must refuse, not stream a made-up number
+                        server.engine.cancel(handle.request_id)
+                        status = "client_error"
+                        return self._json(
+                            400, {"error": "this engine delivers no "
+                                           "log-probabilities"})
                     # headers INSIDE the cancel-on-disconnect guard: a
                     # client that drops before the stream starts must
                     # still free its sequence, not decode max_new
@@ -577,8 +592,13 @@ class InferenceServer:
                         self.end_headers()
                         first_at = None
                         last_at = None
+                        # (duck-typed engines' stream() takes no such
+                        # argument: pass it only when it was asked for)
+                        stream_kw = {"with_logprobs": True} \
+                            if want_lp else {}
                         for tok in handle.stream(
-                                timeout=server._request_timeout or 120.0):
+                                timeout=server._request_timeout or 120.0,
+                                **stream_kw):
                             now = time.perf_counter()
                             if last_at is not None:
                                 # inter-token latency at the STREAM
@@ -644,9 +664,11 @@ class InferenceServer:
                                 # legitimately)
                                 _lifecycle.get_ledger().stamp_once(
                                     "first_token")
+                            evt = ({"token": int(tok[0]),
+                                    "logprob": float(tok[1])}
+                                   if want_lp else {"token": int(tok)})
                             self.wfile.write(
-                                json.dumps({"token": int(tok)}).encode()
-                                + b"\n")
+                                json.dumps(evt).encode() + b"\n")
                             self.wfile.flush()
                         final = {
                             "done": True,
@@ -656,6 +678,8 @@ class InferenceServer:
                                 [int(x) for x in
                                  handle.result(timeout=5.0)],
                         }
+                        if want_lp:
+                            final["logprobs"] = handle.logprobs
                         self.wfile.write(json.dumps(final).encode()
                                          + b"\n")
                         self.wfile.flush()
@@ -1097,11 +1121,12 @@ class StreamInterrupted(RuntimeError):
 
     def __init__(self, message, output_ids=None, tokens=(),
                  finish_reason="interrupted", request_id=None,
-                 tenant_id=None):
+                 tenant_id=None, logprobs=()):
         super().__init__(message)
         self.output_ids = (None if output_ids is None
                            else np.asarray(output_ids, np.int32))
         self.tokens = list(tokens)
+        self.logprobs = list(logprobs)   # when the request asked
         self.finish_reason = finish_reason
         self.request_id = request_id
         # who was being billed when the stream cut (ISSUE 16): the
@@ -1218,7 +1243,7 @@ class InferenceClient:
         return min(max(ra, 0.05), self.max_retry_wait)
 
     def generate(self, input_ids, max_new_tokens=32, eos_token_id=None,
-                 on_token=None, resume=False) -> dict:
+                 on_token=None, resume=False, logprobs=False) -> dict:
         """Stream one sequence through POST /generate.
 
         Tokens are consumed INCREMENTALLY off the ndjson stream —
@@ -1227,7 +1252,11 @@ class InferenceClient:
         ``{"output_ids": np.int32 array, "tokens": [...],
         "finish_reason": ..., "request_id": ..., "resumed": n}``
         (`resumed` counts router-side mid-stream failovers this stream
-        absorbed, ISSUE 20 — 0 on the common path).
+        absorbed, ISSUE 20 — 0 on the common path).  With
+        ``logprobs=True`` the request asks for each token's
+        log-probability and the record gains ``"logprobs"``, aligned
+        with ``"tokens"`` (a resume leg's verify token is swallowed
+        with its value, like the token).
 
         Retry discipline (ISSUE 7): ONE request identity is minted
         BEFORE the retry loop — a 429/503 shed retries under the same
@@ -1263,12 +1292,13 @@ class InferenceClient:
                 if resume else 0)
         legs_used = 0
         prior: list = []           # tokens delivered by earlier legs
+        prior_lp: list = []        # and their log-probabilities
         cur_ids, cur_max = ids, max_new
         while True:
             try:
                 out = self._generate_attempt(cur_ids, cur_max,
                                              eos_token_id, on_token,
-                                             ctx)
+                                             ctx, logprobs)
             except StreamInterrupted as e:
                 delivered = list(e.tokens)
                 if legs_used >= legs or e.output_ids is None:
@@ -1278,13 +1308,14 @@ class InferenceClient:
                     raise
                 legs_used += 1
                 prior.extend(delivered)
+                prior_lp.extend(e.logprobs)
                 cur_ids = [int(x) for x in e.output_ids]
                 cur_max = cur_max - len(delivered)
                 if cur_max < 1:
                     # every budgeted token already arrived; only the
                     # final record was lost — synthesize it (greedy
                     # contract: the delivered prefix IS the answer)
-                    return {
+                    out = {
                         "output_ids": np.asarray(cur_ids, np.int32),
                         "tokens": prior,
                         "finish_reason": "length",
@@ -1292,13 +1323,18 @@ class InferenceClient:
                         "tenant_id": ctx.tenant_id,
                         "resumed": legs_used,
                     }
+                    if logprobs:
+                        out["logprobs"] = prior_lp
+                    return out
                 continue
             out["tokens"] = prior + out["tokens"]
+            if logprobs:
+                out["logprobs"] = prior_lp + out["logprobs"]
             out["resumed"] = int(out.get("resumed", 0) or 0) + legs_used
             return out
 
     def _generate_attempt(self, ids, max_new_tokens, eos_token_id,
-                          on_token, ctx) -> dict:
+                          on_token, ctx, logprobs=False) -> dict:
         """One /generate leg under an existing request identity: the
         pre-ISSUE-20 generate() body.  Raises StreamInterrupted with
         THIS leg's delivered tokens; generate() merges legs."""
@@ -1309,6 +1345,8 @@ class InferenceClient:
                 "max_new_tokens": int(max_new_tokens)}
         if eos_token_id is not None:
             body["eos_token_id"] = int(eos_token_id)
+        if logprobs:
+            body["logprobs"] = True
         data = json.dumps(body).encode()
         headers = {"Content-Type": "application/json"}
         headers.update(ctx.to_headers())
@@ -1330,7 +1368,7 @@ class InferenceClient:
                 try:
                     with urllib.request.urlopen(
                             req, timeout=self.timeout) as r:
-                        tokens = []
+                        tokens, lps = [], []
                         for line in r:
                             line = line.strip()
                             if not line:
@@ -1350,12 +1388,14 @@ class InferenceClient:
                                     evt.get("error",
                                             "stream interrupted"),
                                     output_ids=evt.get("output_ids"),
-                                    tokens=tokens,
+                                    tokens=tokens, logprobs=lps,
                                     finish_reason=evt.get(
                                         "finish_reason", "interrupted"),
                                     request_id=evt.get("request_id"),
                                     tenant_id=ctx.tenant_id)
                             tokens.append(int(evt["token"]))
+                            if logprobs:
+                                lps.append(float(evt["logprob"]))
                             if on_token is not None:
                                 on_token(int(evt["token"]))
                     if final is None:
@@ -1380,7 +1420,7 @@ class InferenceClient:
             if retry_wait is not None:
                 self.sleep(retry_wait)
                 continue
-            return {
+            out = {
                 "output_ids": np.asarray(final["output_ids"], np.int32),
                 "tokens": tokens,
                 "finish_reason": final.get("finish_reason"),
@@ -1391,6 +1431,9 @@ class InferenceClient:
                 # the router when a resume leg served part of the stream
                 "resumed": int(final.get("resumed", 0) or 0),
             }
+            if logprobs:
+                out["logprobs"] = lps
+            return out
 
     def predict(self, *arrays, **named) -> dict:
         import urllib.error

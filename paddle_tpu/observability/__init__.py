@@ -115,6 +115,16 @@ _SCHEMA_COUNTERS = tuple(
        for e in ("submitted", "admitted", "completed", "cancelled",
                  "evicted")]
     + [("engine.tokens", {})]
+    # what a step ran (ISSUE 33): decode dispatches (plain or
+    # speculative alike) and — summed over them — the slots that ran
+    # and the cached positions they attended (delta over delta of
+    # engine.steps{kind=decode} is the mean a step); prompt positions
+    # COMPUTED by prefills, by the prefix cache's outcome for the
+    # sequence
+    + [("engine.steps", {"kind": "decode"}),
+       ("engine.decode_slots", {}), ("engine.decode_live_tokens", {})]
+    + [("engine.prefill_tokens", {"cache": c})
+       for c in ("hit", "partial", "miss")]
     + [("paged.dispatch", {"tier": t}) for t in ("pallas", "fallback")]
     # speculative decoding (ISSUE 12): per-pass draft-token outcomes —
     # accepted counts committed draft proposals, rejected the discarded
@@ -226,6 +236,14 @@ _SCHEMA_HISTS = (
     # the last token the dead replica delivered and the first token
     # the resume replica delivered — THE latency cost of a resume
     ("router.resume_gap_ms", {}),
+    # what an arrival waits for inside the engine (ISSUE 33): entry of
+    # submit() -> queued at the scheduler; queued -> its prefill
+    # begins; and the loop asking for the step lock -> holding it
+    # (maintenance — defrag, cache clear, close — is what it can wait
+    # behind)
+    ("engine.submit_wait_ms", {}),
+    ("engine.admit_wait_ms", {}),
+    ("engine.lock_wait_ms", {"who": "loop"}),
 )
 
 

@@ -880,6 +880,458 @@ def test_perf_gate_serving_metric_round_trip(tmp_path):
     assert run(1000.0).returncode == 2
 
 
+# ------------- ISSUE 33: what the engine says about itself -------------
+
+def _run(eng, handles):
+    """Step inline until every handle is done."""
+    idle = 0
+    while any(not h.done.is_set() for h in handles):
+        idle = 0 if eng.step() else idle + 1
+        assert idle < 1000, "engine made no progress"
+
+
+def _reference_logprobs(model, out, n_prompt):
+    """Teacher-forced: one full float32 forward over prompt + delivered
+    tokens, a plain log_softmax, read at the delivered tokens."""
+    logits = np.asarray(
+        model(P.to_tensor(out[None, :], "int32"))._value, np.float32)[0]
+    ref = np.asarray(jax.nn.log_softmax(jnp.asarray(logits), axis=-1))
+    return np.array([ref[n_prompt - 1 + i, out[n_prompt + i]]
+                     for i in range(out.size - n_prompt)])
+
+
+def _draft():
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    P.seed(7)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=128, hidden_size=16, num_layers=1, num_heads=2,
+        max_seq_len=64))
+    model.eval()
+    return model
+
+
+def _lp_cold(model, prompts):
+    eng = InferenceEngine(model, EngineConfig(
+        page_size=8, max_slots=2, max_seq_len=64))
+    hs = [eng.submit(prompts[1], max_new_tokens=1)]
+    _run(eng, hs)
+    assert hs[0].cache_state == "miss" and len(hs[0].logprobs) == 1
+    return hs
+
+
+def _lp_chunk(chunk):
+    def case(model, prompts):
+        eng = InferenceEngine(model, EngineConfig(
+            page_size=8, max_slots=3, decode_chunk=chunk,
+            max_seq_len=64))
+        hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
+        _run(eng, hs)
+        return hs
+    return case
+
+
+def _lp_warm(model, prompts):
+    eng = InferenceEngine(model, EngineConfig(
+        page_size=8, max_slots=2, max_seq_len=64))
+    first = [eng.submit(prompts[2], max_new_tokens=4)]
+    _run(eng, first)
+    # shares prompts[2]'s two committed pages, then goes its own way
+    again = np.concatenate([prompts[2][:16], prompts[4]])
+    hs = [eng.submit(again, max_new_tokens=6)]
+    _run(eng, hs)
+    assert hs[0].cache_state in ("hit", "partial")
+    assert hs[0]._seq.shared_len == 16
+    return first + hs
+
+
+def _lp_preempted(model, prompts):
+    eng = InferenceEngine(model, EngineConfig(
+        page_size=4, max_slots=2, num_pages=10, max_seq_len=64))
+    hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
+    _run(eng, hs)
+    assert any(h._seq.evictions for h in hs)   # someone was resumed
+    return hs
+
+
+def _lp_spec(model, prompts):
+    eng = InferenceEngine(model, EngineConfig(
+        page_size=8, max_slots=3, max_seq_len=64, spec_tokens=3),
+        draft_model=_draft())
+    hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
+    _run(eng, hs)
+    return hs
+
+
+def _lp_kv_int8(model, prompts):
+    eng = InferenceEngine(model, EngineConfig(
+        page_size=8, max_slots=3, decode_chunk=2, max_seq_len=64,
+        kv_precision="int8"))
+    hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
+    _run(eng, hs)
+    return hs
+
+
+def _lp_two_lengths(model, prompts):
+    eng = InferenceEngine(model, EngineConfig(
+        page_size=8, max_slots=2, max_seq_len=64))
+    hs = [eng.submit(prompts[0], max_new_tokens=8),     # 3 tokens
+          eng.submit(prompts[2], max_new_tokens=8)]     # 17 tokens
+    eng.step()
+    assert eng.scheduler.stats()["running"] == 2    # one batch
+    _run(eng, hs)
+    return hs
+
+
+@pytest.mark.parametrize("case,tol", [
+    (_lp_cold, 1e-4), (_lp_chunk(1), 1e-4), (_lp_chunk(4), 1e-4),
+    (_lp_warm, 1e-4), (_lp_preempted, 1e-4), (_lp_spec, 1e-4),
+    # the int8-KV tier's stated tolerance: its pages round each K/V
+    # vector to 8 bits, read 3.0e-4 at the most here
+    (_lp_kv_int8, 2e-3), (_lp_two_lengths, 1e-4),
+], ids=["cold_first_token", "decode_chunk1", "decode_chunk4",
+        "warm_prefill", "preempted_resumed", "speculative", "kv_int8",
+        "two_lengths_one_batch"])
+def test_delivered_logprobs_match_teacher_forced_reference(
+        gpt_model, prompts, case, tol):
+    """(a) Every delivered token carries `logit[token] -
+    logsumexp(logits)` of the program that chose it: equal to a plain
+    float32 forward over prompt + delivered tokens on every path a
+    token can take to the stream."""
+    for h in case(gpt_model, prompts):
+        out = h.result()
+        n0 = out.size - len(h.tokens)
+        assert len(h.logprobs) == len(h.tokens) > 0
+        assert list(h.stream(timeout=1, with_logprobs=True)) == \
+            list(zip(h.tokens, h.logprobs))
+        want = _reference_logprobs(gpt_model, out, n0)
+        np.testing.assert_allclose(h.logprobs, want, rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("seed", [11, 12, 13])
+def test_logprob_gap_sees_int8_weights_under_bfloat16(prompts, seed):
+    """(b) What a serve cell's `correct` will lean on, at CPU size: on
+    a model cast to bfloat16, the mean |log-probability - float32
+    reference over the same bfloat16-rounded weights| is larger with
+    the engine's int8 weight tier than without.  Read here (120
+    positions a seed): 5.7e-4 / 8.1e-4, 6.0e-4 / 9.7e-4, 6.0e-4 /
+    9.5e-4 — factors 1.43, 1.60, 1.57; 1.2 is asked."""
+    served = _gpt(seed=seed)
+    served.bfloat16()
+    ref = _gpt(seed=seed)
+    for (_, p), (_, q) in zip(ref.named_parameters(),
+                              served.named_parameters()):
+        p._value = q._value.astype(jnp.float32)
+    gap = {}
+    for tier in (None, "int8"):
+        eng = InferenceEngine(served, EngineConfig(
+            page_size=8, max_slots=3, max_seq_len=64,
+            weight_precision=tier))
+        hs = [eng.submit(p, max_new_tokens=24) for p in prompts]
+        _run(eng, hs)
+        gap[tier] = np.mean(np.concatenate([
+            np.abs(_reference_logprobs(ref, h.result(), p.size)
+                   - np.array(h.logprobs))
+            for h, p in zip(hs, prompts)]))
+    assert gap["int8"] > 1.2 * gap[None], gap
+
+
+def _raw_generate(address, body):
+    """POST /generate and return the stream's bytes, line by line."""
+    import urllib.request
+
+    req = urllib.request.Request(
+        address + "/generate", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json",
+                 "X-Request-Id": "raw-" + str(len(body))})
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return r.read().splitlines(keepends=True)
+
+
+def test_generate_stream_without_logprobs_is_byte_for_byte(
+        gen_server, gpt_model, prompts, refs):
+    """(f) A request that does not ask sees exactly the old stream."""
+    lines = _raw_generate(gen_server.address, {
+        "input_ids": prompts[1].tolist(), "max_new_tokens": 10})
+    toks = refs[1][prompts[1].size:]
+    want = [json.dumps({"token": int(t)}).encode() + b"\n" for t in toks]
+    want.append(json.dumps({
+        "done": True, "request_id": "raw-2", "finish_reason": "length",
+        "output_ids": [int(x) for x in refs[1]]}).encode() + b"\n")
+    assert lines == want
+
+
+def test_generate_stream_with_logprobs_carries_every_value(
+        gen_server, gpt_model, prompts, refs):
+    """(f) With `"logprobs": true` every token event and the final body
+    carry the value, and the client hands them back."""
+    from paddle_tpu.inference.serving import InferenceClient
+
+    lines = [json.loads(x) for x in _raw_generate(gen_server.address, {
+        "input_ids": prompts[1].tolist(), "max_new_tokens": 10,
+        "logprobs": True})]
+    events, final = lines[:-1], lines[-1]
+    assert [e["token"] for e in events] == \
+        [int(t) for t in refs[1][prompts[1].size:]]
+    assert all(set(e) == {"token", "logprob"} for e in events)
+    assert final["logprobs"] == [e["logprob"] for e in events]
+    want = _reference_logprobs(gpt_model, refs[1], prompts[1].size)
+    np.testing.assert_allclose(final["logprobs"], want, rtol=0,
+                               atol=1e-4)
+    cli = InferenceClient(gen_server.address, timeout=60.0)
+    r = cli.generate(prompts[1], max_new_tokens=10, logprobs=True)
+    assert r["logprobs"] == final["logprobs"]
+    assert "logprobs" not in cli.generate(prompts[1], max_new_tokens=2)
+
+
+def test_generate_logprobs_refused_by_an_engine_without_logits():
+    """An engine duck-type that has no logits answers 400 to
+    `"logprobs": true` and streams as ever without it."""
+    import urllib.error
+
+    from paddle_tpu.inference.fleet import ToyEngine
+    from paddle_tpu.inference.serving import InferenceServer
+
+    srv = InferenceServer(engine=ToyEngine(max_slots=2, token_time=0.0),
+                          request_timeout=30.0).start()
+    try:
+        body = {"input_ids": [1, 2, 3], "max_new_tokens": 3}
+        assert len(_raw_generate(srv.address, body)) == 4
+        with pytest.raises(urllib.error.HTTPError) as ei:
+            _raw_generate(srv.address, dict(body, logprobs=True))
+        assert ei.value.code == 400
+        assert "log-probabilities" in ei.value.read().decode()
+    finally:
+        srv.shutdown()
+
+
+def test_same_programs_with_and_without_logprobs(gpt_model, prompts):
+    """(c) The log-probability is always computed: asking for it
+    compiles nothing, one decode program and the same prefill programs
+    either way."""
+    from paddle_tpu.inference.serving import (
+        InferenceClient, InferenceServer,
+    )
+
+    programs = {}
+    for ask in (False, True):
+        eng = InferenceEngine(gpt_model, EngineConfig(
+            page_size=8, max_slots=2, max_seq_len=64))
+        srv = InferenceServer(engine=eng, request_timeout=60.0).start()
+        try:
+            cli = InferenceClient(srv.address, timeout=60.0)
+            for p in prompts[:3]:
+                cli.generate(p, max_new_tokens=6, logprobs=ask)
+        finally:
+            srv.shutdown()
+        programs[ask] = sorted(eng._programs, key=repr)
+    assert programs[False] == programs[True]
+    kinds = [k[0] for k in programs[True]]
+    assert kinds.count("decode") == 1 and kinds.count("prefill") == 2
+
+
+def test_step_accounting_matches_a_scripted_run(gpt_model, prompts):
+    """(d) `batch` / `live_tokens` on `engine.decode`, the prefill
+    span's fields and the counters, against a run small enough to
+    count by hand: prompts of 3, 9 and 17 tokens wanting 2, 5 and 5,
+    pages of 8.  One step prefills all three (a token each), then four
+    decode steps; the short one finishes after the first."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.observability import metrics, trace
+
+    obs.attach(crash_hook=False)
+    metrics.reset()
+    obs.attach(crash_hook=False)
+    trace.clear()
+    try:
+        eng = InferenceEngine(gpt_model, EngineConfig(
+            page_size=8, max_slots=3, max_seq_len=64,
+            prefill_bucket=16))
+        hs = [eng.submit(prompts[i], max_new_tokens=n)
+              for i, n in ((0, 2), (1, 5), (2, 5))]
+        truth = []          # the scheduler's own view, step by step
+        while any(not h.done.is_set() for h in hs):
+            eng.step()
+            truth.append(sorted(len(h.tokens) for h in hs))
+        assert truth == [[2, 2, 2], [2, 3, 3], [2, 4, 4], [2, 5, 5]]
+        assert not eng.step()                       # one idle step
+        ev = [e for e in trace.events() if e.get("ph") == "X"]
+        dec = [e["args"] for e in ev if e["name"] == "engine.decode"]
+        assert [a["batch"] for a in dec] == [3, 2, 2, 2]
+        # cached positions at each step's start: 3+9+17, 10+18, ...
+        assert [a["live_tokens"] for a in dec] == [29, 28, 30, 32]
+        pre = [e["args"] for e in ev if e["name"] == "engine.prefill"]
+        assert [(a["tokens"], a["cached_tokens"]) for a in pre] == \
+            [(3, 0), (9, 0), (17, 0)]
+        sch = [e["args"] for e in ev if e["name"] == "engine.schedule"]
+        assert (sch[0]["admitted"], sch[0]["waiting"],
+                sch[0]["free_slots"]) == (3, 0, 0)
+        assert sch[1]["free_slots"] == 1            # the short one left
+        c = metrics.snapshot()["counters"]
+        assert c["engine.steps{kind=decode}"] == 4
+        assert c["engine.decode_slots"] == 3 + 2 + 2 + 2
+        assert c["engine.decode_live_tokens"] == 29 + 28 + 30 + 32
+        assert c["engine.prefill_tokens{cache=miss}"] == 3 + 9 + 17
+        assert c["engine.prefill_tokens{cache=hit}"] == 0
+        h = metrics.snapshot()["histograms"]
+        assert h["engine.submit_wait_ms"]["count"] == 3
+        assert h["engine.admit_wait_ms"]["count"] == 3
+        assert h["engine.lock_wait_ms{who=loop}"]["count"] == 5
+        kinds = [e["kind"] for e in
+                 eng.request_debug(hs[0].request_id)["events"]]
+        assert kinds[:4] == ["submitted", "queued", "admitted",
+                             "prefill_start"]
+    finally:
+        obs.detach()
+
+
+def test_step_accounting_counts_speculative_passes(gpt_model, prompts):
+    """(d) with a draft model: one `engine.steps{kind=decode}` a
+    speculative pass, so the documented means (`engine.decode_slots`
+    and `engine.decode_live_tokens` over that count) hold in either
+    mode.  How many tokens a pass commits depends on the draft, so the
+    truth is read off the sequences before each step."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.observability import metrics, trace
+
+    obs.attach(crash_hook=False)
+    metrics.reset()
+    obs.attach(crash_hook=False)
+    trace.clear()
+    try:
+        eng = InferenceEngine(gpt_model, EngineConfig(
+            page_size=8, max_slots=3, max_seq_len=64, spec_tokens=2),
+            draft_model=_draft())
+        hs = [eng.submit(prompts[i], max_new_tokens=n)
+              for i, n in ((0, 2), (1, 7), (2, 7))]
+        truth = [(3, 3 + 9 + 17)]     # the first step prefills all
+        eng.step()
+        while any(not h.done.is_set() for h in hs):
+            live = [h._seq for h in hs if not h._seq.done]
+            truth.append((len(live), sum(s.length for s in live)))
+            eng.step()
+        assert len(truth) >= 3 and truth[1][0] == 2
+        dec = [e["args"] for e in trace.events()
+               if e.get("ph") == "X" and e["name"] == "engine.decode"]
+        assert all(a["spec"] for a in dec)
+        assert [(a["batch"], a["live_tokens"]) for a in dec] == truth
+        c = metrics.snapshot()["counters"]
+        assert c["engine.steps{kind=decode}"] == len(truth)
+        assert c["engine.decode_slots"] == sum(b for b, _ in truth)
+        assert c["engine.decode_live_tokens"] == sum(
+            n for _, n in truth)
+    finally:
+        obs.detach()
+
+
+def test_submit_and_cancel_do_not_wait_for_a_step(gpt_model, prompts):
+    """(e) No timing: a decode program that blocks on an Event holds a
+    step (and the step lock) open; submit() and cancel() from another
+    thread return, and the new sequence stands in the scheduler's
+    queue, while the step is still blocked.  On the parent both calls
+    sat in `with self._lock` until the step ended."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.observability import metrics
+
+    obs.attach(crash_hook=False)
+    metrics.reset()
+    obs.attach(crash_hook=False)
+    eng = InferenceEngine(gpt_model, EngineConfig(
+        page_size=8, max_slots=2, max_seq_len=64))
+    entered, release = threading.Event(), threading.Event()
+    real = eng._decode_program(1)
+
+    def blocked(*args):
+        entered.set()
+        assert release.wait(60)
+        return real(*args)
+
+    eng._programs[("decode", 1, False)] = blocked
+    try:
+        first = eng.submit(prompts[0], max_new_tokens=4)
+        eng.start()
+        assert entered.wait(60)             # a step is open, lock held
+        got = {}
+
+        def arrive():
+            got["kept"] = eng.submit(prompts[1], max_new_tokens=4)
+            got["dropped"] = eng.submit(prompts[3], max_new_tokens=4)
+            got["cancel"] = eng.cancel(got["dropped"].request_id)
+
+        t = threading.Thread(target=arrive, daemon=True)
+        t.start()
+        t.join(5)
+        assert not t.is_alive(), "submit()/cancel() waited for the step"
+        assert not release.is_set() and not first.done.is_set()
+        assert eng.scheduler.waiting_sequences == 2     # queued NOW
+        assert got["cancel"] and got["dropped"].cancelled
+        h = metrics.snapshot()["histograms"]
+        assert h["engine.submit_wait_ms"]["count"] == 3  # one a call
+        release.set()
+        assert len(got["kept"].result(timeout=60)) == prompts[1].size + 4
+        assert len(first.result(timeout=60)) == prompts[0].size + 4
+    finally:
+        release.set()
+        eng.stop()
+        obs.detach()
+
+
+def test_close_frees_device_state_and_is_idempotent(gpt_model, prompts):
+    """(g) close() stops the loop, finishes what is in flight as
+    cancelled, drops pools, weights and programs; again is a no-op;
+    nothing can be submitted afterwards; the host view stays."""
+    eng = InferenceEngine(gpt_model, EngineConfig(
+        page_size=8, max_slots=2, max_seq_len=64, spec_tokens=2),
+        draft_model=_draft())
+    eng.generate(prompts[:2], max_new_tokens=3)
+    assert eng._programs and eng._k_pools is not None
+    pending = eng.submit(prompts[2], max_new_tokens=4)
+    eng.step()
+    eng.start()
+    eng.close()
+    eng.close()
+    assert pending.done.is_set() and pending.cancelled
+    assert eng._thread is None and not eng._programs
+    for name in ("_k_pools", "_v_pools", "_k_scales", "_v_scales",
+                 "_params", "_buffers", "_draft"):
+        assert getattr(eng, name) is None, name
+    assert eng.scheduler.stats()["running"] == 0
+    assert eng.stats()["pages"]["used"] == eng.pool.used_pages
+    assert not eng.step()
+    with pytest.raises(RuntimeError, match="closed"):
+        eng.submit(prompts[0], max_new_tokens=2)
+    with pytest.raises(RuntimeError, match="closed"):
+        eng.start()
+
+
+@pytest.mark.parametrize("phase,extra", [
+    ("witness", []), ("gap", ["--controls", "1"])])
+def test_serve_probe_rehearses(phase, extra):
+    """tools/serve_probe.py runs end to end at its tiny CPU size: the
+    witness reads the engine's own counters equal to what its request
+    sizes imply; the gap phase reads the log-probability gap after
+    `close()` freed the engine."""
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "serve_probe.py"),
+         "--rehearse", "--phase", phase, "--seed", "3300000101", *extra],
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["phase"] == phase
+    if phase == "witness":
+        assert line["done"] == 15
+        assert line["submit_wait_ms"]["n"] == 15
+        assert line["schedule_left_waiting_beside_free_slot"] == 0
+        for key in ("decode_slots_sum", "decode_live_tokens_sum"):
+            assert line[key]["counter"] == line[key]["implied"] > 0
+    else:
+        assert line["positions"] > 0
+        # float8 weights read further from the reference than the
+        # bfloat16 program does, even at this size
+        assert line["control_fp8"]["mean"] > line["program"]["mean"] > 0
+
+
 @pytest.mark.chaos
 def test_engine_chaos_scenario():
     sys.path.insert(0, os.path.join(REPO, "tools"))

@@ -505,6 +505,30 @@ def test_attach_snapshot_schema_end_to_end(monkeypatch):
     assert summ["compile_ms"]["count"] == 1
 
 
+@pytest.mark.parametrize("section,keys", [
+    ("counters", [
+        "engine.steps{kind=decode}", "engine.decode_slots", "engine.decode_live_tokens",
+        "engine.prefill_tokens{cache=hit}",
+        "engine.prefill_tokens{cache=partial}",
+        "engine.prefill_tokens{cache=miss}"]),
+    ("histograms", [
+        "engine.submit_wait_ms", "engine.admit_wait_ms",
+        "engine.lock_wait_ms{who=loop}"]),
+])
+def test_attach_declares_engine_accounting_schema(section, keys):
+    """ISSUE 33: a fresh process shows the engine's step accounting and
+    its wait histograms at zero — a reader takes deltas and never has
+    to ask whether the key exists."""
+    snap = obs.attach(crash_hook=False).snapshot()[section]
+    try:
+        for key in keys:
+            assert key in snap, key
+            got = snap[key]
+            assert (got["count"] if section == "histograms" else got) == 0
+    finally:
+        obs.detach()
+
+
 def test_bench_telemetry_stack_importable():
     """Satellite CI gate: the bench entrypoint and the whole telemetry
     stack import under JAX_PLATFORMS=cpu (conftest pins cpu), and the

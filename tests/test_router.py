@@ -1073,6 +1073,17 @@ def test_resume_refusal_reasons():
         _close(r)
 
 
+@pytest.mark.parametrize("asked", [False, True])
+def test_resume_body_repeats_the_logprobs_field(asked):
+    """A resume leg asks for log-probabilities exactly when the first
+    leg did (ISSUE 33): the client parses `logprob` off every line."""
+    raw, verify = Router._resume_body([1, 2, 3], [7, 8], 10, None, 1,
+                                      logprobs=asked)
+    body = json.loads(raw)
+    assert verify == 8 and body["input_ids"] == [1, 2, 3, 7]
+    assert body.get("logprobs", False) is asked
+
+
 def test_resume_env_knobs(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_STREAM_RESUME_MAX", "5")
     monkeypatch.setenv("PADDLE_TPU_STREAM_RESUME_CLASSES",
