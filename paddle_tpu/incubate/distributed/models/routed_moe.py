@@ -16,12 +16,18 @@ it, and both of its transposes, to its own grouped Mosaic kernel — a dense
 loop over experts on other backends).  Rows are bounded statically by what
 can really arrive: every token may send all of its `top_k` choices here,
 so the bound is tokens x min(top_k, num_held) rows.  Buffers of that size
-would not fit beside the model, so the sorted rows are walked in CHUNKS of
-`rows_per_chunk`: the first chunk always runs, the others only while rows
-are left (`lax.while_loop` on the routed count — a balanced router needs
-one).  The walk is a `jax.custom_vjp` (a dynamic trip count has no
-reverse-mode rule): the backward recomputes a chunk's gate and up
-projections instead of keeping them.
+would not fit beside the model, so the sorted rows are walked in CHUNKS:
+the first, a little over a balanced router's load, always runs, the later
+ones only while rows are left (`lax.while_loop` on the routed count — a
+router near balance needs none).  The walk is a `jax.custom_vjp` (a dynamic
+trip count has no reverse-mode rule): the backward recomputes a chunk's
+gate and up projections instead of keeping them.
+
+The INDEX work runs no gather or scatter of single elements over the
+tokens x top_k assignments (XLA's cost milliseconds each on the chip): the
+routing weights and the rows an expert are compare-and-sum over the
+experts, and a row's weight is fetched inside the chunk walk, for the
+chunk's rows only.
 """
 from __future__ import annotations
 
@@ -40,8 +46,9 @@ __all__ = ["RoutedMoELayer", "sigmoid_topk_route", "sort_held",
            "grouped_experts", "default_rows_per_chunk", "row_counters"]
 
 # what the layer counted in its last step, one int32 vector a layer: rows
-# routed to held experts, rows the grouped product was handed (chunks run
-# x rows_per_chunk), rows left unprocessed (always 0)
+# routed to held experts, rows the grouped product was handed (the first
+# chunk + the later chunks run x their size), rows left unprocessed
+# (always 0)
 ROW_KINDS = ("routed", "computed", "dropped")
 
 _RAGGED = jax.lax.RaggedDotDimensionNumbers
@@ -61,14 +68,21 @@ def sigmoid_topk_route(x, w_router, expert_bias, top_k, route_scale,
     (not `s + bias`), divided by their sum + 1e-20 (`route_norm`), times
     `route_scale`.  x [T, H], w_router [H, E].  Returns (idx [T, k] int32,
     weights [T, k] float32).  The choice is held across a block's
-    recomputation (`_keep`): the replay gathers the weights at the kept
+    recomputation (`_keep`): the replay reads the weights at the kept
     `idx` and runs no `top_k`."""
     logits = jax.lax.dot_general(x, w_router, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + expert_bias.astype(jnp.float32), top_k)
     idx = _keep(idx.astype(jnp.int32), "moe_sort")
-    w = jnp.take_along_axis(s, idx, axis=1)
+    # s at the chosen, as a masked sum over the experts: one term of a sum
+    # is not zero and a token's choices are distinct, so value and
+    # transpose are `take_along_axis`'s and its scatter-add's to the bit.
+    # Tokens minor ([k, E, T]): the sum runs down the experts with no
+    # reduction across lanes — half the time of [T, k, E] on the chip
+    experts = jnp.arange(s.shape[1], dtype=jnp.int32)[None, :, None]
+    w = jnp.sum(jnp.where(idx.T[:, None, :] == experts, s.T[None], 0.0),
+                axis=1).T
     if route_norm:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
     return idx, w * route_scale
@@ -77,29 +91,39 @@ def sigmoid_topk_route(x, w_router, expert_bias, top_k, route_scale,
 def sort_held(idx, expert_start, num_held, rows_pad):
     """The assignments whose expert is held here, sorted by expert.
     idx [T, k].  Returns (tok [rows_pad] — the token of each sorted row,
-    slot [rows_pad] — its place in the flat [T*k] assignment list, sizes
+    slot [rows_pad] — its place in the flat assignment list, sizes
     [num_held] — rows an expert, total).  Rows from `total` on are padding
-    (token 0)."""
+    (token 0); their slots are the assignments held elsewhere and, past
+    T*k, the row's own number: no two rows share a slot."""
     t, k = idx.shape
     local = idx.reshape(-1) - expert_start
     held = (local >= 0) & (local < num_held)
     key = jnp.where(held, local, num_held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.zeros((num_held + 1,), jnp.int32).at[key].add(1)[:num_held]
+    sizes = jnp.sum(key[:, None] == jnp.arange(num_held, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
     total = jnp.sum(sizes)
-    pad = rows_pad - t * k
-    order = jnp.pad(order, (0, pad))
+    slot = jnp.concatenate(
+        [order, jnp.arange(t * k, rows_pad, dtype=jnp.int32)])
     live = jnp.arange(rows_pad, dtype=jnp.int32) < total
-    slot = jnp.where(live, order, 0)
-    return slot // k, slot, sizes, total
+    return jnp.where(live, slot // k, 0), slot, sizes, total
 
 
 def default_rows_per_chunk(tokens, top_k, num_held, num_experts):
-    """Twice the rows a balanced router sends here, in whole tiles of 512,
-    and no more than can arrive."""
-    bound = tokens * min(top_k, num_held)
+    """(rows of the first chunk, rows of each later one), from the shapes
+    alone.  The first: 1.25 x the rows a balanced router sends here, in
+    whole tiles of 512 — a router up to a quarter over balance needs no
+    second chunk.  A later one: the balanced load in such tiles;
+    it runs under skew only.  Neither more than can arrive."""
+    bound = -(-tokens * min(top_k, num_held) // 8) * 8
     expect = -(-tokens * top_k * num_held // num_experts)
-    return min(-(-2 * expect // 512) * 512, -(-bound // 8) * 8)
+    first = min(-(-5 * expect // (4 * 512)) * 512, bound)
+    return first, min(-(-expect // 512) * 512, bound)
+
+
+def _rows_pad(assignments, first, later):
+    """The sorted rows' padded length: every assignment lies in a chunk."""
+    return first + -(-max(assignments - first, 0) // later) * later
 
 
 def _chunk_sizes(cum, lo, rows):
@@ -112,16 +136,21 @@ def _row_starts(sizes):
     return jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
 
 
-def _chunk_rows(x, tok, w_row, cum, total, c, rows):
-    """Chunk `c` of the sorted rows: (their tokens, their routing weights,
-    which of them are real [rows, 1], rows an expert in the chunk, the
-    gathered inputs)."""
-    lo = c * rows
+def _chunk_rows(x, tok, slot, w_flat, cum, total, lo, rows):
+    """Sorted rows [lo, lo + rows): (their tokens, their slots, their
+    routing weights, which of them are real [rows, 1], rows an expert in
+    the chunk, the gathered inputs)."""
     with jax.named_scope("moe.sort"):
         tk = jax.lax.dynamic_slice_in_dim(tok, lo, rows)
-        wr = jax.lax.dynamic_slice_in_dim(w_row, lo, rows)
+        sl = jax.lax.dynamic_slice_in_dim(slot, lo, rows)
+        wr = w_flat.at[sl].get(mode="promise_in_bounds", unique_indices=True)
         live = ((lo + jnp.arange(rows, dtype=jnp.int32)) < total)[:, None]
-        return tk, wr, live, _chunk_sizes(cum, lo, rows), x[tk]
+        return tk, sl, wr, live, _chunk_sizes(cum, lo, rows), x[tk]
+
+
+def _later_chunks(total, first, later):
+    """Chunks the `while_loop` runs after the first: rows left / later."""
+    return jnp.maximum(-(-(total - first) // later), 0)
 
 
 def _silu_mul(g, u):
@@ -135,25 +164,27 @@ def _rd(x, w, sizes):
                               ).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def grouped_experts(x, w_gate, w_up, w_down, tok, w_row, sizes, total,
-                    rows_per_chunk):
-    """sum over the sorted rows r of w_row[r] * expert(x[tok[r]]) scattered
-    back to the tokens: x [T, H]; w_gate, w_up [E, H, F]; w_down [E, F, H];
-    tok, w_row [rows_pad] (rows_pad a multiple of rows_per_chunk); sizes
-    [E] rows an expert; total their sum.  Returns (y [T, H] in x's dtype,
-    counts [3] int32: rows routed, computed, dropped)."""
-    return _grouped_fwd(x, w_gate, w_up, w_down, tok, w_row, sizes, total,
-                        rows_per_chunk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def grouped_experts(x, w_gate, w_up, w_down, tok, slot, w_flat, sizes, total,
+                    chunks):
+    """sum over the sorted rows r of w_flat[slot[r]] * expert(x[tok[r]])
+    scattered back to the tokens: x [T, H]; w_gate, w_up [E, H, F]; w_down
+    [E, F, H]; tok, slot [rows_pad] and w_flat [rows_pad] — the flat
+    routing weights, padded (`_rows_pad`); sizes [E] rows an expert; total
+    their sum; chunks (first, later) rows a chunk.  Returns (y [T, H] in
+    x's dtype, counts [3] int32: rows routed, computed, dropped)."""
+    return _grouped_fwd(x, w_gate, w_up, w_down, tok, slot, w_flat, sizes,
+                        total, chunks)[0]
 
 
-def _grouped_fwd(x, w_gate, w_up, w_down, tok, w_row, sizes, total,
-                 rows_per_chunk):
-    rc = rows_per_chunk
+def _grouped_fwd(x, w_gate, w_up, w_down, tok, slot, w_flat, sizes, total,
+                 chunks):
+    first, later = chunks
     cum = _row_starts(sizes)
 
-    def chunk(c, y):
-        tk, wr, live, gs, xs = _chunk_rows(x, tok, w_row, cum, total, c, rc)
+    def chunk(lo, rows, y):
+        tk, _, wr, live, gs, xs = _chunk_rows(x, tok, slot, w_flat, cum,
+                                              total, lo, rows)
         with jax.named_scope("moe.gmm"):
             a = _silu_mul(_rd(xs, w_gate, gs), _rd(xs, w_up, gs))
             o = jax.lax.ragged_dot(a, w_down, gs,
@@ -162,33 +193,35 @@ def _grouped_fwd(x, w_gate, w_up, w_down, tok, w_row, sizes, total,
             o = jnp.where(live, o * wr[:, None], 0.0)
             return y.at[tk].add(o), jnp.sum(live.astype(jnp.int32))
 
-    y0 = jnp.zeros(x.shape, jnp.float32)
-    y, done = chunk(jnp.int32(0), y0)
-    n_chunks = jnp.maximum(-(-total // rc), 1)
+    y, done = chunk(jnp.int32(0), first, jnp.zeros(x.shape, jnp.float32))
+    n_later = _later_chunks(total, first, later)
 
     def body(carry):
         c, y, done = carry
-        y, n = chunk(c, y)
+        y, n = chunk(first + c * later, later, y)
         return c + 1, y, done + n
 
-    _, y, done = jax.lax.while_loop(lambda s: s[0] < n_chunks, body,
-                                    (jnp.int32(1), y, done))
-    counts = jnp.stack([total, n_chunks * rc, total - done]).astype(jnp.int32)
-    return (y.astype(x.dtype), counts), (x, w_gate, w_up, w_down, tok,
-                                        w_row, sizes, total)
+    _, y, done = jax.lax.while_loop(lambda s: s[0] < n_later, body,
+                                    (jnp.int32(0), y, done))
+    counts = jnp.stack([total, first + n_later * later,
+                        total - done]).astype(jnp.int32)
+    return (y.astype(x.dtype), counts), (x, w_gate, w_up, w_down, tok, slot,
+                                        w_flat, sizes, total)
 
 
-def _grouped_bwd(rows_per_chunk, res, cts):
-    x, w_gate, w_up, w_down, tok, w_row, sizes, total = res
+def _grouped_bwd(chunks, res, cts):
+    x, w_gate, w_up, w_down, tok, slot, w_flat, sizes, total = res
     dy = cts[0]
-    rc = rows_per_chunk
+    first, later = chunks
     cum = _row_starts(sizes)
     f32 = jnp.float32
 
-    def chunk(c, dx, dwr):
-        """Gradients of chunk `c`: adds into dx [T, H] f32 and writes its
-        rows of dwr [rows_pad] f32; returns the chunk's (dWg, dWu, dWd)."""
-        tk, wr, live, gs, xs = _chunk_rows(x, tok, w_row, cum, total, c, rc)
+    def chunk(lo, rows, dx, dw):
+        """Gradients of sorted rows [lo, lo + rows): adds into dx [T, H]
+        f32 and writes the rows' places of dw [rows_pad] f32; returns the
+        chunk's (dWg, dWu, dWd)."""
+        tk, sl, wr, live, gs, xs = _chunk_rows(x, tok, slot, w_flat, cum,
+                                               total, lo, rows)
         with jax.named_scope("moe.sort"):
             dyo = jnp.where(live, dy[tk], 0).astype(x.dtype)
         with jax.named_scope("moe.gmm"):
@@ -200,8 +233,8 @@ def _grouped_bwd(rows_per_chunk, res, cts):
             sg = jax.nn.sigmoid(g32)
             act = g32 * sg
             a32 = act * u32
-            # d(w_row): <dy[tok], expert(x)> = <a, dy[tok] Wd^T>
-            dwr_c = jnp.where(live[:, 0], jnp.sum(a32 * da_u, axis=1), 0.0)
+            # d(row's weight): <dy[tok], expert(x)> = <a, dy[tok] Wd^T>
+            dwr = jnp.where(live[:, 0], jnp.sum(a32 * da_u, axis=1), 0.0)
             da = da_u * wr[:, None]
             dg = (da * u32 * sg * (1.0 + g32 * (1.0 - sg))).astype(x.dtype)
             du = (da * act).astype(x.dtype)
@@ -219,25 +252,26 @@ def _grouped_bwd(rows_per_chunk, res, cts):
                     du, w_up, gs, _DN_T, preferred_element_type=f32))
         with jax.named_scope("moe.combine"):
             dx = dx.at[tk].add(jnp.where(live, dxs, 0.0))
-            dwr = jax.lax.dynamic_update_slice_in_dim(dwr, dwr_c, c * rc, 0)
-        return dx, dwr, (dwg, dwu, dwd)
+            # no slot is written twice (`sort_held`), in a chunk or across
+            dw = dw.at[sl].set(dwr, mode="promise_in_bounds",
+                               unique_indices=True)
+        return dx, dw, (dwg, dwu, dwd)
 
-    dx0 = jnp.zeros(x.shape, f32)
-    dwr0 = jnp.zeros(w_row.shape, f32)
-    dx, dwr, dws = chunk(jnp.int32(0), dx0, dwr0)
-    n_chunks = jnp.maximum(-(-total // rc), 1)
+    dx, dw, dws = chunk(jnp.int32(0), first, jnp.zeros(x.shape, f32),
+                        jnp.zeros(w_flat.shape, f32))
+    n_later = _later_chunks(total, first, later)
 
     def body(carry):
-        c, dx, dwr, dws = carry
-        dx, dwr, more = chunk(c, dx, dwr)
-        return c + 1, dx, dwr, tuple(a + b for a, b in zip(dws, more))
+        c, dx, dw, dws = carry
+        dx, dw, more = chunk(first + c * later, later, dx, dw)
+        return c + 1, dx, dw, tuple(a + b for a, b in zip(dws, more))
 
-    _, dx, dwr, dws = jax.lax.while_loop(
-        lambda s: s[0] < n_chunks, body, (jnp.int32(1), dx, dwr, dws))
+    _, dx, dw, dws = jax.lax.while_loop(
+        lambda s: s[0] < n_later, body, (jnp.int32(0), dx, dw, dws))
     dwg, dwu, dwd = dws
     return (dx.astype(x.dtype), dwg.astype(w_gate.dtype),
-            dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), None,
-            dwr.astype(w_row.dtype), None, None)
+            dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), None, None,
+            dw.astype(w_flat.dtype), None, None)
 
 
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -252,19 +286,19 @@ def _routed_part(x, w_router, expert_bias, w_gate, w_up, w_down, *, top_k,
     with jax.named_scope("moe.route"):
         idx, w = sigmoid_topk_route(x, w_router, expert_bias, top_k,
                                     route_scale, route_norm)
-    rc = default_rows_per_chunk(t, top_k, num_held, w_router.shape[1])
-    # every assignment of every token fits: tokens x top_k >= the bound
-    rows_pad = -(-t * top_k // rc) * rc
+    chunks = default_rows_per_chunk(t, top_k, num_held, w_router.shape[1])
+    rows_pad = _rows_pad(t * top_k, *chunks)
     with jax.named_scope("moe.sort"):
         # the sort's small products (2 MB a layer) are the grouped VJP's
         # residuals: held, so that a block's replay runs no argsort
         tok, slot, sizes, total = (
             _keep(v, "moe_sort")
             for v in sort_held(idx, expert_start, num_held, rows_pad))
-        w_row = w.reshape(-1)[slot].astype(jnp.float32)
-    y, counts = grouped_experts(x, w_gate, w_up, w_down, tok, w_row,
+        w_flat = jnp.pad(w.reshape(-1).astype(jnp.float32),
+                         (0, rows_pad - t * top_k))
+    y, counts = grouped_experts(x, w_gate, w_up, w_down, tok, slot, w_flat,
                                 jax.lax.stop_gradient(sizes),
-                                jax.lax.stop_gradient(total), rc)
+                                jax.lax.stop_gradient(total), chunks)
     return y, sizes, counts
 
 

@@ -227,6 +227,7 @@ def test_grouped_expert_products_compile_to_the_chips_own_kernel(one_chip):
     backward: every `ragged_dot` (and both transposes of it) becomes the
     TPU compiler's grouped Mosaic kernel — none is left as a dense
     per-expert expansion."""
+    import math
     import re
 
     from paddle_tpu.incubate.distributed.models import routed_moe
@@ -246,6 +247,15 @@ def test_grouped_expert_products_compile_to_the_chips_own_kernel(one_chip):
         ((held, f, h), bf))
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 11
     assert not re.search(r" ragged-dot\(", text)
+    # the index work: no gather or scatter reads an index operand as long
+    # as the tokens x top_k assignments (XLA's run milliseconds each on
+    # the chip) — a chunk's own are the longest
+    first, later = routed_moe.default_rows_per_chunk(t, k, held, e)
+    size = {m.group(1): math.prod(int(d) for d in m.group(2).split(","))
+            for m in re.finditer(r"(%[\w.\-]+) = \w+\[([\d,]+)\]", text)}
+    spans = [size[m.group(1)] for m in re.finditer(
+        r" (?:gather|scatter)\(%[\w.\-]+, (%[\w.\-]+)[,)]", text)]
+    assert spans and max(spans) <= max(first, later) < t * k
 
 
 def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,

@@ -50,7 +50,7 @@ LOADS = {
 @pytest.mark.parametrize("load", list(LOADS))
 @pytest.mark.parametrize("start,held,chunk", [
     (0, 8, None),     # every expert here: one chunk holds all that can come
-    (2, 4, 16),       # a share, walked in many small chunks
+    (2, 4, (24, 16)),  # a share, walked in many small chunks
     (0, 3, None),     # a share whose chunk is its static bound
 ])
 def test_grouped_product_matches_a_loop_over_experts(rng, monkeypatch, load,
@@ -81,26 +81,107 @@ def test_grouped_product_matches_a_loop_over_experts(rng, monkeypatch, load,
             1.0, float(jnp.abs(w).max())))
 
 
+def _parent_route(x, wr, bias):
+    """(idx, weights) as PR 33 read them: `take_along_axis`."""
+    s = jax.nn.sigmoid(jax.lax.dot_general(
+        x, wr, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+    _, idx = jax.lax.top_k(s + bias, K)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    return idx, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * SCALE
+
+
+def _parent_forms(x, wr, bias, wg, wu, wd, start):
+    """The routed part with the index work as PR 33 had it: the weights by
+    `take_along_axis`, the rows an expert by a scatter-add of ones, the
+    weights gathered WHOLE into sorted order (so a chunk's own gather is a
+    slice: its slots are the rows' numbers).  The chunk walk is the
+    module's."""
+    held = wg.shape[0]
+    idx, w = _parent_route(x, wr, bias)
+    chunks = rm.default_rows_per_chunk(x.shape[0], K, held, E)
+    rows_pad = rm._rows_pad(x.shape[0] * K, *chunks)
+    local = idx.reshape(-1) - start
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    total = jnp.sum(sizes)
+    order = jnp.pad(order, (0, rows_pad - order.shape[0]))
+    live = jnp.arange(rows_pad, dtype=jnp.int32) < total
+    slot = jnp.where(live, order, 0)
+    w_row = w.reshape(-1)[slot].astype(jnp.float32)
+    y, counts = rm.grouped_experts(
+        x, wg, wu, wd, slot // K, jnp.arange(rows_pad, dtype=jnp.int32),
+        w_row, sizes, total, chunks)
+    return y, sizes, counts
+
+
+def _assert_same_to_the_bit(got, want):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+@pytest.mark.parametrize("start,held,chunk", [
+    (0, 8, None),        # every expert here, one chunk
+    (2, 4, (40, 24)),    # a share in several chunks of two sizes
+])
+def test_index_forms_are_the_parents_to_the_bit(rng, monkeypatch, load, start,
+                                                held, chunk):
+    """The routing weights as a masked sum, the rows an expert by
+    compare-and-sum and a chunk's own gather of its rows' weights (and its
+    write of their gradients): value and gradient are those of
+    `take_along_axis`, `.at[].add` and the whole gather, digit for digit —
+    balanced, skewed and with an expert nobody chose.  Evaluated equation
+    by equation: what is compared is the mathematics, not a compiler's
+    fusions."""
+    if chunk:
+        monkeypatch.setattr(rm, "default_rows_per_chunk", lambda *a: chunk)
+    x = jnp.asarray(rng.randn(T, H), jnp.float32)
+    wr, wg, wu, wd = _weights(rng, held)
+    bias = jnp.asarray(LOADS[load], jnp.float32)
+    dy = jnp.asarray(rng.randn(T, H), jnp.float32)
+    dw = jnp.asarray(rng.randn(T, K), jnp.float32)
+
+    def whole(part):
+        def f(x, wr, bias, wg):
+            y, sizes, counts = part(x, wr, bias, wg, wu, wd, start)
+            return (y * dy).sum(), (y, sizes, counts)
+        return jax.value_and_grad(f, argnums=(0, 1, 2, 3), has_aux=True)(
+            x, wr, bias, wg)
+
+    def route_alone(route):
+        return jax.value_and_grad(lambda x, wr: (route(x, wr)[1] * dw).sum(),
+                                  argnums=(0, 1))(x, wr)
+
+    got = whole(_mine)
+    _assert_same_to_the_bit(got, whole(_parent_forms))
+    (_, (_, sizes, counts)), (_, dwr, dbias, _) = got
+    assert int(counts[0]) == int(sizes.sum()) and int(counts[2]) == 0
+    assert float(jnp.abs(dwr).max()) > 0 and not np.asarray(dbias).any()
+    _assert_same_to_the_bit(
+        route_alone(lambda x, wr: rm.sigmoid_topk_route(x, wr, bias, K, SCALE)),
+        route_alone(lambda x, wr: _parent_route(x, wr, bias)))
+
+
 def test_static_row_bound_is_what_can_arrive():
-    # a token sends at most min(top_k, held) rows here
-    assert rm.default_rows_per_chunk(16384, 8, 16, 128) == 32768
-    assert rm.default_rows_per_chunk(64, 3, 2, 8) == 128      # bound: 64 x 2
-    assert rm.default_rows_per_chunk(96, 3, 8, 8) == 288      # 96 x 3
+    # (first chunk, later chunks): 1.25 x and 1 x the balanced load in
+    # tiles of 512; a token sends at most min(top_k, held) rows here
+    assert rm.default_rows_per_chunk(16384, 8, 16, 128) == (20480, 16384)
+    assert rm.default_rows_per_chunk(64, 3, 2, 8) == (128, 128)  # 64 x 2
+    assert rm.default_rows_per_chunk(96, 3, 8, 8) == (288, 288)  # 96 x 3
+    # every assignment has a place in some chunk
+    assert rm._rows_pad(16384 * 8, 20480, 16384) == 20480 + 7 * 16384
+    assert rm._rows_pad(288, 288, 288) == 288
 
 
-def test_skew_past_twice_the_balanced_load_walks_a_second_chunk(rng):
-    """The default chunk is twice a balanced router's rows; every token on
-    both experts of a quarter share is four times that: the `while_loop`
-    runs, forward and backward, and nothing is dropped."""
-    t, start, held = 1024, 2, 2
+def _against_the_loop(rng, t, start, held, bias, want_counts):
     x = jnp.asarray(rng.randn(t, H), jnp.float32)
     wr, wg, wu, wd = _weights(rng, held)
-    bias = jnp.asarray([0, 0, 10.0, 10, 0, 0, 0, 0], jnp.float32)
-    rc = rm.default_rows_per_chunk(t, K, held, E)
-    assert rc == 1536 < 2 * t
+    bias = jnp.asarray(bias, jnp.float32)
     y, sizes, counts = _mine(x, wr, bias, wg, wu, wd, start)
-    assert [int(n) for n in sizes] == [t, t]
-    assert [int(n) for n in counts] == [2 * t, 2 * rc, 0]
+    assert [int(n) for n in sizes] == [t] * held
+    assert [int(n) for n in counts] == want_counts
     np.testing.assert_allclose(y, _loop(x, wr, bias, wg, wu, wd, start),
                                atol=1e-4)
     got = jax.grad(lambda x, wg: (_mine(
@@ -110,6 +191,28 @@ def test_skew_past_twice_the_balanced_load_walks_a_second_chunk(rng):
     for g, w in zip(got, ref):
         np.testing.assert_allclose(g, w, atol=1e-5 * max(
             1.0, float(jnp.abs(w).max())))
+
+
+def test_skew_past_twice_the_balanced_load_walks_a_second_chunk(rng):
+    """The first chunk is 1.25 x a balanced router's rows and a later one
+    1 x; every token on both experts of a quarter share is four times
+    that: the `while_loop` runs twice, forward and backward, with chunks
+    of another size than the first, and nothing is dropped."""
+    t = 2048
+    first, later = rm.default_rows_per_chunk(t, K, 2, E)
+    assert (first, later) == (2048, 1536) and first + later < 2 * t
+    _against_the_loop(rng, t, 2, 2, [0, 0, 10.0, 10, 0, 0, 0, 0],
+                      [2 * t, first + 2 * later, 0])
+
+
+@pytest.mark.parametrize("first,later_run", [(2048, 0), (2040, 1)],
+                         ids=["on_the_last_row", "eight_rows_past"])
+def test_load_at_the_first_chunks_edge(rng, monkeypatch, first, later_run):
+    """2048 rows against a first chunk of exactly 2048: no later chunk
+    runs; against 2040, one of 512 does — forward and backward."""
+    monkeypatch.setattr(rm, "default_rows_per_chunk", lambda *a: (first, 512))
+    _against_the_loop(rng, 1024, 2, 2, [0, 0, 10.0, 10, 0, 0, 0, 0],
+                      [2048, first + later_run * 512, 0])
 
 
 def test_layer_shares_add_up_and_count_their_rows(rng):
