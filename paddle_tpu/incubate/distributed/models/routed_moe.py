@@ -1,6 +1,7 @@
-"""Routed experts as deployed: a sigmoid router over ALL the experts of the
-layer with several per token, a shared expert, and this chip's SHARE of the
-routed experts — no capacity, no dropped token (docs/MOE.md).
+"""Routed experts as deployed: a router over ALL the experts of the layer
+(sigmoid scores, or a softmax — `score_func`) with several per token, a
+shared expert where the model has one, and this chip's SHARE of the routed
+experts — no capacity, no dropped token (docs/MOE.md).
 
 The layer is told which experts it holds (`expert_start`, `num_held`),
 routes over all `num_experts`, and computes the part of the result that its
@@ -42,7 +43,8 @@ from ....nn.initializer import Normal
 from ....nn.layer_base import Layer
 from ....observability import metrics as _metrics
 
-__all__ = ["RoutedMoELayer", "sigmoid_topk_route", "sort_held",
+__all__ = ["RoutedMoELayer", "sigmoid_topk_route", "softmax_topk_route",
+           "sort_held",
            "grouped_experts", "default_rows_per_chunk", "row_counters"]
 
 # what the layer counted in its last step, one int32 vector a layer: rows
@@ -73,7 +75,33 @@ def sigmoid_topk_route(x, w_router, expert_bias, top_k, route_scale,
     logits = jax.lax.dot_general(x, w_router, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + expert_bias.astype(jnp.float32), top_k)
+    idx, w = _chosen(s, s + expert_bias.astype(jnp.float32), top_k,
+                     route_norm)
+    return idx, w * route_scale
+
+
+def softmax_topk_route(x, w_router, expert_bias, top_k, route_scale,
+                       route_norm=True):
+    """Scores `p = softmax(float32(x Wr))` over all experts; the `top_k`
+    of `p + expert_bias` are chosen; their weights are `p` at the chosen,
+    divided by their sum (`route_norm`, the Qwen3-MoE family's
+    `norm_topk_prob`), times `route_scale`.  Same arguments, result and
+    kept choice as `sigmoid_topk_route`."""
+    logits = jax.lax.dot_general(x, w_router, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(logits, axis=-1)
+    idx, w = _chosen(p, p + expert_bias.astype(jnp.float32), top_k,
+                     route_norm)
+    return idx, w * route_scale
+
+
+SCORE_FUNCS = ("sigmoid", "softmax")
+
+
+def _chosen(s, ranked, top_k, route_norm):
+    """(idx [T, k], w [T, k]): the `top_k` of `ranked`, and `s` there,
+    over their sum + 1e-20 under `route_norm`."""
+    _, idx = jax.lax.top_k(ranked, top_k)
     idx = _keep(idx.astype(jnp.int32), "moe_sort")
     # s at the chosen, as a masked sum over the experts: one term of a sum
     # is not zero and a token's choices are distinct, so value and
@@ -85,7 +113,7 @@ def sigmoid_topk_route(x, w_router, expert_bias, top_k, route_scale,
                 axis=1).T
     if route_norm:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
-    return idx, w * route_scale
+    return idx, w
 
 
 def sort_held(idx, expert_start, num_held, rows_pad):
@@ -278,14 +306,16 @@ grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def _routed_part(x, w_router, expert_bias, w_gate, w_up, w_down, *, top_k,
-                 route_scale, route_norm, expert_start):
+                 route_scale, route_norm, expert_start, score_func="sigmoid"):
     """The held experts' part of the layer's result for tokens x [T, H],
     and the counters: (y [T, H], sizes [num_held], counts [3])."""
     t = x.shape[0]
     num_held = w_gate.shape[0]
     with jax.named_scope("moe.route"):
-        idx, w = sigmoid_topk_route(x, w_router, expert_bias, top_k,
-                                    route_scale, route_norm)
+        route = (softmax_topk_route if score_func == "softmax"
+                 else sigmoid_topk_route)
+        idx, w = route(x, w_router, expert_bias, top_k, route_scale,
+                       route_norm)
     chunks = default_rows_per_chunk(t, top_k, num_held, w_router.shape[1])
     rows_pad = _rows_pad(t * top_k, *chunks)
     with jax.named_scope("moe.sort"):
@@ -315,8 +345,13 @@ class RoutedMoELayer(Layer):
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
                  num_held=None, expert_start=0, shared_width=None,
-                 route_scale=1.0, route_norm=True, initializer_range=0.02):
+                 route_scale=1.0, route_norm=True, initializer_range=0.02,
+                 score_func="sigmoid"):
         super().__init__()
+        if score_func not in SCORE_FUNCS:
+            raise ValueError(f"RoutedMoELayer: score_func {score_func!r} is "
+                             f"not one of {SCORE_FUNCS}")
+        self.score_func = score_func
         num_held = num_experts if num_held is None else num_held
         if num_held < 1 or not 0 <= expert_start <= num_experts - num_held:
             raise ValueError("RoutedMoELayer: the held experts "
@@ -351,10 +386,12 @@ class RoutedMoELayer(Layer):
         buffers: what a recomputed block calls (a buffer written inside
         `jax.checkpoint` would leak its tracer); `forward` adds them."""
         _metrics.inc("moe.dispatch", kernel="ragged_dot")
+        _metrics.inc("moe.route", score=self.score_func)
         if _keeping():
             _metrics.inc("moe.recompute_kept", what="out")
         kw = dict(top_k=self.top_k, route_scale=self.route_scale,
-                  route_norm=self.route_norm, expert_start=self.expert_start)
+                  route_norm=self.route_norm, expert_start=self.expert_start,
+                  score_func=self.score_func)
         shared = self.shared_gate is not None
 
         def f(xv, wr, bias, wg, wu, wd, *sh):

@@ -523,7 +523,12 @@ class GPTPretrainingCriterion(nn.Layer):
     [vocab, hidden] head weight
     through `model.fused_head_weight()` (GPT: the tied embedding;
     models/afmoe.py: an untied head) — the live parameter, so the train
-    step's bind_state makes it differentiable like any other param."""
+    step's bind_state makes it differentiable like any other param.
+
+    A second loss term: where the model given as model= has a method
+    `pop_aux_loss()` (models/keye.py: the sum of its layers' indexer
+    losses, registered by the forward pass that made `logits`), the
+    criterion takes that scalar and adds it to the token loss."""
 
     def __init__(self, ignore_index=-100, fused=True, model=None):
         super().__init__()
@@ -534,6 +539,12 @@ class GPTPretrainingCriterion(nn.Layer):
             model.fused_head_weight()   # refuses a model without one
 
     def forward(self, logits, labels):
+        loss = self._token_loss(logits, labels)
+        pop = getattr(self._model, "pop_aux_loss", None)
+        aux = pop() if pop is not None else None
+        return loss if aux is None else loss + aux
+
+    def _token_loss(self, logits, labels):
         lv = logits._value if hasattr(logits, "_value") else logits
         yv = labels._value if hasattr(labels, "_value") else labels
         is_hidden = getattr(logits, "name", None) == "fused_head_hidden"

@@ -15,6 +15,7 @@ from .layers_rnn import *  # noqa: F401,F403
 from . import utils  # noqa: F401
 from . import quant  # noqa: F401
 from .layers_transformer import *  # noqa: F401,F403
+from .layers_sparse_index import *  # noqa: F401,F403
 from ..core.tensor import Parameter  # noqa: F401
 
 

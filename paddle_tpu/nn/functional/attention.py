@@ -14,13 +14,16 @@ from __future__ import annotations
 import contextlib
 import math
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
 from ...core.dispatch import apply, op
 
 __all__ = [
-    "scaled_dot_product_attention", "flash_attention",
+    "scaled_dot_product_attention", "selected_attention",
+    "selected_attention_probs", "selected_attention_pairs", "flash_attention",
     "flash_attn_unpadded", "sdp_kernel",
     "fused_rotary_position_embedding", "apply_rotary_pos_emb",
 ]
@@ -129,6 +132,66 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                  attn_mask)
 
 
+def selected_attention(query, key, value, selected):
+    """Attention over a selected set of keys (learned sparse attention;
+    `F.sparse_attention` is the CSR-pattern API): query t of every head sees
+    the keys s with `selected[b, t, s]` != 0 ([B, T, T] int8, the causal
+    bound included — `F.sparse_select_topk` makes it from an indexer's
+    scores).  Inputs [batch, seq, heads, head_dim], grouped-query heads as
+    they are; the selection gets no gradient.  Returns (out, stats):
+    `stats` is what `selected_attention_probs` needs to follow the call.
+
+    The kernel follows from the arguments: the Pallas kernels of
+    `ops/pallas/sparse_attention.py` on the TPU (a dense causal pass that
+    masks), the jax.numpy form elsewhere (`sparse_attn.dispatch{kernel}`).
+    """
+    from ...observability import metrics as _obs_metrics
+    from ...ops.pallas import sparse_attention as _sp
+
+    def f(q, k, v, m):
+        if _sparse_pallas(q):
+            _obs_metrics.inc("sparse_attn.dispatch", kernel="pallas")
+            out, stats = _sp.sparse_attention(q, k, v, m)
+        else:
+            _obs_metrics.inc("sparse_attn.dispatch", kernel="reference")
+            out, stats = _sp.reference(q, k, v, m), (q, k)
+        return out, stats
+
+    return apply("selected_attention", f, query, key, value, selected)
+
+
+def _sparse_pallas(q):
+    from ...ops.pallas import sparse_attention as _sp
+
+    return _sdp_policy["flash"] and _sp.available(q)
+
+
+def selected_attention_probs(stats, selected):
+    """P [B, T, T] float32: the mean over the heads of the softmax over
+    the selected keys (0 elsewhere) of the `selected_attention` call that
+    returned `stats`, detached — the target of the indexer's loss."""
+    from ...ops.pallas import sparse_attention as _sp
+
+    def f(st, m):
+        if len(st) == 3:
+            return _sp.head_mean_probs(*st, m)
+        return _sp.reference_probs(*st, m)
+
+    return apply("selected_attention_probs", f, tuple(stats), selected)
+
+
+def selected_attention_pairs(query):
+    """(computed, causal): the (query, key) pairs `selected_attention`
+    multiplies for a call of `query`'s shape — all of the tiles its
+    kernel visits, selected or not — and the causal pairs."""
+    from ...ops.pallas import sparse_attention as _sp
+
+    b, t = query.shape[0], query.shape[1]
+    v = getattr(query, "_value", query)
+    computed = _sp.computed_pairs(b, t) if _sparse_pallas(v) else b * t * t
+    return computed, b * t * (t + 1) // 2
+
+
 def flash_attention(query, key, value, dropout=0.0, causal=False,
                     return_softmax=False, fixed_seed_offset=None, rng_name="",
                     training=True, name=None):
@@ -221,9 +284,15 @@ def _rope_rotate_interleaved(x):
 def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
                                     position_ids=None, use_neox_rotary_style=True,
                                     time_major=False, rotary_emb_base=10000.0,
-                                    name=None):
+                                    name=None, mrope_section=None):
     """q/k/v: [B, S, H, D]. Matches incubate.nn.functional.
-    fused_rotary_position_embedding semantics (fused_rope_kernel.cu)."""
+    fused_rotary_position_embedding semantics (fused_rope_kernel.cu).
+
+    mrope_section (multimodal rotary positions, with position_ids [3, B,
+    S] — temporal, height, width — and no sin/cos): the D/2 rotary pairs
+    are owned by the three rows in contiguous sections, e.g. (16, 24, 24):
+    pair i turns by the position of the row whose section holds i.  Three
+    equal rows are plain rotary positions."""
     if time_major:
         raise NotImplementedError(
             "fused_rotary_position_embedding: time_major=True ([S, B, ...]"
@@ -231,7 +300,22 @@ def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
     b, s, h, d = q.shape
     if sin is None or cos is None:
         inv = 1.0 / (rotary_emb_base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-        if position_ids is not None:
+        if mrope_section is not None:
+            if position_ids is None or jnp.ndim(position_ids) != 3 \
+                    or sum(mrope_section) != d // 2:
+                raise ValueError(
+                    "fused_rotary_position_embedding: mrope_section needs "
+                    f"position_ids [3, B, S] and sections that sum to {d // 2}")
+            pos = jnp.asarray(position_ids).astype(jnp.float32)  # [3, B, S]
+            row = np.repeat(np.arange(3), mrope_section)         # [D/2]
+            # the pair's own row of positions, as a select over the three
+            freqs = sum(jnp.where(row == r, pos[r][:, :, None] * inv, 0.0)
+                        for r in range(3))                       # [B,S,D/2]
+            emb = jnp.concatenate([freqs, freqs], axis=-1)
+            cos = jnp.cos(emb)[:, :, None, :]
+            sin = jnp.sin(emb)[:, :, None, :]
+            position_ids = None  # consumed
+        elif position_ids is not None:
             # explicit positions (decode offsets): build phases per position
             pos = jnp.asarray(position_ids).astype(jnp.float32)  # [B, S]
             freqs = pos[:, :, None] * inv[None, None, :]         # [B,S,D/2]

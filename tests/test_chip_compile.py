@@ -258,6 +258,43 @@ def test_grouped_expert_products_compile_to_the_chips_own_kernel(one_chip):
     assert spans and max(spans) <= max(first, later) < t * k
 
 
+def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(one_chip):
+    """keye-vl2-ep8.train.seq8192: 2 x 8192, 32:4 heads of 128, an indexer
+    of 16 heads of 64, topk 2048 — the index-score kernels (forward and
+    backward), the selection's search, the indexer's loss, the masked
+    grouped-query attention
+    (forward, dq, dkdv) and the head-mean probabilities, at the default
+    blocks, through the chip's compiler.  The indexer goes through its
+    functional entries: each must take its kernel when compiled for the
+    chip (a predicate on the wrong shape once sent two of them to their
+    jax.numpy forms in the whole model)."""
+    from paddle_tpu.nn.functional import sparse_index as sx   # the entries
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+
+    b, t, h, hkv, d, j, di = 2, 8192, 32, 4, 128, 16, 64
+    bf = jnp.bfloat16
+
+    def step(q, k, v, qi, ki, w, g):
+        def loss(q, k, v, qi, ki, w):
+            scores = sx.index_scores(qi, ki, w)
+            mask, _ = sx.select_topk(jax.lax.stop_gradient(scores), 2048)
+            out, (qt, kt, lse) = sa.sparse_attention(q, k, v, mask)
+            probs = sa.head_mean_probs(qt, kt, lse, mask)
+            return ((out.astype(jnp.float32) * g).sum()
+                    + sx.indexer_loss(scores, mask, probs))
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))(q, k, v, qi, ki, w)
+
+    text = _compile(one_chip, step, ((b, t, h, d), bf), ((b, t, hkv, d), bf),
+                    ((b, t, hkv, d), bf), ((b, t, j, di), bf),
+                    ((b, t, di), bf), ((b, t, j), bf),
+                    ((b, t, h, d), jnp.float32))
+    for kernel in ("sparse_index_fwd", "sparse_index_dq", "sparse_index_dk",
+                   "sparse_index_select", "sparse_index_loss_fwd",
+                   "sparse_index_loss_bwd", "sparse_attn_fwd", "sparse_attn_dq",
+                   "sparse_attn_dkdv", "sparse_attn_probs"):
+        assert kernel in text, kernel
+
+
 def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,
                                                   monkeypatch):
     """A Mosaic kernel inside a multi-device program is refused at

@@ -251,3 +251,75 @@ def test_layer_shares_add_up_and_count_their_rows(rng):
     np.testing.assert_allclose(total + shared, want, atol=2e-5)
     with pytest.raises(ValueError, match="held experts"):
         layer(4, 6)
+
+
+@pytest.mark.parametrize("score,norm", [("softmax", True), ("softmax", False),
+                                        ("sigmoid", True), ("sigmoid", False)])
+def test_route_is_the_plain_form_of_its_score_function(rng, score, norm):
+    """Both routes against their plain forms (`take_along_axis` over the
+    scores): the same choice, the same weights and the same gradients; the
+    softmax route's weights sum to one under `route_norm` and its scores
+    over ALL experts do without it."""
+    x = jnp.asarray(rng.randn(T, H), jnp.float32)
+    wr = jnp.asarray(rng.randn(H, E) * 0.5, jnp.float32)
+    bias = jnp.asarray(rng.randn(E) * 0.1, jnp.float32)
+    dw = jnp.asarray(rng.randn(T, K), jnp.float32)
+    mine = rm.softmax_topk_route if score == "softmax" else rm.sigmoid_topk_route
+
+    def plain(x, wr):
+        logits = jax.lax.dot_general(x, wr, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        s = (jax.nn.softmax(logits, -1) if score == "softmax"
+             else jax.nn.sigmoid(logits))
+        _, idx = jax.lax.top_k(s + bias, K)
+        w = jnp.take_along_axis(s, idx, axis=1)
+        if norm:
+            w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+        return idx, w * SCALE
+
+    idx, w = mine(x, wr, bias, K, SCALE, norm)
+    want_idx, want_w = plain(x, wr)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(want_w))
+    if score == "softmax" and norm:
+        np.testing.assert_allclose(w.sum(1), SCALE, rtol=1e-6)
+    grads = lambda route: jax.grad(
+        lambda x, wr: (route(x, wr)[1] * dw).sum(), argnums=(0, 1))(x, wr)
+    _assert_same_to_the_bit(grads(lambda x, wr: mine(x, wr, bias, K, SCALE,
+                                                     norm)), grads(plain))
+
+
+def test_layer_routes_by_its_score_func(rng):
+    """`score_func` picks the route from the layer's arguments; the
+    sigmoid layer is the default and an unknown name is refused."""
+    import paddle_tpu as P
+
+    x = P.to_tensor(rng.randn(4, 24, H).astype("float32"))
+    layers = {s: rm.RoutedMoELayer(H, F, E, K, score_func=s)
+              for s in rm.SCORE_FUNCS}
+    assert rm.RoutedMoELayer(H, F, E, K).score_func == "sigmoid"
+    for name, value in zip(("router", "w_gate", "w_up", "w_down"),
+                           _weights(rng, E)):
+        for l in layers.values():
+            getattr(l, name)._value = value
+    outs = {s: np.asarray(l(x)._value) for s, l in layers.items()}
+    assert np.abs(outs["softmax"] - outs["sigmoid"]).max() > 1e-4
+    xv = x._value.reshape(-1, H)
+    l = layers["softmax"]
+    want = _loop_softmax(xv, l.router._value, l.w_gate._value, l.w_up._value,
+                         l.w_down._value)
+    np.testing.assert_allclose(outs["softmax"].reshape(-1, H), want, atol=1e-5)
+    with pytest.raises(ValueError, match="score_func"):
+        rm.RoutedMoELayer(H, F, E, K, score_func="tanh")
+
+
+def _loop_softmax(x, wr, wg, wu, wd):
+    p = jax.nn.softmax(x @ wr, -1)
+    _, idx = jax.lax.top_k(p, K)
+    w = jnp.take_along_axis(p, idx, 1)
+    w = w / w.sum(1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(wg.shape[0]):
+        we = jnp.sum(jnp.where(idx == e, w, 0.0), 1)
+        y = y + we[:, None] * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+    return y
