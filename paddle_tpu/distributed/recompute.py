@@ -16,9 +16,12 @@ cores' output and log-sum-exp — their VJP's residuals, so the backward
 does not run the flash forward kernel a second time — and the routed
 expert layer's result, which spares the replay the whole grouped forward
 (its VJP's residuals are its inputs), with the router's choice and the
-sort's small integer products (no `top_k`, no argsort in the replay), and
+sort's small integer products (no `top_k`, no argsort in the replay),
 learned sparse attention's selection (the [B, T, T] int8 mask: the replay
-runs no search over the index scores).
+runs no search over the index scores), and the gradient of the indexer's
+loss by the index scores (`indexer_grad`, [B, T, T] float32, formed in the
+loss's forward rule as its only residual: the replay makes neither the index
+scores nor the attention's head-mean probabilities nor the loss again).
 Projections, norms, rotary, layout swaps and the router's scores are
 replayed.  `policy="full"` replays everything
 (the last bytes: nothing but the segment's input is held); `"dots"` and
@@ -41,7 +44,8 @@ from ..core.tensor import Tensor
 __all__ = ["recompute", "recompute_sequential", "keep", "keeping", "KEPT"]
 
 # the names `keep()` takes: what the default policy holds across a replay
-KEPT = ("flash_out", "flash_lse", "moe_out", "moe_sort", "sparse_select")
+KEPT = ("flash_out", "flash_lse", "moe_out", "moe_sort", "sparse_select",
+        "indexer_grad")
 
 # Named rematerialization policies (the TPU memory/FLOPs dial — SURVEY §7
 # hard part (c)). None keeps the marked values (above) and replays the

@@ -28,9 +28,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.dispatch import apply
-from ...distributed.recompute import keep as _keep
+from ...distributed.recompute import keep as _keep, keeping as _keeping
 
 __all__ = ["sparse_index_scores", "sparse_select_topk", "sparse_indexer_loss",
            "selected_pairs"]
@@ -141,24 +142,70 @@ def indexer_loss(scores, mask, probs):
     """mean over the rows of sum over the selected keys of
     P (log P - log softmax_selected(I)); P is taken as given (detached).
     On the TPU a row's reductions run on the row in VMEM
-    (`ops/pallas/sparse_index.py::indexer_loss`)."""
+    (`ops/pallas/sparse_index.py::indexer_loss`).
+
+    The gradient is formed in the forward pass.  By the scores a row's KL
+    has the closed form `d = softmax_selected(I) * sum(P) - P` on the
+    selected keys, which wants nothing the backward pass brings but a
+    scalar: the differentiated call makes `d` [B, T, T] float32 where the
+    row is already read and holds it as its ONLY residual, marked
+    `indexer_grad` (`distributed/recompute.py`).  A recomputed block then
+    replays neither the index-score forward nor the attention's head-mean
+    probabilities nor this loss: nothing in its backward pass reads them
+    (`sparse_index.recompute_kept{what=loss_grad}`, one a call whose
+    segment holds the marks).  An undifferentiated call writes no
+    [B, T, T] array."""
+    from ...observability import metrics as _metrics
     from ...ops.pallas import sparse_index as _kernels
 
-    if _took_kernel("loss", _kernels.on_tpu()):
-        return _kernels.indexer_loss(scores, mask,
-                                     jax.lax.stop_gradient(probs))
-    return _indexer_loss_rows(scores, mask, probs)
+    _took_kernel("loss", _kernels.on_tpu())
+    if _keeping():
+        _metrics.inc("sparse_index.recompute_kept", what="loss_grad")
+    return _loss(scores, mask,
+                 jax.lax.stop_gradient(probs).astype(scores.dtype))
 
 
-def _indexer_loss_rows(scores, mask, probs):
+def _loss_and_grad(scores, mask, probs, diff):
+    """(the loss, `d` where `diff` and None otherwise), by the form the
+    backend takes."""
+    from ...ops.pallas import sparse_index as _kernels
+
+    if _kernels.on_tpu():
+        return _kernels.indexer_loss(scores, mask, probs, diff)
+    return _indexer_loss_rows(scores, mask, probs, diff)
+
+
+def _indexer_loss_rows(scores, mask, probs, diff=False):
     sel = mask > 0
-    probs = jax.lax.stop_gradient(probs)
-    lse = jax.nn.logsumexp(jnp.where(sel, scores, -jnp.inf), axis=-1,
-                           keepdims=True)
+    masked = jnp.where(sel, scores, -jnp.inf)
+    lse = jax.nn.logsumexp(masked, axis=-1, keepdims=True)
     live = sel & (probs > 0)
     kl = jnp.where(live, probs * (jnp.log(jnp.where(live, probs, 1.0))
                                   - (scores - lse)), 0.0)
-    return jnp.sum(kl) / (scores.shape[0] * scores.shape[1])
+    value = jnp.sum(kl) / (scores.shape[0] * scores.shape[1])
+    if not diff:
+        return value, None
+    ps = jnp.sum(jnp.where(sel, probs, 0.0), axis=-1, keepdims=True)
+    return value, jnp.where(sel, jnp.exp(masked - lse) * ps - probs, 0.0)
+
+
+@jax.custom_vjp
+def _loss(scores, mask, probs):
+    return _loss_and_grad(scores, mask, probs, False)[0]
+
+
+def _loss_fwd(scores, mask, probs):
+    value, d = _loss_and_grad(scores, mask, probs, True)
+    return value, _keep(d, "indexer_grad")
+
+
+def _loss_bwd(d, g):
+    b, t, _ = d.shape
+    return (d * (g / (b * t)), np.zeros(d.shape, jax.dtypes.float0),
+            jnp.zeros_like(d))
+
+
+_loss.defvjp(_loss_fwd, _loss_bwd)
 
 
 # --- ops ---------------------------------------------------------------------

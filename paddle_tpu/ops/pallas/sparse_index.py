@@ -22,10 +22,13 @@ the key's position among the ties at the threshold — run on it there,
 where the jax.numpy form reads the [B, T, T] array 46 times from HBM.
 
 `indexer_loss` is the indexer's KL on the same blocks of rows: a row's
-log-sum-exp over its selected scores, its KL and — in the backward — the
-gradient `softmax_selected(I) * sum(P) - P` are each one pass over the row in
-VMEM (XLA's row reductions over the masked [B, T, T] arrays took 13 ms a
-pass on the chip).
+log-sum-exp over its selected scores, its KL and — where the call is
+differentiated — the gradient `softmax_selected(I) * sum(P) - P` are ONE
+visit of the row in VMEM (XLA's row reductions over the masked [B, T, T]
+arrays took 13 ms a pass on the chip).  The gradient is formed in the
+forward pass because it is the one array the backward pass wants: kept
+across a block's recomputation, it spares the replay the index scores, `P`
+and this kernel.
 
 Off the TPU `nn/functional/sparse_index.py` computes the same values in
 jax.numpy.
@@ -327,7 +330,7 @@ def _wide(col):
     return jnp.broadcast_to(col, (col.shape[0], 128))
 
 
-def _loss_fwd_kernel(s_ref, m_ref, p_ref, kl_ref, lse_ref, ps_ref):
+def _loss_kernel(s_ref, m_ref, p_ref, kl_ref, d_ref=None):
     x, p = s_ref[...], p_ref[...]
     sel = m_ref[...].astype(jnp.float32) > 0.0
     xm = jnp.where(sel, x, -1e30)
@@ -338,61 +341,33 @@ def _loss_fwd_kernel(s_ref, m_ref, p_ref, kl_ref, lse_ref, ps_ref):
     kl = jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - (x - lse)),
                    0.0)
     kl_ref[...] = _wide(jnp.sum(kl, axis=1, keepdims=True))
-    lse_ref[...] = _wide(lse)
-    ps_ref[...] = _wide(jnp.sum(jnp.where(sel, p, 0.0), axis=1,
-                                keepdims=True))
+    if d_ref is not None:
+        ps = jnp.sum(jnp.where(sel, p, 0.0), axis=1, keepdims=True)
+        d_ref[...] = jnp.where(sel, jnp.exp(xm - lse) * ps - p, 0.0)
 
 
-def _loss_bwd_kernel(s_ref, m_ref, p_ref, lse_ref, ps_ref, d_ref):
-    sel = m_ref[...].astype(jnp.float32) > 0.0
-    soft = jnp.exp(jnp.where(sel, s_ref[...], -1e30) - lse_ref[:, 0:1])
-    d_ref[...] = jnp.where(sel, soft * ps_ref[:, 0:1] - p_ref[...], 0.0)
-
-
-def _loss_specs(t, rows):
+def indexer_loss(scores, mask, probs, diff=False):
+    """(mean over the rows of sum over the selected keys of P (log P - log
+    softmax_selected(I)), d); scores, probs [B, T, T] float32, mask int8.
+    `d` [B, T, T] float32 is the rows' gradient by the scores,
+    `softmax_selected(I) * sum(P) - P` on the selected keys and 0 elsewhere
+    (the mean's 1 / (B * T) left out), written on the same visit of the
+    row where `diff` is set; None otherwise, and then no [B, T, T] array
+    is written (`nn/functional/sparse_index.py::indexer_loss` has the
+    rule that keeps `d`)."""
+    b, t, _ = scores.shape
+    rows = min(SELECT_ROWS, t)
     row = pl.BlockSpec((None, rows, t), lambda bi, ri: (bi, ri, 0))
     stat = pl.BlockSpec((None, rows, 128), lambda bi, ri: (bi, ri, 0))
-    return row, stat
-
-
-@jax.custom_vjp
-def indexer_loss(scores, mask, probs):
-    """mean over the rows of sum over the selected keys of P (log P - log
-    softmax_selected(I)); scores, probs [B, T, T] float32, mask int8.  The
-    gradient reaches the scores only."""
-    return _loss_fwd(scores, mask, probs)[0]
-
-
-def _loss_fwd(scores, mask, probs):
-    b, t, _ = scores.shape
-    rows = min(SELECT_ROWS, t)
-    row, stat = _loss_specs(t, rows)
-    shape = jax.ShapeDtypeStruct((b, t, 128), jnp.float32)
-    kl, lse, ps = pl.pallas_call(
-        _loss_fwd_kernel, grid=(b, t // rows), in_specs=[row, row, row],
-        out_specs=[stat, stat, stat], out_shape=[shape, shape, shape],
+    out_specs = [stat]
+    out_shape = [jax.ShapeDtypeStruct((b, t, 128), jnp.float32)]
+    if diff:
+        out_specs.append(row)
+        out_shape.append(jax.ShapeDtypeStruct(scores.shape, jnp.float32))
+    kl, *d = pl.pallas_call(
+        _loss_kernel, grid=(b, t // rows), in_specs=[row, row, row],
+        out_specs=out_specs, out_shape=out_shape,
         interpret=_interpret(), compiler_params=_params((_PLL, _PLL)),
-        name="sparse_index_loss_fwd",
+        name=_fwd_name("sparse_index_loss", diff),
     )(scores, mask, probs)
-    return jnp.sum(kl[:, :, 0]) / (b * t), (scores, mask, probs, lse, ps)
-
-
-def _loss_bwd(res, g):
-    import numpy as np
-
-    scores, mask, probs, lse, ps = res
-    b, t, _ = scores.shape
-    rows = min(SELECT_ROWS, t)
-    row, stat = _loss_specs(t, rows)
-    d = pl.pallas_call(
-        _loss_bwd_kernel, grid=(b, t // rows),
-        in_specs=[row, row, row, stat, stat], out_specs=row,
-        out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.float32),
-        interpret=_interpret(), compiler_params=_params((_PLL, _PLL)),
-        name="sparse_index_loss_bwd",
-    )(scores, mask, probs, lse, ps)
-    return (d * (g / (b * t)), np.zeros(mask.shape, jax.dtypes.float0),
-            jnp.zeros_like(probs))
-
-
-indexer_loss.defvjp(_loss_fwd, _loss_bwd)
+    return jnp.sum(kl[:, :, 0]) / (b * t), (d[0] if diff else None)

@@ -258,41 +258,63 @@ def test_grouped_expert_products_compile_to_the_chips_own_kernel(one_chip):
     assert spans and max(spans) <= max(first, later) < t * k
 
 
-def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(one_chip):
+@pytest.mark.parametrize("recomputed", [False, True],
+                         ids=["plain", "recomputed"])
+def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(
+        one_chip, recomputed):
     """keye-vl2-ep8.train.seq8192: 2 x 8192, 32:4 heads of 128, an indexer
     of 16 heads of 64, topk 2048 — the index-score kernels (forward and
-    backward), the selection's search, the indexer's loss, the masked
-    grouped-query attention
-    (forward, dq, dkdv) and the head-mean probabilities, at the default
-    blocks, through the chip's compiler.  The indexer goes through its
-    functional entries: each must take its kernel when compiled for the
-    chip (a predicate on the wrong shape once sent two of them to their
-    jax.numpy forms in the whole model)."""
-    from paddle_tpu.nn.functional import sparse_index as sx   # the entries
-    from paddle_tpu.ops.pallas import sparse_attention as sa
+    backward), the selection's search, the indexer's loss (ONE kernel: the
+    value and the gradient by the scores), the masked grouped-query
+    attention (forward, dq, dkdv) and the head-mean probabilities, at the
+    default blocks, through the chip's compiler.  The layer goes through
+    its functional entries: each must take its kernel when compiled for
+    the chip (a predicate on the wrong shape once sent two of them to
+    their jax.numpy forms in the whole model).  Every kernel stands ONCE
+    in the compiled gradient, also where the layer is recomputed: what its
+    backward pass wants of the forward kernels is kept
+    (`distributed/recompute.py`), so the replay runs none of them."""
+    import importlib
+    import re
 
+    import paddle_tpu as P
+    from paddle_tpu.core import flags
+    from paddle_tpu.nn import functional as F
+
+    rc = importlib.import_module("paddle_tpu.distributed.recompute")
     b, t, h, hkv, d, j, di = 2, 8192, 32, 4, 128, 16, 64
     bf = jnp.bfloat16
 
-    def step(q, k, v, qi, ki, w, g):
-        def loss(q, k, v, qi, ki, w):
-            scores = sx.index_scores(qi, ki, w)
-            mask, _ = sx.select_topk(jax.lax.stop_gradient(scores), 2048)
-            out, (qt, kt, lse) = sa.sparse_attention(q, k, v, mask)
-            probs = sa.head_mean_probs(qt, kt, lse, mask)
-            return ((out.astype(jnp.float32) * g).sum()
-                    + sx.indexer_loss(scores, mask, probs))
-        return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))(q, k, v, qi, ki, w)
+    def layer(q, k, v, qi, ki, w):
+        scores = F.sparse_index_scores(qi, ki, w)
+        mask, _ = F.sparse_select_topk(scores, 2048)
+        out, stats = F.selected_attention(q, k, v, mask)
+        probs = F.selected_attention_probs(stats, mask)
+        return out, F.sparse_indexer_loss(scores, mask, probs)
 
-    text = _compile(one_chip, step, ((b, t, h, d), bf), ((b, t, hkv, d), bf),
+    def step(g, *args):
+        def loss(*args):
+            with flags.trace_guard():
+                tensors = [P.to_tensor(a) for a in args]
+                for x in tensors:
+                    x.stop_gradient = False
+                out, aux = (rc.recompute(layer, *tensors) if recomputed
+                            else layer(*tensors))
+            return (out._value.astype(jnp.float32) * g).sum() + aux._value
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))(*args)
+
+    text = _compile(one_chip, step, ((b, t, h, d), jnp.float32),
+                    ((b, t, h, d), bf), ((b, t, hkv, d), bf),
                     ((b, t, hkv, d), bf), ((b, t, j, di), bf),
-                    ((b, t, di), bf), ((b, t, j), bf),
-                    ((b, t, h, d), jnp.float32))
+                    ((b, t, di), bf), ((b, t, j), bf))
+    # XLA names a Mosaic call after its kernel (`%jvp_sparse_index_fwd_.1`)
+    calls = re.findall(r"(%[\w.\-]+) = [^\n]*tpu_custom_call", text)
     for kernel in ("sparse_index_fwd", "sparse_index_dq", "sparse_index_dk",
-                   "sparse_index_select", "sparse_index_loss_fwd",
-                   "sparse_index_loss_bwd", "sparse_attn_fwd", "sparse_attn_dq",
-                   "sparse_attn_dkdv", "sparse_attn_probs"):
-        assert kernel in text, kernel
+                   "sparse_index_select", "sparse_index_loss",
+                   "sparse_attn_fwd", "sparse_attn_dq", "sparse_attn_dkdv",
+                   "sparse_attn_probs"):
+        assert sum(kernel in c for c in calls) == 1, kernel
+    assert len(calls) == 9 and "sparse_index_loss_bwd" not in text
 
 
 def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,

@@ -259,9 +259,23 @@ def test_eight_shares_add_up_to_the_uncut_layer(bench):
     assert float(jnp.abs(parts[0]).max()) > 1e-4
 
 
-def _grad_program(recompute, policy=None, monkeypatch=None):
+_PROGRAMS = {}
+
+
+def _grad_program(recompute, policy=None, monkeypatch=None, kernels=False):
     """(text of the tiny step's gradient jaxpr, the trace-time counters it
-    added)."""
+    added, the loss, the gradients), made once a session.  With `kernels`
+    the sparse entries take their Pallas forms (through the interpreter) as
+    on the chip, and the program is traced, not run: no loss, no
+    gradients."""
+    key = (recompute, policy, kernels)
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = _make_grad_program(recompute, policy, monkeypatch,
+                                            kernels)
+    return _PROGRAMS[key]
+
+
+def _make_grad_program(recompute, policy, monkeypatch, kernels):
     import functools
 
     import paddle_tpu as P
@@ -274,6 +288,12 @@ def _grad_program(recompute, policy=None, monkeypatch=None):
     if policy:
         monkeypatch.setattr(keye, "_recompute",
                             functools.partial(rc.recompute, policy=policy))
+    if kernels:
+        from paddle_tpu.ops.pallas import sparse_attention as sa
+        from paddle_tpu.ops.pallas import sparse_index as sx
+
+        monkeypatch.setattr(sa, "available", lambda q: True)
+        monkeypatch.setattr(sx, "on_tpu", lambda: True)
     P.seed(0)
     model = keye.KeyeForCausalLM(keye.keye_tiny(
         recompute=recompute, fused_head_ce=True))
@@ -292,14 +312,15 @@ def _grad_program(recompute, policy=None, monkeypatch=None):
     try:
         before = dict(metrics.snapshot()["counters"])
         text = str(jax.make_jaxpr(jax.grad(loss))(params))
-        value, grads = jax.value_and_grad(loss)(params)
+        value, grads = (None, None) if kernels \
+            else jax.value_and_grad(loss)(params)
         now = metrics.snapshot()["counters"]
     finally:
         if not was:
             metrics.disable()
     added = {k: v - before.get(k, 0) for k, v in now.items()
              if v - before.get(k, 0)}
-    return text, added, float(value), grads
+    return text, added, value if kernels else float(value), grads
 
 
 def test_selection_is_kept_under_recomputation(monkeypatch):
@@ -327,6 +348,54 @@ def test_selection_is_kept_under_recomputation(monkeypatch):
     for n, g in grads.items():
         np.testing.assert_allclose(np.asarray(g), np.asarray(grads_off[n]),
                                    atol=1e-6, err_msg=n)
+
+
+def test_indexer_loss_gradient_is_kept_under_recomputation(monkeypatch):
+    """keye_tiny, 2 layers: the indexer's loss forms its gradient by the
+    scores in its forward rule and keeps it (`indexer_grad`), so under the
+    default policy the index-score forward, the head-mean probabilities and
+    the loss stand ONCE a layer in the gradient's program — as without
+    recomputation — and twice under "full".  Read on the kernels' names
+    (the Pallas forms through the interpreter, as the chip's trace names
+    them) and, in the jax.numpy forms the CPU runs, on the index scores'
+    `relu`; the mark and the counter say so; loss and gradients do not
+    move."""
+    names = ("jvp(sparse_index_fwd)", "sparse_attn_probs",
+             "jvp(sparse_index_loss)", "jvp(sparse_attn_fwd)")
+    calls = lambda text: [len(re.findall(rf"name={re.escape(n)}(?!\w)", text))
+                          for n in names]
+    (kept, added, _, _), (off, added_off, _, _), (full, added_full, _, _) = (
+        _grad_program(*mode, monkeypatch, kernels=True)
+        for mode in ((True, None), (False, None), (True, "full")))
+    assert calls(off) == calls(kept) == [2, 2, 2, 2]
+    assert calls(full) == [4, 4, 4, 4]
+    for text in (kept, off, full):           # the backward's own: once
+        for n in ("sparse_index_dq", "sparse_index_dk", "sparse_attn_dq",
+                  "sparse_attn_dkdv"):
+            assert len(re.findall(rf"name=transpose\(jvp\({n}\)\)",
+                                  text)) == 2, n
+        assert "sparse_index_loss_bwd" not in text
+        assert "name=indexer_grad]" in text  # only a policy reads the mark
+    counter = "sparse_index.recompute_kept{what=loss_grad}"
+    assert added[counter] == 2               # traced once: 2 layers
+    assert counter not in added_off and counter not in added_full
+    assert added["sparse_index.dispatch{kernel=pallas,op=loss}"] == 2
+    assert not any("kernel=reference" in k for k in added)
+    # the jax.numpy forms: the same programs as the CPU runs them
+    relus = lambda text: len(re.findall(r"name=relu\b", text))
+    text_off, jnp_off, value_off, grads_off = _grad_program(False)
+    text_kept, jnp_kept, value, grads = _grad_program(True)
+    text_full, jnp_full, value_full, grads_full = _grad_program(
+        True, "full", monkeypatch)
+    assert relus(text_off) == relus(text_kept) < relus(text_full)
+    assert "name=indexer_grad]" in text_kept
+    assert jnp_kept[counter] == 4            # the jaxpr, then the gradient
+    assert counter not in jnp_off and counter not in jnp_full
+    assert value == value_off == value_full
+    for n, g in grads.items():
+        for other in (grads_off, grads_full):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(other[n]),
+                                       atol=1e-6, err_msg=n)
 
 
 @pytest.mark.parametrize("blocks", [(64, 128), (32, 64)])
@@ -399,12 +468,51 @@ def test_index_kernels_match_the_jnp_form():
     mask = si._select_topk_passes(got, 32)
     probs = jax.nn.softmax(jnp.where(mask > 0, g, -jnp.inf), -1)
     probs = jnp.where(probs < 1e-3, 0.0, probs)          # some exact zeros
-    value, grad = jax.value_and_grad(
-        lambda s: 3.0 * sx.indexer_loss(s, mask, probs))(got)
-    want_value, want_grad = jax.value_and_grad(
-        lambda s: 3.0 * si._indexer_loss_rows(s, mask, probs))(got)
+    probs = probs.at[:, 7].set(0.0)                      # and a whole row
+    want_value, want_d = jax.value_and_grad(
+        lambda s: si._indexer_loss_rows(s, mask, probs)[0])(got)
+    want_d = want_d * (b * t)                            # the mean undone
+    # the fused kernel: the value and `d` on one visit of the row
+    value, d = sx.indexer_loss(got, mask, probs, True)
+    assert d.dtype == jnp.float32 and d.shape == got.shape
     np.testing.assert_allclose(value, want_value, rtol=1e-6)
-    np.testing.assert_allclose(grad, want_grad, atol=1e-7)
+    np.testing.assert_allclose(d, want_d, atol=1e-7 * b * t)
+    assert not np.asarray(d)[np.asarray(mask) == 0].any()
+    # the jax.numpy form's closed-form `d` is autodiff's
+    value, d = si._indexer_loss_rows(got, mask, probs, True)
+    np.testing.assert_allclose(value, want_value, rtol=1e-6)
+    np.testing.assert_allclose(d, want_d, atol=1e-7 * b * t)
+    # the rule of the functional entry over either form
+    for on_tpu in (True, False):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(sx, "on_tpu", lambda: on_tpu)
+            value, grad = jax.value_and_grad(
+                lambda s: 3.0 * si.indexer_loss(s, mask, probs))(got)
+            np.testing.assert_allclose(value, 3.0 * want_value, rtol=1e-6)
+            np.testing.assert_allclose(grad, 3.0 * want_d / (b * t),
+                                       atol=3e-7)
+            plain = jax.make_jaxpr(si.indexer_loss)(got, mask, probs)
+            assert "indexer_grad" not in str(plain)
+    # undifferentiated (evaluation): the statistics only, no [B, T, T] output
+    value, d = sx.indexer_loss(got, mask, probs)
+    assert d is None
+    np.testing.assert_allclose(value, want_value, rtol=1e-6)
+    written = lambda diff: [v.aval.shape for v in _pallas_calls(jax.make_jaxpr(
+        lambda *a: sx.indexer_loss(*a, diff)[0])(got, mask, probs).jaxpr)[0]
+        .outvars]
+    assert written(False) == [(b, t, 128)]
+    assert written(True) == [(b, t, 128), (b, t, t)]
+
+
+def _pallas_calls(jaxpr):
+    """The pallas_call equations of a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
 
 
 def test_cost_keye_by_hand():

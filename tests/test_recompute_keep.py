@@ -23,6 +23,8 @@ rc = importlib.import_module("paddle_tpu.distributed.recompute")
 afmoe = importlib.import_module("paddle_tpu.models.afmoe")
 
 MODES = (False, True, "full")
+# what only learned sparse attention marks (models/keye.py)
+SPARSE_MARKS = ("sparse_select", "indexer_grad")
 KEPT_COUNTERS = ("flash.recompute_kept{what=out_lse}",
                  "moe.recompute_kept{what=out}")
 
@@ -133,16 +135,21 @@ def test_backward_replays_neither_flash_nor_grouped_forward(flash_on,
     assert ragged_full == ragged + 6 * expert_layers
     assert sorts_full == 2 * sorts
     # the marks are in every program; only a policy reads them (this
-    # model marks no selection: tests/test_keye.py has that one)
+    # model marks no selection and no indexer's gradient: tests/test_keye.py
+    # has those two)
     for t in (text, text_off, text_full):
-        assert all(f"name={n}]" in t for n in rc.KEPT if n != "sparse_select")
+        for n in rc.KEPT:
+            assert (f"name={n}]" in t) == (n not in SPARSE_MARKS), n
     assert kept == dict(zip(KEPT_COUNTERS, (layers, expert_layers)))
     assert kept_off == kept_full == dict.fromkeys(KEPT_COUNTERS, 0)
 
 
 def test_keep_takes_only_the_modules_names():
+    assert rc.KEPT == ("flash_out", "flash_lse", "moe_out", "moe_sort",
+                       *SPARSE_MARKS)
     x = jax.numpy.ones((2,))
-    assert rc.keep(x, "flash_out") is x      # outside a trace: an identity
+    for name in rc.KEPT:
+        assert rc.keep(x, name) is x         # outside a trace: an identity
     with pytest.raises(ValueError, match="not one of"):
         rc.keep(x, "anything_else")
     assert not rc.keeping()
