@@ -243,6 +243,84 @@ def test_process_compile_totals_count_every_program_once():
     assert xla_cost.process_compile_totals(until=0.0)["compile_n"] == 0
 
 
+@pytest.fixture
+def cache_dir(tmp_path):
+    """JAX's persistent compilation cache at a directory of this test's
+    own, every compile worth storing; the session's settings back after."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path), True, 0.0, -1)):
+        jax.config.update(k, v)
+    cc.reset_cache()
+    yield tmp_path
+    for k, v in was.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_ledger_says_whether_a_compile_was_a_compile(cache_dir):
+    """The same program twice against an empty cache directory: compiled
+    and stored, then loaded — on the compile's own record and in the
+    process totals, on the timeline `until=` reads."""
+    metrics.enable()
+    args = (jnp.ones((24, 24)), jnp.ones((6, 24)))    # their own programs
+    time.sleep(0.15)                       # `until` answers to within 0.1 s
+    before = xla_cost.process_compile_totals()
+    t0 = time.perf_counter()
+    for _ in range(2):                     # a new wrapper: nothing in memory
+        xla_cost.instrument(_scoped_step(), "ledger_cache")(*args)
+    entry = xla_cost.program_ledger("ledger_cache")
+    assert [c["cache"] for c in entry["compiles"]] == ["miss", "hit"]
+    assert os.listdir(cache_dir)           # the miss was written
+    after = xla_cost.process_compile_totals()
+    d = {k: after[k] - before[k] for k in after}
+    assert d["cache_requests"] == 2 and d["cache_hits"] == 1
+    assert d["cache_writes"] == 1
+    assert d["cache_retrieval_ms"] > 0 and "cache_saved_ms" in d
+    then = xla_cost.process_compile_totals(until=t0)
+    assert then["cache_requests"] <= before["cache_requests"]
+    assert then["cache_hits"] <= before["cache_hits"]
+
+
+def test_ledger_cache_is_none_where_no_cache_was_asked():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    metrics.enable()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        xla_cost.instrument(_scoped_step(), "ledger_nocache")(
+            jnp.ones((16, 16)), jnp.ones((4, 16)))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    entry = xla_cost.program_ledger("ledger_nocache")
+    assert entry["compiles"][0]["cache"] is None
+
+
+def test_ledger_memory_is_the_latest_compiles():
+    metrics.enable()
+    inst = xla_cost.instrument(_scoped_step(), "ledger_memory")
+    inst(jnp.ones((16, 16)), jnp.ones((4, 16)))
+    small = xla_cost.program_ledger("ledger_memory")["memory"]
+    assert set(small) <= {"argument_bytes", "output_bytes", "alias_bytes",
+                          "temp_bytes", "code_bytes"}
+    assert small["argument_bytes"] == (16 * 16 + 4 * 16) * 4
+    inst(jnp.ones((16, 16)), jnp.ones((64, 16)))      # a second signature
+    entry = xla_cost.program_ledger("ledger_memory")
+    assert entry["memory"]["argument_bytes"] == (16 * 16 + 64 * 16) * 4
+    assert entry["bytes"]["peak_at"]["instruction"] in entry["ops"]
+    # the gauges `capture()` always set say the same
+    assert metrics.snapshot()["gauges"][
+        "xla.cost.temp_bytes{label=ledger_memory}"] == \
+        entry["memory"]["temp_bytes"]
+
+
 # ====================== scopes in the program ======================
 
 class _Block(nn.Layer):

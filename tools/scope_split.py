@@ -30,9 +30,11 @@ import sys
 
 
 def phase_of(op_name):
-    if "rematted_computation" in op_name:
-        return "replay"
-    return "bwd" if "transpose(" in op_name else "fwd"
+    """`observability.xla_cost.phase_of` of the checkout that is run (one
+    from PR 37 on): the program ledger names a buffer's phase with it."""
+    from paddle_tpu.observability.xla_cost import phase_of as of
+
+    return of(op_name)
 
 
 def split(rows, scope, steps, floor_ms=0.05):
