@@ -9,9 +9,21 @@ kernel instance works on the tile of ALL `rep` heads of a group: the
 product, the mask block is read once a group, and dK/dV sum over the
 group's heads inside the contraction.  K and V are never expanded.
 
-    grid (batch, kv_head, q_block, k_block)      forward, dQ
-    grid (batch, kv_head, k_block, q_block)      dK/dV
+    grid (batch, kv_head, q_block, k_block)      forward; fused backward
+    grid (batch, kv_head, q_block, k_block)      split pair: dQ
+    grid (batch, kv_head, k_block, q_block)      split pair: dK/dV
     grid (batch, q_block, k_block, kv_head)      head-mean probabilities
+
+The backward is ONE kernel wherever its VMEM fits (`_bwd_vmem_bytes`
+within `_BWD_VMEM_LIMIT`; 8192 x 128 does, with room): each tile makes
+its logits, probabilities and dP once; dQ accumulates over the key blocks
+of its query block (ascending), and dK/dV over the query blocks
+(ascending) into the key/value head's sequence-long [T, d] float32
+scratch — 8 MiB at 8192 x 128 however many query heads share it, where
+a head's sequence-long dQ would be that per query head.  Past the budget
+the split dQ + dK/dV pair runs, which makes the logits twice.  Both
+orders are the split pair's, so the two give the same bits;
+`sparse_attn.backward{kind=fused|split}` counts which was traced.
 
 This is a DENSE CAUSAL PASS that masks: a grid step whose key block lies
 wholly after its query block is skipped (its index maps repeat the last
@@ -90,10 +102,13 @@ def computed_pairs(batch, t, blocks=None):
                        for qi in range(t // bq))
 
 
-def _params(sem):
+def _params(sem, vmem_limit_bytes=None):
     if _interpret():
         return None
-    return pltpu.CompilerParams(dimension_semantics=sem)
+    if vmem_limit_bytes is None:
+        return pltpu.CompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem,
+                                vmem_limit_bytes=int(vmem_limit_bytes))
 
 
 def _rows_to_col(rows):
@@ -283,6 +298,84 @@ def _dkv_kernel(q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, dl_ref, dk_ref,
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, dl_ref, dq_ref,
+                dk_ref, dv_ref, dq_sc, lse_sc, dl_sc, dk_sc, dv_sc, *, scale,
+                bq, bk, rep):
+    """dQ, dK and dV on one visit of each tile: dQ accumulates over the
+    key blocks of a query block (as `_dq_kernel`), dK and dV over the
+    query blocks into the KV head's sequence-long [T, d] float32
+    scratch at the key block's rows (as `_dkv_kernel`, same order).
+    It takes the split pair's dK/dV name, `transpose(jvp(sparse_attn_dkdv))`,
+    and also makes dQ: a device trace counts a layer's backward by that
+    name (`benchmark/readers/cost_sparse_attn.py`)."""
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    last = _last_k(qi, bq, bk)
+    d = q_ref.shape[-1]
+    rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
+
+    @pl.when((qi == 0) & (ki == 0))
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+        lse_sc[...] = _rows_to_col(lse_ref[...])
+        dl_sc[...] = _rows_to_col(dl_ref[...])
+
+    @pl.when(ki <= last)
+    def _():
+        q = q_ref[...].reshape(rep * bq, d)
+        do = do_ref[...].reshape(rep * bq, d)
+        k = k_ref[...]
+        p, ds = _p_ds(q, k, v_ref[...], do, _selected(m_ref), lse_sc[...],
+                      dl_sc[...], scale, rep)
+        dq_sc[...] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dv_sc[rows, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_sc[rows, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == last)
+    def _():
+        dq_ref[...] = dq_sc[...].reshape(rep, bq, d).astype(dq_ref.dtype)
+
+    # the last query block sees every key block: there each block's dK
+    # and dV are final, and its output block is the one the grid is on
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = dk_sc[rows, :].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[rows, :].astype(dv_ref.dtype)
+
+
+# scoped VMEM the fused backward may ask for, per kernel (as flash's
+# _flat_compiler_params: a program-wide raise starves XLA's own ops);
+# past it the split pair runs.  v5e has 128 MiB
+_BWD_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _bwd_vmem_bytes(t, rep, d, bq, bk, esz) -> int:
+    """Scoped VMEM of the fused backward: the KV head's resident float32
+    dK and dV, beside the double-buffered q, do, dq, k, v, dk, dv, mask
+    and stat blocks, the dQ accumulator, the lse and delta columns (each
+    value pads to 128 lanes) and the logits-sized temporaries.  Against
+    the v5e compiler at blocks (128, 1024), 32:4 heads of 128 bf16:
+    23.3 MiB here at 2 x 8192, where it compiles at 17.4 and not at
+    17.2; 47.3 here at 1 x 32768, where it compiles at 42.3."""
+    n, dp = rep * bq, _fa._up(d, 128)
+    return (2 * t * dp * 4
+            + 2 * (3 * n + 4 * bk) * dp * esz
+            + 2 * bq * bk
+            + 2 * 2 * _fa._stat_rows_bytes(1, rep, bq)
+            + n * dp * 4 + 2 * n * 128 * 4
+            + _fa._fused_tile_bytes(n, bk, esz))
+
+
 def _bwd(qt, kt, vt, mask, ot, lse, dot, blocks):
     b, hkv, rep, t, d = qt.shape
     bq, bk = blocks
@@ -292,6 +385,35 @@ def _bwd(qt, kt, vt, mask, ot, lse, dot, blocks):
         delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
                         axis=-1)
     q, kv, m, st = _specs(rep, bq, bk, d, "qk")
+    vmem = _bwd_vmem_bytes(t, rep, d, bq, bk, qt.dtype.itemsize)
+    fused = vmem <= _BWD_VMEM_LIMIT
+    _fa._metrics.inc("sparse_attn.backward",
+                     kind="fused" if fused else "split")
+    if fused:
+        nq = t // bq
+        # a key block's dK/dV block is written on the last query block's
+        # walk and held at block 0 before it, which nothing writes
+        dkv = pl.BlockSpec((None, None, bk, d), lambda bi, hi, qi, ki: (
+            bi, hi, jnp.where(qi == nq - 1, ki, 0), 0))
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale, bq=bq, bk=bk,
+                              rep=rep),
+            grid=(b, hkv, nq, t // bk),
+            in_specs=[q, kv, kv, m, q, st, st],
+            out_specs=[q, dkv, dkv],
+            out_shape=[jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+                       jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                       jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
+            scratch_shapes=[pltpu.VMEM((n, d), jnp.float32),
+                            pltpu.VMEM((n, 1), jnp.float32),
+                            pltpu.VMEM((n, 1), jnp.float32),
+                            pltpu.VMEM((t, d), jnp.float32),
+                            pltpu.VMEM((t, d), jnp.float32)],
+            interpret=_interpret(),
+            compiler_params=_params((_PLL, _PLL, _ARB, _ARB),
+                                    max(vmem, _fa._T_VMEM_LIMIT)),
+            name=_bwd_name("sparse_attn_dkdv"),
+        )(qt, kt, vt, mask, dot, lse, delta)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk, rep=rep),
         grid=(b, hkv, t // bq, t // bk),

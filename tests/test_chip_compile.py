@@ -266,7 +266,8 @@ def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(
     of 16 heads of 64, topk 2048 — the index-score kernels (forward and
     backward), the selection's search, the indexer's loss (ONE kernel: the
     value and the gradient by the scores), the masked grouped-query
-    attention (forward, dq, dkdv) and the head-mean probabilities, at the
+    attention (forward and ONE backward kernel: dQ, dK and dV, its VMEM
+    limit from `_bwd_vmem_bytes`) and the head-mean probabilities, at the
     default blocks, through the chip's compiler.  The layer goes through
     its functional entries: each must take its kernel when compiled for
     the chip (a predicate on the wrong shape once sent two of them to
@@ -280,6 +281,7 @@ def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(
     import paddle_tpu as P
     from paddle_tpu.core import flags
     from paddle_tpu.nn import functional as F
+    from paddle_tpu.observability import metrics
 
     rc = importlib.import_module("paddle_tpu.distributed.recompute")
     b, t, h, hkv, d, j, di = 2, 8192, 32, 4, 128, 16, 64
@@ -303,18 +305,31 @@ def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(
             return (out._value.astype(jnp.float32) * g).sum() + aux._value
         return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))(*args)
 
-    text = _compile(one_chip, step, ((b, t, h, d), jnp.float32),
-                    ((b, t, h, d), bf), ((b, t, hkv, d), bf),
-                    ((b, t, hkv, d), bf), ((b, t, j, di), bf),
-                    ((b, t, di), bf), ((b, t, j), bf))
-    # XLA names a Mosaic call after its kernel (`%jvp_sparse_index_fwd_.1`)
+    was = metrics.enabled()
+    metrics.enable()
+    before = dict(metrics.snapshot()["counters"])
+    try:
+        text = _compile(one_chip, step, ((b, t, h, d), jnp.float32),
+                        ((b, t, h, d), bf), ((b, t, hkv, d), bf),
+                        ((b, t, hkv, d), bf), ((b, t, j, di), bf),
+                        ((b, t, di), bf), ((b, t, j), bf))
+        now = metrics.snapshot()["counters"]
+    finally:
+        if not was:
+            metrics.disable()
+    backward = {k: now[k] - before.get(k, 0) for k in now
+                if k.startswith("sparse_attn.backward")}
+    assert backward == {"sparse_attn.backward{kind=fused}": 1}
+    # XLA names a Mosaic call after its kernel (`%jvp_sparse_index_fwd_.1`);
+    # the fused backward makes dQ under the dK/dV kernel's name
     calls = re.findall(r"(%[\w.\-]+) = [^\n]*tpu_custom_call", text)
     for kernel in ("sparse_index_fwd", "sparse_index_dq", "sparse_index_dk",
                    "sparse_index_select", "sparse_index_loss",
-                   "sparse_attn_fwd", "sparse_attn_dq", "sparse_attn_dkdv",
+                   "sparse_attn_fwd", "sparse_attn_dkdv",
                    "sparse_attn_probs"):
         assert sum(kernel in c for c in calls) == 1, kernel
-    assert len(calls) == 9 and "sparse_index_loss_bwd" not in text
+    assert len(calls) == 8 and "sparse_index_loss_bwd" not in text
+    assert "sparse_attn_dq" not in text
 
 
 def test_flash_runs_per_shard_on_a_four_chip_mesh(topo, one_chip,

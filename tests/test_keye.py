@@ -370,12 +370,16 @@ def test_indexer_loss_gradient_is_kept_under_recomputation(monkeypatch):
     assert calls(off) == calls(kept) == [2, 2, 2, 2]
     assert calls(full) == [4, 4, 4, 4]
     for text in (kept, off, full):           # the backward's own: once
-        for n in ("sparse_index_dq", "sparse_index_dk", "sparse_attn_dq",
-                  "sparse_attn_dkdv"):
+        # (the fused attention backward makes dQ under the dK/dV name)
+        for n in ("sparse_index_dq", "sparse_index_dk", "sparse_attn_dkdv"):
             assert len(re.findall(rf"name=transpose\(jvp\({n}\)\)",
                                   text)) == 2, n
+        assert "sparse_attn_dq" not in text
         assert "sparse_index_loss_bwd" not in text
         assert "name=indexer_grad]" in text  # only a policy reads the mark
+    for got in (added, added_off, added_full):
+        assert got["sparse_attn.backward{kind=fused}"] == 2
+        assert "sparse_attn.backward{kind=split}" not in got
     counter = "sparse_index.recompute_kept{what=loss_grad}"
     assert added[counter] == 2               # traced once: 2 layers
     assert counter not in added_off and counter not in added_full
@@ -431,6 +435,72 @@ def test_pallas_kernels_match_the_jnp_form(blocks):
         ((qi * bq + bq - 1) // bk + 1) * bk * bq for qi in range(t // bq))
     with pytest.raises(ValueError, match="do not divide"):
         sa.computed_pairs(1, 200, blocks)
+
+
+def _selection(case, b, t, key):
+    """A causal [B, T, T] int8 selection: random, with the first rows
+    whole (t < topk there), or with rows of exactly one key."""
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    some = (jax.random.uniform(key, (b, t, t)) < 0.3) | jnp.eye(t, dtype=bool)
+    if case == "first_rows_whole":
+        some = some | (jnp.arange(t) < 96)[:, None]
+    if case == "one_key":
+        rows = jnp.arange(t)[:, None]
+        one = jnp.arange(t)[None] == jnp.where(rows % 3 == 0, rows // 2, rows)
+        some = jnp.where(rows % 2 == 0, one, some)
+    return (causal & some).astype(jnp.int8)
+
+
+@pytest.mark.parametrize("case,t,h,blocks", [
+    ("random", 256, 4, (64, 128)),
+    ("random", 256, 8, (32, 64)),          # rep 4, 8 x 4 blocks
+    ("first_rows_whole", 256, 4, (32, 128)),
+    ("one_key", 128, 4, (32, 32)),
+    ("random", 64, 2, (64, 64)),           # one query and one key block
+])
+def test_fused_backward_equals_the_split_pair(monkeypatch, case, t, h,
+                                              blocks):
+    """The fused backward kernel (one visit of each tile: dQ over the key
+    blocks, dK/dV of the key/value head resident over the query blocks)
+    against the split dq + dkdv pair, which a VMEM budget lowered to
+    nothing takes: dQ, dK and dV bit for bit, both at `reference()`'s
+    gradients; `sparse_attn.backward{kind}` says which ran."""
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+
+    b, hkv, d = 2, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(t + h), 5)
+    q = jax.random.normal(ks[0], (b, t, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, t, hkv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, t, hkv, d), jnp.float32)
+    mask = _selection(case, b, t, ks[3])
+    g = jax.random.normal(ks[4], q.shape)
+    loss = lambda *a: (sa.sparse_attention(*a, mask, blocks)[0] * g).sum()
+
+    def grads():
+        before = dict(metrics.snapshot()["counters"])
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        now = metrics.snapshot()["counters"]
+        return got, {c: now[c] - before.get(c, 0) for c in now
+                     if c.startswith("sparse_attn.backward")
+                     and now[c] - before.get(c, 0)}
+
+    was = metrics.enabled()
+    metrics.enable()
+    try:
+        fused, took = grads()
+        assert took == {"sparse_attn.backward{kind=fused}": 1}
+        monkeypatch.setattr(sa, "_BWD_VMEM_LIMIT", 0)
+        split, took = grads()
+        assert took == {"sparse_attn.backward{kind=split}": 1}
+    finally:
+        if not was:
+            metrics.disable()
+    want = jax.grad(lambda *a: (sa.reference(*a, mask) * g).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, s, w in zip(fused, split, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(s))
+        np.testing.assert_allclose(a, w, atol=5e-5)
 
 
 def test_index_kernels_match_the_jnp_form():
