@@ -406,7 +406,7 @@ def _token_slices(b, s, v):
     return sc, -(-s // sc)
 
 
-def _fused_linear_ce(h, w, labels, ignore_index):
+def _fused_linear_ce(h, w, labels, ignore_index, weights=None):
     """Cross entropy fused WITH the LM-head projection ("cut cross
     entropy"): the [B, S, V] logits never exist.  A `lax.scan` over
     slices of the SEQUENCE (`_token_slices`) computes `h_c @ w.T` on the
@@ -423,11 +423,27 @@ def _fused_linear_ce(h, w, labels, ignore_index):
     step shards it, and a scan along it would walk the sharded axis.
 
     h: [B, S, Hd]; w: [V, Hd] (tied-embedding layout); labels: [B, S].
-    Returns (total_loss_f32, valid_count_f32)."""
-    b, s, hd = h.shape
+    Returns (total_loss_f32, valid_count_f32).
+
+    weights (per-token weights, e.g. a looped model's exit distribution):
+    h is then [P * B, S, Hd], P read-outs of the same B rows stacked P
+    major, and weights [P * B, S]; labels [B, S] serve all P.  The total
+    is sum over read-outs and valid tokens of weight x CE, the count that
+    of B x S's valid tokens; `d` is scaled by the weight, and the weights'
+    own gradient — each token's CE — is formed in the forward scan too, a
+    third residual.  One call carries ONE dW for all P read-outs.  With
+    weights None the program is the unweighted one, op for op."""
+    pb, s, hd = h.shape
+    b = labels.shape[0]
+    if pb % b or (weights is None and pb != b):
+        raise ValueError(f"hidden rows {pb} are not P read-outs of the "
+                         f"labels' {b} rows (P > 1 needs weights)")
+    reps = pb // b
     v = w.shape[0]
-    sc, n = _token_slices(b, s, v)
+    sc, n = _token_slices(pb, s, v)
     _metrics.inc("head_ce.scan", axis="tokens", chunks=n)
+    if weights is not None:
+        _metrics.inc("head_ce.weights", kind="per_token")
     pad = n * sc - s
     if pad:
         labels = jnp.pad(labels, ((0, 0), (0, pad)),
@@ -436,54 +452,69 @@ def _fused_linear_ce(h, w, labels, ignore_index):
     def padded(hh):
         return jnp.pad(hh, ((0, 0), (0, pad), (0, 0))) if pad else hh
 
-    def chunk(hp, ww, i):
+    def chunk(hp, ww, wp, i):
         hc = jax.lax.dynamic_slice_in_dim(hp, i * sc, sc, axis=1)
         lc = jax.lax.dynamic_slice_in_dim(labels, i * sc, sc, axis=1)
         # the slice's rows as ONE axis, B major (the merge keeps a dp
         # sharding of B): on the chip the scan runs up to 7 % faster over
         # [B * sc, V] logits than over [B, sc, V]
-        hc, lc = hc.reshape(b * sc, hd), lc.reshape(b * sc)
+        hc, lc = hc.reshape(pb * sc, hd), lc.reshape(b * sc)
         valid = lc != ignore_index
         safe = jnp.where(valid, lc, 0).astype(jnp.int32)
+        rows_valid, rows_safe = valid, safe
+        if reps > 1:         # the P read-outs' rows share their labels
+            rows_valid, rows_safe = jnp.tile(valid, reps), jnp.tile(safe, reps)
         logits = jax.lax.dot_general(
             hc, ww, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [B * sc, V]
         m = jnp.max(logits, axis=1)
         l = jnp.sum(jnp.exp(logits - m[:, None]), axis=1)
         lse = m + jnp.log(jnp.maximum(l, 1e-30))
-        picked = jnp.take_along_axis(logits, safe[:, None], axis=1)[:, 0]
+        picked = jnp.take_along_axis(logits, rows_safe[:, None], axis=1)[:, 0]
+        ce = jnp.where(rows_valid, lse - picked, 0.0)
+        wc = None
+        if wp is not None:
+            wc = jax.lax.dynamic_slice_in_dim(
+                wp, i * sc, sc, axis=1).reshape(pb * sc)
         # [summed loss, valid rows]: the count rides the scan so that,
         # under dp, its reduction over the shards FOLLOWS the loop's (the
         # CPU backend leaves dW's all-reduce inside the loop, and two
         # collectives free to start in either order deadlock there)
-        sums = (jnp.where(valid, lse - picked, 0.0).sum(),
+        sums = ((ce if wc is None else wc * ce).sum(),
                 valid.astype(jnp.float32).sum())
-        return sums, (hc, logits, lse, safe, valid)
+        return sums, (hc, logits, lse, rows_safe, rows_valid, ce, wc)
+
+    def padded_weights(wt):
+        if wt is None or not pad:
+            return wt
+        return jnp.pad(wt, ((0, 0), (0, pad)))
 
     @jax.custom_vjp
-    def core(hh, ww):
-        hp = padded(hh)
+    def core(hh, ww, wt):
+        hp, wp = padded(hh), padded_weights(wt)
 
         def body(sums, i):
-            return jax.tree.map(jnp.add, sums, chunk(hp, ww, i)[0]), None
+            return jax.tree.map(jnp.add, sums, chunk(hp, ww, wp, i)[0]), None
 
         sums, _ = jax.lax.scan(body, (jnp.zeros((), jnp.float32),) * 2,
                                jnp.arange(n))
         return sums
 
-    def core_f(hh, ww):
+    def core_f(hh, ww, wt):
         _metrics.inc("head_ce.grad", where="forward")
-        hp = padded(hh)
+        hp, wp = padded(hh), padded_weights(wt)
 
         def body(carry, i):
-            sums, dh, dw = carry
-            part, (hc, logits, lse, safe, valid) = chunk(hp, ww, i)
+            sums, dh, dw, dwt = carry
+            part, (hc, logits, lse, safe, valid, ce, wc) = chunk(hp, ww, wp, i)
             # XLA fuses `d` into both products as their operand's
             # producer; materialised once in bf16 (an optimization
             # barrier) the scan is 6-10 ms a step slower on the chip
             onehot = jnp.arange(v) == safe[:, None]
             d = ((jnp.exp(logits - lse[:, None]) - onehot)
-                 * valid[:, None]).astype(hh.dtype)        # [B * sc, V]
+                 * (valid[:, None] if wc is None
+                    else jnp.where(valid, wc, 0.0)[:, None])
+                 ).astype(hh.dtype)                        # [B * sc, V]
             dhc = jax.lax.dot_general(
                 d, ww, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)        # [B * sc, Hd]
@@ -491,21 +522,26 @@ def _fused_linear_ce(h, w, labels, ignore_index):
                 d, hc, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)        # [V, Hd]
             dh = jax.lax.dynamic_update_slice_in_dim(
-                dh, dhc.reshape(b, sc, hd), i * sc, axis=1)
-            return (jax.tree.map(jnp.add, sums, part), dh, dw), None
+                dh, dhc.reshape(pb, sc, hd), i * sc, axis=1)
+            if dwt is not None:     # d(total)/d(weight) = the token's CE
+                dwt = jax.lax.dynamic_update_slice_in_dim(
+                    dwt, ce.reshape(pb, sc), i * sc, axis=1)
+            return (jax.tree.map(jnp.add, sums, part), dh, dw, dwt), None
 
         init = ((jnp.zeros((), jnp.float32),) * 2,
-                jnp.zeros((b, n * sc, hd), jnp.float32),
-                jnp.zeros((v, hd), jnp.float32))
-        (sums, dh, dw), _ = jax.lax.scan(body, init, jnp.arange(n))
-        return sums, (dh[:, :s], dw)
+                jnp.zeros((pb, n * sc, hd), jnp.float32),
+                jnp.zeros((v, hd), jnp.float32),
+                None if wt is None else jnp.zeros((pb, n * sc), jnp.float32))
+        (sums, dh, dw, dwt), _ = jax.lax.scan(body, init, jnp.arange(n))
+        return sums, (dh[:, :s], dw, None if dwt is None else dwt[:, :s])
 
     def core_b(res, g):
-        dh, dw = res             # of the summed loss; the count has none
-        return (g[0] * dh).astype(h.dtype), (g[0] * dw).astype(w.dtype)
+        dh, dw, dwt = res        # of the summed loss; the count has none
+        return ((g[0] * dh).astype(h.dtype), (g[0] * dw).astype(w.dtype),
+                None if dwt is None else (g[0] * dwt).astype(weights.dtype))
 
     core.defvjp(core_f, core_b)
-    return core(h, w)
+    return core(h, w, weights)
 
 
 class GPTPretrainingCriterion(nn.Layer):
@@ -528,7 +564,13 @@ class GPTPretrainingCriterion(nn.Layer):
     A second loss term: where the model given as model= has a method
     `pop_aux_loss()` (models/keye.py: the sum of its layers' indexer
     losses, registered by the forward pass that made `logits`), the
-    criterion takes that scalar and adds it to the token loss."""
+    criterion takes that scalar and adds it to the token loss.  The same
+    door may hand a pair `(weights, scalar)` (models/ouro.py: the exit
+    distribution [P, B, S] over the P read-outs the model stacked into
+    its hidden states, and the entropy term): each read-out's token CE is
+    then scaled by its weight in the one scan (`_fused_linear_ce`'s
+    `weights`, under the same scope `head_ce`) before the scalar is
+    added."""
 
     def __init__(self, ignore_index=-100, fused=True, model=None):
         super().__init__()
@@ -539,12 +581,15 @@ class GPTPretrainingCriterion(nn.Layer):
             model.fused_head_weight()   # refuses a model without one
 
     def forward(self, logits, labels):
-        loss = self._token_loss(logits, labels)
         pop = getattr(self._model, "pop_aux_loss", None)
         aux = pop() if pop is not None else None
+        weights = None
+        if isinstance(aux, tuple):
+            weights, aux = aux
+        loss = self._token_loss(logits, labels, weights)
         return loss if aux is None else loss + aux
 
-    def _token_loss(self, logits, labels):
+    def _token_loss(self, logits, labels, weights=None):
         lv = logits._value if hasattr(logits, "_value") else logits
         yv = labels._value if hasattr(labels, "_value") else labels
         is_hidden = getattr(logits, "name", None) == "fused_head_hidden"
@@ -562,19 +607,25 @@ class GPTPretrainingCriterion(nn.Layer):
 
             w = self._model.fused_head_weight()  # live (bindable) param
 
-            def f(hh, lb, wv):
+            def f(hh, lb, wv, wt=None):
                 # [B, S, Hd] as the model hands it: the scan cuts S and
                 # keeps B (the axis dp shards) whole in every slice
                 s, hd = hh.shape[-2:]
                 total, count = _fused_linear_ce(
                     hh.reshape(-1, s, hd), wv, lb.reshape(-1, s),
-                    self.ignore_index)
+                    self.ignore_index,
+                    None if wt is None else wt.reshape(-1, s))
                 return total / jnp.maximum(count, 1.0)
 
             # head projection + CE in one scan over slices of the
             # sequence: one scope, `head_ce`
+            args = (logits, labels, w) + (() if weights is None else (weights,))
             with jax.named_scope("head_ce"):
-                return apply("fused_linear_ce", f, logits, labels, w)
+                return apply("fused_linear_ce", f, *args)
+        if weights is not None:
+            raise RuntimeError("per-token weights are read by the fused head "
+                               "+ CE scan only: the model must hand it its "
+                               "hidden states")
         if self.fused and lv.shape[-1] >= 8192:
             from ..core.dispatch import apply
 

@@ -664,3 +664,47 @@ def test_sweep_is_held_to_the_compiler_on_a_two_layer_afmoe_step(topo,
     calls = step_bytes.mosaic_calls(compiled.as_text())
     assert calls["jvp_flash_transpose_window_fwd_"] == 2
     assert calls["transpose_jvp_flash_transpose_window_dq__"] == 2
+
+
+def test_looped_step_compiles_at_the_ouro_cells_shape(topo, one_chip):
+    """`tools/step_bytes.py` on the Ouro cell cut to its first layer (3 x
+    4096, 16 heads of 128, the whole 49,152-row head, the stack run FOUR
+    times): the whole train step through the chip's compiler.  Every
+    layer APPLICATION is a recomputed segment that keeps its flash output
+    + lse, so the forward kernel stands once an application (4) and no
+    replay runs it again; the four passes' read-outs are ONE weighted
+    head + CE scan; the sweep stays under the compiler's temporaries."""
+    import importlib.util
+    from pathlib import Path
+
+    from paddle_tpu.distributed import topology
+    from paddle_tpu.observability import metrics, xla_cost
+
+    path = Path(__file__).parents[1] / "tools" / "step_bytes.py"
+    spec = importlib.util.spec_from_file_location("step_bytes", path)
+    step_bytes = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(step_bytes)
+    was = metrics.enabled()
+    metrics.enable()
+    before = dict(metrics.snapshot()["counters"])
+    try:
+        compiled = step_bytes.compile_step("ouro-2.6b-pp8.train.seq4096",
+                                           topo.devices[0], layers=1)
+        entry = xla_cost.program_ledger("train_step")
+        now = metrics.snapshot()["counters"]
+    finally:
+        topology.reset_topology()
+        if not was:
+            metrics.disable()
+    added = {k: v - before.get(k, 0) for k, v in now.items()
+             if v - before.get(k, 0)}
+    assert added["flash.recompute_kept{what=out_lse}"] == 4
+    assert [added[f"loop.apply{{ut={k}}}"] for k in (1, 2, 3, 4)] == [1] * 4
+    assert added["head_ce.weights{kind=per_token}"] == 1
+    assert added["flash.dispatch{tier=transpose}"] == 4
+    peak, temp = entry["bytes"]["peak_bytes"], entry["memory"]["temp_bytes"]
+    assert peak <= temp
+    assert "loop." in entry["bytes"]["peak_at"]["op_name"]
+    calls = step_bytes.mosaic_calls(compiled.as_text())
+    assert calls["jvp_flash_transpose_fwd_"] == 4
+    assert calls["transpose_jvp_flash_transpose_dkdv__"] == 4
